@@ -893,7 +893,10 @@ def main() -> None:
                     help="reduced steps/sweeps (CI mode)")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
     print("name,us_per_call,derived")
+    failed = []
     for fn in ALL:
         if args.only and args.only not in fn.__name__:
             continue
@@ -901,6 +904,9 @@ def main() -> None:
             fn(fast=args.fast)
         except Exception as e:  # report, keep the harness going
             _row(fn.__name__ + "_FAILED", 0.0, f"{type(e).__name__}:{e}")
+            failed.append(fn.__name__)
+    if failed:
+        sys.exit(f"{len(failed)} benchmark(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -23,13 +23,15 @@ sends every request the same prompt — watch `prefix_hits`), and
 dequantized in-VMEM by the flash-decode kernel. `--tp N` shards the
 whole stack over an N-device mesh — attention heads, MLP hidden, vocab,
 and the KV arena's head axis — token-identical to 1 device with
-per-device param/KV bytes ~1/N (`--devices N` forces N fake host
-devices for trying this on a CPU box); `--chunked-prefill C` prefills
+per-device param/KV bytes ~1/N (`--devices N` with JAX_PLATFORMS=cpu
+forces N fake host devices for trying this on a CPU box);
+`--chunked-prefill C` prefills
 prompts at most C rows per step into a staging row so decode keeps
 running mid-prefill (DESIGN.md §4.12).
 
-    PYTHONPATH=src python examples/serve_engine.py --devices 4 --tp 4 \
-        --packed --bits 4 --prompt-lens 16,4,9,12 --gens 12 --slots 2
+    JAX_PLATFORMS=cpu PYTHONPATH=src python examples/serve_engine.py \
+        --devices 4 --tp 4 --packed --bits 4 --prompt-lens 16,4,9,12 \
+        --gens 12 --slots 2
 
     PYTHONPATH=src python examples/serve_engine.py --chunked-prefill 8 \
         --prompt-lens 6,40 --gens 24,8 --slots 2
@@ -112,22 +114,17 @@ def main():
                          "axis) — token-identical to 1 device, per-device "
                          "bytes ~1/tp (DESIGN.md §4.12)")
     ap.add_argument("--devices", type=int, default=0,
-                    help="force N fake host devices "
-                         "(xla_force_host_platform_device_count) so --tp "
-                         "runs on a CPU box")
+                    help="CPU runs (JAX_PLATFORMS=cpu): force N fake host "
+                         "devices (xla_force_host_platform_device_count) so "
+                         "--tp runs on a CPU box")
     ap.add_argument("--chunked-prefill", type=int, default=None,
                     metavar="CHUNK",
                     help="prefill prompts at most CHUNK rows per step into "
                          "a staging row so decode keeps running mid-prefill "
                          "(DESIGN.md §4.12)")
     args = ap.parse_args()
-    if args.devices:
-        import os
-        flag = f"--xla_force_host_platform_device_count={args.devices}"
-        if "xla_force_host_platform_device_count" not in \
-                os.environ.get("XLA_FLAGS", ""):
-            os.environ["XLA_FLAGS"] = \
-                (os.environ.get("XLA_FLAGS", "") + " " + flag).strip()
+    from repro.launch.mesh import force_host_devices
+    force_host_devices(args.devices)   # CPU runs only
     if args.kv_bits is not None:
         args.paged = True
 

@@ -8,14 +8,15 @@ a real accelerator).
 
     PYTHONPATH=src python examples/train_lm_geta.py --steps 200
 
-Sharded (data-parallel over N devices; on a CPU host N fake XLA devices
-are forced before jax initializes — add --fsdp to shard params/opt-state):
+Sharded (data-parallel over N devices; a CPU run held to the CPU with
+JAX_PLATFORMS=cpu gets N forced XLA host devices, an accelerator host
+uses its chips — add --fsdp to shard params/opt-state):
 
-    PYTHONPATH=src python examples/train_lm_geta.py --steps 50 --devices 4
+    JAX_PLATFORMS=cpu PYTHONPATH=src python examples/train_lm_geta.py \
+        --steps 50 --devices 4
 """
 import argparse
 import dataclasses
-import os
 
 
 def main():
@@ -28,22 +29,15 @@ def main():
     ap.add_argument("--inject-failure", type=int, default=None,
                     help="step at which to simulate a node failure")
     ap.add_argument("--devices", type=int, default=None,
-                    help="data-parallel mesh over N devices (CPU hosts get "
-                         "N forced XLA host devices)")
+                    help="data-parallel mesh over N devices (CPU runs "
+                         "with JAX_PLATFORMS=cpu get N forced XLA host "
+                         "devices)")
     ap.add_argument("--fsdp", action="store_true",
                     help="shard params + optimizer state over the data axis")
     args = ap.parse_args()
 
-    if args.devices and args.devices > 1:
-        # must precede the first jax import — jax locks the device count.
-        # Append to any existing XLA_FLAGS (setdefault would silently
-        # leave the host single-device when the user has unrelated flags
-        # exported); an explicit device-count flag in the env wins.
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                f"{flags} --xla_force_host_platform_device_count="
-                f"{args.devices}").strip()
+    from repro.launch.mesh import force_host_devices
+    force_host_devices(args.devices or 0)   # CPU runs only
 
     from repro.configs import CompressionConfig
     from repro.launch.train import train_loop

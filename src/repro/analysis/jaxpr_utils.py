@@ -3,7 +3,7 @@
 Everything here is pure introspection over `jax.make_jaxpr` output: no
 compilation, no device execution. The central abstraction is a recursive
 equation walk that descends into *every* sub-jaxpr an equation carries in
-its params — `pjit` bodies, `shard_map` bodies, `scan`/`while`/`cond`
+its params — `jit` bodies, `shard_map` bodies, `scan`/`while`/`cond`
 branches, custom-vjp call jaxprs — yielding each equation together with
 the *path* of enclosing higher-order primitives, so a pass can ask both
 "does a psum appear anywhere?" and "is this all_gather inside a
@@ -16,7 +16,7 @@ from typing import Iterator, Optional
 
 import jax
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 
 def iter_subjaxprs(eqn) -> Iterator[tuple[str, "jcore.Jaxpr", tuple]]:
@@ -39,7 +39,7 @@ def walk_eqns(jaxpr, path: tuple[str, ...] = ()
     """DFS over every equation of `jaxpr` and all nested sub-jaxprs.
 
     Yields (eqn, path) where `path` is the tuple of enclosing primitive
-    names ("pjit", "shard_map", "scan", ...) from outermost to innermost.
+    names ("jit", "shard_map", "scan", ...) from outermost to innermost.
     Accepts a Jaxpr or ClosedJaxpr."""
     if isinstance(jaxpr, jcore.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
@@ -86,28 +86,26 @@ def collect_consts(closed, min_elems: int = 1
     return out
 
 
-def outer_pjit_eqn(closed) -> Optional[object]:
-    """The single top-level pjit equation of `jax.make_jaxpr(jitted_fn)`
+def outer_jit_eqn(closed) -> Optional[object]:
+    """The single top-level jit equation of `jax.make_jaxpr(jitted_fn)`
     output — the equation whose params carry the jit's in/out shardings.
     None when the traced callable was not a jit wrapper."""
     eqns = closed.jaxpr.eqns if isinstance(closed, jcore.ClosedJaxpr) \
         else closed.eqns
-    pjits = [e for e in eqns if e.primitive.name == "pjit"]
-    if len(eqns) == 1 and len(pjits) == 1:
-        return pjits[0]
-    return pjits[0] if len(pjits) == 1 else None
+    jits = [e for e in eqns if e.primitive.name == "jit"]
+    return jits[0] if len(jits) == 1 else None
 
 
 def is_unspecified(sharding) -> bool:
-    """True for pjit's UnspecifiedValue marker (no out_sharding pinned).
-    Matched by type name — the class moved modules across JAX releases."""
+    """True for jit's UnspecifiedValue marker (no out_sharding pinned).
+    Matched by type name — the class is private to `jax._src`."""
     return sharding is None or type(sharding).__name__ == "UnspecifiedValue"
 
 
-def out_shardings_of(pjit_eqn) -> tuple:
-    """The flat out_shardings tuple a pjit equation declares (one entry
+def out_shardings_of(jit_eqn) -> tuple:
+    """The flat out_shardings tuple a jit equation declares (one entry
     per flattened output leaf; UnspecifiedValue where unpinned)."""
-    return tuple(pjit_eqn.params.get("out_shardings", ()))
+    return tuple(jit_eqn.params.get("out_shardings", ()))
 
 
 def spec_of(sharding):

@@ -35,14 +35,16 @@ from repro.analysis.report import Finding, make_finding
 # contraction ever splits) and allowed in training only via the
 # slice-ordered path below.
 REDUCTION_PRIMS = frozenset({
-    "psum", "psum2", "all_reduce", "reduce_scatter", "all_to_all",
-    "pmax", "pmin", "pmean", "reduce_precision_psum",
+    "psum", "psum_invariant", "unreduced_psum", "reduce_scatter",
+    "unreduced_reduce_scatter", "all_to_all", "ragged_all_to_all",
+    "pmax", "pmin",
 })
 # Pure data movement: bitwise replication/rotation, no arithmetic.
 # Banned in serving jaxprs too (nothing should move between devices
 # mid-decode), but allowed inside the trainer's shard_map (the ordered
 # reduction gathers slices and sums them in a fixed order locally).
-MOVEMENT_PRIMS = frozenset({"all_gather", "ppermute", "pbroadcast"})
+MOVEMENT_PRIMS = frozenset({"all_gather", "all_gather_invariant",
+                            "all_gather_reduced", "ppermute", "pbroadcast"})
 
 IDENTITY = "identity"
 SHARDING = "sharding"
@@ -176,14 +178,14 @@ def audit_sharding_pins(traced_entries) -> list[Finding]:
         if te.expected_out is None or te.kind != "serving":
             continue
         import jax
-        pjit_eqn = ju.outer_pjit_eqn(te.jaxpr)
-        if pjit_eqn is None:
+        jit_eqn = ju.outer_jit_eqn(te.jaxpr)
+        if jit_eqn is None:
             findings.append(make_finding(
-                SHARDING, te.group, te.name, "no-pjit",
-                "entry did not trace to a single pjit equation — cannot "
+                SHARDING, te.group, te.name, "no-jit",
+                "entry did not trace to a single jit equation — cannot "
                 "audit its out_shardings", severity="warning"))
             continue
-        actual = ju.out_shardings_of(pjit_eqn)
+        actual = ju.out_shardings_of(jit_eqn)
         leaves_p = jax.tree_util.tree_flatten_with_path(te.expected_out)[0]
         if len(actual) != len(leaves_p):
             findings.append(make_finding(

@@ -221,14 +221,19 @@ class QASSO:
         tensors we scan block-by-block along the *unsharded* leading axis
         (a reshape(-1) would all-gather sharded axes), scoping temps to one
         block. No AD flows through optimizer statistics, so the scan costs
-        nothing in the backward."""
+        nothing in the backward.
+
+        The clipped magnitudes here use `jnp.power`, not the quantizers'
+        exp·log form: with the latter, the 1-device and 4-device CPU runs of
+        tests/test_sharded_training.py part ways at the first stage
+        boundary, where these stats feed knife-edge decisions."""
 
         def stats_of(ws, gs, rs):
             ws = ws.astype(jnp.float32)
             gs = gs.astype(jnp.float32)
             sign = jnp.sign(ws)
-            clipv = sign * Q.clip_qmt(jnp.abs(ws), qm, t)
-            resv = sign * Q.residual(jnp.abs(ws), d0, qm, t)
+            clipv = sign * Q.clip_qmt(jnp.abs(ws), qm, t, jnp.power)
+            resv = sign * Q.residual(jnp.abs(ws), d0, qm, t, jnp.power)
             return jnp.stack([
                 jnp.sum(rs * gs * clipv),
                 jnp.sum(rs * gs * resv),

@@ -102,16 +102,31 @@ def step_size_bounds(
     return d_min, d_max
 
 
-def clip_qmt(x_abs: jax.Array, q_m: jax.Array, t: jax.Array) -> jax.Array:
-    """clip_{q_m}^t(|x|) of Eq (13) — the nonlinear clipped magnitude."""
+def pos_pow(base: jax.Array, t: jax.Array) -> jax.Array:
+    """base ** t for base > 0, as exp(t * log(base)) — the form the Pallas
+    kernels use. `jnp.power` with a traced float exponent lowers to XLA's
+    general pow (negative bases, integer exponents); fused into the
+    operand of a weight GEMM it made the TPU compiler take 10-20x longer
+    on every fake-quantized program (a 3-layer internlm2-1.8b prefill
+    compiled for v5e in 46 s, 3.8 s with this form)."""
+    return jnp.exp(t * jnp.log(base))
+
+
+def clip_qmt(x_abs: jax.Array, q_m: jax.Array, t: jax.Array,
+             power=pos_pow) -> jax.Array:
+    """clip_{q_m}^t(|x|) of Eq (13) — the nonlinear clipped magnitude.
+    `power(base, t)` raises the clamped magnitude: `pos_pow` for the
+    quantizers, `jnp.power` for QASSO's site statistics
+    (`QASSO._site_stats_chunked` says why)."""
     q_m = jnp.maximum(q_m, _EPS)
     a = jnp.minimum(x_abs, q_m)
-    return jnp.power(jnp.maximum(a, _EPS), t) * (x_abs > 0)
+    return power(jnp.maximum(a, _EPS), t) * (x_abs > 0)
 
 
-def residual(x_abs: jax.Array, d: jax.Array, q_m: jax.Array, t: jax.Array) -> jax.Array:
+def residual(x_abs: jax.Array, d: jax.Array, q_m: jax.Array, t: jax.Array,
+             power=pos_pow) -> jax.Array:
     """R(x) of Eq (14): round(x~/d) - x~/d for the clipped magnitude."""
-    xt = clip_qmt(x_abs, q_m, t)
+    xt = clip_qmt(x_abs, q_m, t, power)
     r = xt / jnp.maximum(d, _EPS)
     return jnp.round(r) - r
 
@@ -165,7 +180,7 @@ def _fake_quant_bwd(res, g):
 
     # --- Eq (5): dx_Q/dt = sgn(x) * clip^t * log(clip_base)
     base = jnp.where(inside, safe_ax, qm32)
-    dt_elem = sign * jnp.power(base, t32) * jnp.log(base)
+    dt_elem = sign * pos_pow(base, t32) * jnp.log(base)
     dt = jnp.sum(g32 * dt_elem).astype(jnp.float32)
 
     # --- Eq (6): dx_Q/dq_m = 0 inside, sgn(x)*t*q_m^{t-1} outside.

@@ -11,30 +11,11 @@ Gradient compression (int8 + per-block scales, error feedback):
 """
 from __future__ import annotations
 
-import functools
-import inspect
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # JAX >= 0.6 exports shard_map at the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # older JAX: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-compatible shard_map: the replication-check kwarg was renamed
-    (check_rep -> check_vma) across JAX releases; forward whichever the
-    installed version accepts."""
-    params = inspect.signature(_shard_map).parameters
-    if "check_vma" in params:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_vma)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
 
 
 def replicate_stats(mesh: Mesh | None):
@@ -130,8 +111,8 @@ def compressed_grad_allreduce(grads: Any, mesh: Mesh,
         return jax.tree_util.tree_map(red, gs)
 
     specs = jax.tree_util.tree_map(lambda _: P(), sent)
-    reduced = shard_map(reduce_fn, mesh=mesh, in_specs=(specs,),
-                        out_specs=specs, check_vma=False)(sent)
+    reduced = jax.shard_map(reduce_fn, mesh=mesh, in_specs=(specs,),
+                            out_specs=specs, check_vma=False)(sent)
     mean = jax.tree_util.tree_map(lambda g: g / k, reduced)
     return mean, new_ef
 

@@ -167,14 +167,30 @@ def serving_param_specs(plan: ShardingPlan, params_axes: dict[str, tuple],
     genuinely TP-shardable axis (q/kv heads, mlp hidden, vocab_out) goes
     through the same `spec_for` divisibility-checked rules training uses,
     so a pruned width that stops dividing the mesh falls back to
-    replication instead of crashing — recorded in `plan.fallbacks`."""
+    replication instead of crashing — recorded in `plan.fallbacks`.
+
+    A row-parallel projection (o-proj, down-proj: a sharded contraction
+    axis feeding the residual `embed`) shards its output columns
+    instead. Split along K, the dot's partial sums would meet in an
+    all-reduce (in the activation dtype, in an order no single device
+    shares), and tp>1 decode would drift from tp=1; split along N, every
+    output element is one device's full-K dot, and only exact
+    all-gathers move activations."""
     specs = {}
     for name, leaf in params.items():
         ax = serving_axes_for(name, params_axes)
         if ax is None or len(ax) != np.ndim(leaf):
             specs[name] = P()
-        else:
-            specs[name] = plan.spec_for(name, tuple(ax), np.shape(leaf))
+            continue
+        spec = plan.spec_for(name, tuple(ax), np.shape(leaf))
+        if len(ax) >= 2 and ax[-1] == "embed" and spec[-2] is not None \
+                and spec[-1] is None:
+            mesh_ax = spec[-2]
+            names = (mesh_ax,) if isinstance(mesh_ax, str) else mesh_ax
+            size = int(np.prod([plan.mesh.shape[a] for a in names]))
+            spec = P(*spec[:-2], None,
+                     mesh_ax if np.shape(leaf)[-1] % size == 0 else None)
+        specs[name] = spec
     return specs
 
 

@@ -6,26 +6,36 @@ Decode-time attention is the one hot op the kernel backend didn't own:
 slots with `jnp.where`, and softmaxes — three HBM round-trips of an array
 that grows with max_seq. This kernel streams each cache row once:
 
-  grid = (batch-slot b, KV head h, split-K chunk c)   — c innermost
+  grid = (batch-slot b, split-K chunk c)   — c innermost
 
-Chunk programs for one (b, h) run consecutively (the same accumulator
+Every program takes *all* KV heads of its chunk: the arena
+(B, S, KVh, dh) is viewed as (B, S, KVh * dh) — a free row-major
+reshape — and one (chunk, KVh * dh) block streams per step. Its last two
+dims are (8, 128)-aligned (chunk) or equal to the array's (KVh * dh), so
+the block is legal for the TPU lowering at any head count and head
+width; the per-head (chunk, dh) tiles are static lane slices in VMEM.
+The q / output blocks are (KVh, gp, dh), again whole in their last two
+dims. The slot's valid length rides in SMEM through scalar prefetch
+(`PrefetchScalarGridSpec`), never as a (1, 1) VMEM block.
+
+Chunk programs for one slot run consecutively (the same accumulator
 pattern `gemm_core` uses for its K axis), carrying the online-softmax
-state across chunks in VMEM scratch:
+state across chunks in VMEM scratch, per KV head:
 
-  m  (g, LANES) f32   running row max of the scores
-  l  (g, LANES) f32   running softmax denominator
+  m  (KVh, gp, LANES) f32   running row max of the scores
+  l  (KVh, gp, LANES) f32   running softmax denominator
   o  = the f32 output block itself, holding the *unnormalized*
        rescaled accumulator until the last chunk divides by l.
 
-Per chunk: s = q @ k_chunk^T / sqrt(dh), masked to the slot's valid
-length; m_new = max(m, max(s)); both l and o are rescaled by
+Per chunk and head: s = q @ k_chunk^T / sqrt(dh), masked to the slot's
+valid length; m_new = max(m, max(s)); both l and o are rescaled by
 exp(m - m_new) before accumulating exp(s - m_new) — the standard
 flash-attention cross-chunk combine, so any chunking of the cache length
 produces the same softmax (the chunk-count invariance test pins this).
 
-GQA: all g = H // KVh query groups of one KV head are computed by a
-single program as the (g, dh) LHS of both GEMMs, so the kv tile is read
-once per head, not once per query head.
+GQA: all g = H // KVh query groups of one KV head are the (gp, dh) LHS
+of both GEMMs, so each kv tile is read once per head, not once per
+query head.
 
 Valid-length / ring-window masking lives *inside* the kernel: a slot at
 position `pos` has written exactly n_valid = min(pos + 1, S) arena rows —
@@ -34,7 +44,8 @@ windowed (ring_len = S) arena. Attention is permutation-invariant over
 KV rows, so the ring's scrambled storage order needs no unscrambling;
 columns >= n_valid are masked to -1e30 and chunks that start at or past
 n_valid are skipped entirely (`@pl.when`), so scores for unwritten rows
-are never computed.
+are never computed. Their block index clamps to the slot's last valid
+chunk, so the pipeline does not fetch them either.
 """
 from __future__ import annotations
 
@@ -60,64 +71,81 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+def _init_state(o_ref, m_ref, l_ref):
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _head_update(h, q, kt, vt, col0, n_valid, scale, o_ref, m_ref, l_ref):
+    """One online-softmax step of KV head `h` over a (chunk, dh) tile."""
+    s = jax.lax.dot_general(
+        q, kt, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale      # (gp, chunk)
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + col0
+    s = jnp.where(col < n_valid, s, _NEG_INF)
+    m_prev = m_ref[h, :, :1]                             # (gp, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)                               # (gp, chunk)
+    l_new = l_ref[h, :, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p, vt, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)              # (gp, dh)
+    o_ref[0, h] = o_ref[0, h] * alpha + pv
+    m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+    l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+
+def _finalize(o_ref, l_ref):
+    o_ref[0] = o_ref[0] / jnp.maximum(l_ref[:, :, :1], 1e-30)
+
+
 def _kernel(nv_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
-            chunk: int, nchunks: int, scale: float):
-    c = pl.program_id(2)
+            chunk: int, nchunks: int, scale: float, kvh: int, dh: int):
+    b = pl.program_id(0)
+    c = pl.program_id(1)
 
     @pl.when(c == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        o_ref[...] = jnp.zeros_like(o_ref)
+        _init_state(o_ref, m_ref, l_ref)
 
-    n_valid = nv_ref[0, 0]
+    n_valid = nv_ref[b]
 
     @pl.when(c * chunk < n_valid)
     def _chunk():
-        q = q_ref[0, 0].astype(jnp.float32)              # (g, dh)
-        kt = k_ref[0, :, 0, :].astype(jnp.float32)       # (chunk, dh)
-        s = jax.lax.dot_general(
-            q, kt, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (g, chunk)
-        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + c * chunk
-        s = jnp.where(col < n_valid, s, _NEG_INF)
-        m_prev = m_ref[:, :1]                            # (g, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                           # (g, chunk)
-        l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        vt = v_ref[0, :, 0, :].astype(jnp.float32)       # (chunk, dh)
-        pv = jax.lax.dot_general(
-            p, vt, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (g, dh)
-        o_ref[0, 0] = o_ref[0, 0] * alpha + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        for h in range(kvh):
+            q = q_ref[0, h].astype(jnp.float32)                   # (gp, dh)
+            kt = k_ref[0, :, h * dh:(h + 1) * dh].astype(jnp.float32)
+            vt = v_ref[0, :, h * dh:(h + 1) * dh].astype(jnp.float32)
+            _head_update(h, q, kt, vt, c * chunk, n_valid, scale,
+                         o_ref, m_ref, l_ref)
 
     @pl.when(c == nchunks - 1)
     def _final():
-        o_ref[0, 0] = o_ref[0, 0] / jnp.maximum(l_ref[:, :1], 1e-30)
+        _finalize(o_ref, l_ref)
 
 
-def plan_tiles(g: int, dh: int, S: int, chunk: int | None = None, *,
-               align: int = 128) -> tuple[int, int, int]:
-    """Resolve the (gp, dhp, chunk) tile geometry the flash-decode kernel
-    launches for a (g, dh, S) problem at the given lane alignment (128
-    compiled, 8 interpret). Shared by `decode_attn_pallas` and the static
-    VMEM model (`kernels.introspect`) so they cannot drift."""
+def plan_tiles(g: int, S: int, chunk: int | None = None, *,
+               align: int = 128) -> tuple[int, int]:
+    """Resolve the (gp, chunk) tile geometry the flash-decode kernel
+    launches for g query groups over an S-row arena: the query-group rows
+    pad to the 8-sublane tile, and the chunk aligns to `align` rows (128
+    compiled, 8 interpret). The head width is never padded: blocks take
+    whole rows of KVh * dh lanes. Shared by `decode_attn_pallas` and the
+    static VMEM model (`kernels.introspect`) so they cannot drift."""
     chunk = int(chunk or DEFAULT_CHUNK)
     chunk = max(align, min(_round_up(chunk, align), _round_up(S, align)))
-    return _round_up(g, 8), _round_up(dh, align), chunk
+    return _round_up(g, 8), chunk
 
 
-def plan_paged_tiles(g: int, dh: int, dhs: int, kv_bits: int | None, *,
-                     align: int = 128) -> tuple[int, int, int]:
-    """(gp, dhp, dhsp) for the paged kernel: the stored byte width `dhs`
-    pads to the lane tile and nibble unpack doubles it back to >= dh.
-    Shared with the static VMEM model like `plan_tiles`."""
-    dhsp = _round_up(dhs, align)
-    dhp = dhsp * 2 if kv_bits == 4 else dhsp
-    return _round_up(g, 8), dhp, dhsp
+def plan_paged_tiles(g: int, dh: int, dhs: int, kv_bits: int | None) -> int:
+    """gp for the paged kernel, whose chunk is the page: the stored byte
+    width `dhs` (dh, or dh // 2 nibble pairs at kv_bits=4) streams
+    unpadded and nibble unpack doubles it back to dh in VMEM. Shared with
+    the static VMEM model like `plan_tiles`."""
+    assert dhs == (dh // 2 if kv_bits == 4 else dh), (dh, dhs, kv_bits)
+    return _round_up(g, 8)
 
 
 def decode_attn_pallas(q, k, v, pos, *, window: int = 0,
@@ -144,38 +172,50 @@ def decode_attn_pallas(q, k, v, pos, *, window: int = 0,
     S = k.shape[1]
     scale = 1.0 / math.sqrt(dh)
 
-    # Compiled TPU tiles want 128-lane alignment; the interpreter (CPU
+    # Compiled TPU blocks want 128-row chunks; the interpreter (CPU
     # parity tier) runs any shape, so it may tile at the 8-sublane floor.
     align = 8 if interpret else 128
-    gp, dhp, chunk = plan_tiles(g, dh, S, chunk, align=align)
+    gp, chunk = plan_tiles(g, S, chunk, align=align)
     Sp = _round_up(S, chunk)
-
-    qp = jnp.pad(q, ((0, 0), (0, 0), (0, gp - g), (0, dhp - dh)))
-    kp = jnp.pad(k, ((0, 0), (0, Sp - S), (0, 0), (0, dhp - dh)))
-    vp = jnp.pad(v, ((0, 0), (0, Sp - S), (0, 0), (0, dhp - dh)))
-    nv = jnp.minimum(jnp.asarray(pos, jnp.int32).reshape(B, 1) + 1, S)
-
     nchunks = Sp // chunk
-    grid = (B, KVh, nchunks)
+
+    qp = jnp.pad(q, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
+    kp = jnp.pad(k, ((0, 0), (0, Sp - S), (0, 0), (0, 0)))
+    vp = jnp.pad(v, ((0, 0), (0, Sp - S), (0, 0), (0, 0)))
+    kp = kp.reshape(B, Sp, KVh * dh)
+    vp = vp.reshape(B, Sp, KVh * dh)
+    nv = jnp.minimum(jnp.asarray(pos, jnp.int32).reshape(B) + 1, S)
+
+    def qmap(b, c, nv_ref):
+        return (b, 0, 0, 0)
+
+    def kvmap(b, c, nv_ref):
+        # chunks past the slot's valid rows are skipped by the kernel;
+        # pinning their block to the last valid chunk skips the fetch
+        return (b, jnp.minimum(c, (nv_ref[b] - 1) // chunk), 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, nchunks),
+        in_specs=[
+            pl.BlockSpec((1, KVh, gp, dh), qmap),
+            pl.BlockSpec((1, chunk, KVh * dh), kvmap),
+            pl.BlockSpec((1, chunk, KVh * dh), kvmap),
+        ],
+        out_specs=pl.BlockSpec((1, KVh, gp, dh), qmap),
+        scratch_shapes=[
+            pltpu.VMEM((KVh, gp, _LANES), jnp.float32),   # running max m
+            pltpu.VMEM((KVh, gp, _LANES), jnp.float32),   # running denom l
+        ],
+    )
     out = pl.pallas_call(
         functools.partial(_kernel, chunk=chunk, nchunks=nchunks,
-                          scale=scale),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, c: (b, 0)),
-            pl.BlockSpec((1, 1, gp, dhp), lambda b, h, c: (b, h, 0, 0)),
-            pl.BlockSpec((1, chunk, 1, dhp), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, chunk, 1, dhp), lambda b, h, c: (b, c, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, gp, dhp), lambda b, h, c: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KVh, gp, dhp), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((gp, _LANES), jnp.float32),   # running max m
-            pltpu.VMEM((gp, _LANES), jnp.float32),   # running denom l
-        ],
+                          scale=scale, kvh=KVh, dh=dh),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KVh, gp, dh), jnp.float32),
         interpret=interpret,
     )(nv, qp, kp, vp)
-    return out[:, :, :g, :dh]
+    return out[:, :, :g]
 
 
 def tp_decode_attn(q, k, v, pos, *, mesh, axis: str = "model",
@@ -193,9 +233,8 @@ def tp_decode_attn(q, k, v, pos, *, mesh, axis: str = "model",
 
     Like `tp_gemm`, the kernel call sits *inside* shard_map where it is
     partitioned already, so the default backend is
-    `dispatch.shard_local_default()` — TPU hosts keep the fused kernel
+    `dispatch.platform_default()` — TPU hosts keep the fused kernel
     under TP instead of the mesh-demoted einsum path."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels import ops as _ops
@@ -206,17 +245,17 @@ def tp_decode_attn(q, k, v, pos, *, mesh, axis: str = "model",
     if KVh % tp:
         raise ValueError(f"tp_decode_attn: KVh={KVh} must divide the "
                          f"{axis!r} axis size {tp}")
-    backend = backend or dispatch.shard_local_default()
+    backend = backend or dispatch.platform_default()
 
     def body(ql, kl, vl, pl_):
         return _ops.decode_attn_op(ql, kl, vl, pl_, window=window,
                                    chunk=chunk, backend=backend)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axis), P(None, None, axis), P(None, None, axis),
                   P()),
-        out_specs=P(None, axis), check_rep=False)(q, k, v, pos)
+        out_specs=P(None, axis), check_vma=False)(q, k, v, pos)
 
 
 def _page_dequant(w, scale, bits):
@@ -224,66 +263,53 @@ def _page_dequant(w, scale, bits):
 
     Mirrors `core.quant.kv_quant_decode` on a 2D tile: arithmetic-shift
     nibble unpack for bits=4 (low nibble in byte order first), then the
-    per-row scale. Zero codes with zero scale stay exact zeros, so pool
-    padding and zero-page rows contribute nothing to the dot products.
+    per-row scale ((P, 1)). Zero codes with zero scale stay exact zeros,
+    so pool padding and zero-page rows contribute nothing to the dot
+    products.
     """
     x = w.astype(jnp.int32)
     if bits == 4:
         lo = (x << 28) >> 28
         hi = (x << 24) >> 28
         x = jnp.stack([lo, hi], axis=-1).reshape(x.shape[0], x.shape[1] * 2)
-    return x.astype(jnp.float32) * scale[:, None]
+    return x.astype(jnp.float32) * scale
 
 
 def _paged_kernel(nv_ref, pt_ref, q_ref, k_ref, v_ref, *rest,
-                  chunk: int, nchunks: int, scale: float, kv_bits):
+                  chunk: int, nchunks: int, scale: float, kv_bits,
+                  kvh: int, dhs: int):
     if kv_bits is not None:
         ks_ref, vs_ref, o_ref, m_ref, l_ref = rest
     else:
         ks_ref = vs_ref = None
         o_ref, m_ref, l_ref = rest
     b = pl.program_id(0)
-    c = pl.program_id(2)
+    c = pl.program_id(1)
 
     @pl.when(c == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        o_ref[...] = jnp.zeros_like(o_ref)
+        _init_state(o_ref, m_ref, l_ref)
 
     n_valid = nv_ref[b]
 
     @pl.when(c * chunk < n_valid)
     def _chunk():
-        q = q_ref[0, 0].astype(jnp.float32)              # (g, dh)
-        kt = k_ref[0, :, 0, :]                           # (chunk, dh*)
-        vt = v_ref[0, :, 0, :]
-        if kv_bits is not None:
-            kt = _page_dequant(kt, ks_ref[0, :, 0], kv_bits)
-            vt = _page_dequant(vt, vs_ref[0, :, 0], kv_bits)
-        else:
-            kt = kt.astype(jnp.float32)
-            vt = vt.astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, kt, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (g, chunk)
-        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + c * chunk
-        s = jnp.where(col < n_valid, s, _NEG_INF)
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, vt, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (g, dh)
-        o_ref[0, 0] = o_ref[0, 0] * alpha + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        for h in range(kvh):
+            q = q_ref[0, h].astype(jnp.float32)              # (gp, dh)
+            kt = k_ref[0, :, h * dhs:(h + 1) * dhs]          # (P, dhs)
+            vt = v_ref[0, :, h * dhs:(h + 1) * dhs]
+            if kv_bits is not None:
+                kt = _page_dequant(kt, ks_ref[0, :, h:h + 1], kv_bits)
+                vt = _page_dequant(vt, vs_ref[0, :, h:h + 1], kv_bits)
+            else:
+                kt = kt.astype(jnp.float32)
+                vt = vt.astype(jnp.float32)
+            _head_update(h, q, kt, vt, c * chunk, n_valid, scale,
+                         o_ref, m_ref, l_ref)
 
     @pl.when(c == nchunks - 1)
     def _final():
-        o_ref[0, 0] = o_ref[0, 0] / jnp.maximum(l_ref[:, :1], 1e-30)
+        _finalize(o_ref, l_ref)
 
 
 def paged_decode_attn_pallas(q, kpool, vpool, pos, page_table, *, page_size,
@@ -298,10 +324,13 @@ def paged_decode_attn_pallas(q, kpool, vpool, pos, page_table, *, page_size,
     codes of byte width dh / dh//2 for kv_bits 8 / 4 plus per-row scales
     k_scale/v_scale (n_pages, page_size, KVh) f32, decoded in VMEM right
     after the tile load — the KV analogue of the weight `unpack_dequant`
-    epilogue. page_table: (B, Lp) int32; both it and the per-slot valid
-    length ride in scalar-prefetch SMEM (`PrefetchScalarGridSpec`), so
-    the k/v BlockSpec index map can address tile (pt[b, c], h) directly
-    and only a slot's own pages ever stream into VMEM. Chunk = page_size
+    epilogue. Like the contiguous kernel, one program takes every KV head
+    of its page: the pool is viewed as (n_pages, page_size, KVh * dhs)
+    and the scales as (n_pages, page_size, KVh), blocks whole in their
+    last two dims. page_table: (B, Lp) int32; both it and the per-slot
+    valid length ride in scalar-prefetch SMEM (`PrefetchScalarGridSpec`),
+    so the k/v BlockSpec index map can address page pt[b, c] directly and
+    only a slot's own pages ever stream into VMEM. Chunk = page_size
     (must be a multiple of 8). `seq_len` is the logical arena length;
     masking is the same min(pos+1, seq_len) rule as the contiguous
     kernel. Returns (B, KVh, g, dh) f32.
@@ -314,53 +343,48 @@ def paged_decode_attn_pallas(q, kpool, vpool, pos, page_table, *, page_size,
     Lp = page_table.shape[1]
     if Lp * P < seq_len:
         raise ValueError(f"page table covers {Lp * P} rows < seq_len {seq_len}")
-    dhs = kpool.shape[-1]
+    n_pages, dhs = kpool.shape[0], kpool.shape[-1]
     scale = 1.0 / math.sqrt(dh)
-    align = 8 if interpret else 128
 
-    # Pad the code byte stream; nibble unpack doubles it back to >= dh.
-    gp, dhp, dhsp = plan_paged_tiles(g, dh, dhs, kv_bits, align=align)
-    qp = jnp.pad(q, ((0, 0), (0, 0), (0, gp - g), (0, dhp - dh)))
-    kp = jnp.pad(kpool, ((0, 0), (0, 0), (0, 0), (0, dhsp - dhs)))
-    vp = jnp.pad(vpool, ((0, 0), (0, 0), (0, 0), (0, dhsp - dhs)))
+    gp = plan_paged_tiles(g, dh, dhs, kv_bits)
+    qp = jnp.pad(q, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
+    kp = kpool.reshape(n_pages, P, KVh * dhs)
+    vp = vpool.reshape(n_pages, P, KVh * dhs)
     nv = jnp.minimum(jnp.asarray(pos, jnp.int32).reshape(B) + 1, seq_len)
     pt = jnp.asarray(page_table, jnp.int32)
 
-    def qmap(b, h, c, nv_ref, pt_ref):
-        return (b, h, 0, 0)
+    def qmap(b, c, nv_ref, pt_ref):
+        return (b, 0, 0, 0)
 
-    def kvmap(b, h, c, nv_ref, pt_ref):
-        return (pt_ref[b, c], 0, h, 0)
-
-    def smap(b, h, c, nv_ref, pt_ref):
-        return (pt_ref[b, c], 0, h)
+    def pagemap(b, c, nv_ref, pt_ref):
+        return (pt_ref[b, c], 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, gp, dhp), qmap),
-        pl.BlockSpec((1, P, 1, dhsp), kvmap),
-        pl.BlockSpec((1, P, 1, dhsp), kvmap),
+        pl.BlockSpec((1, KVh, gp, dh), qmap),
+        pl.BlockSpec((1, P, KVh * dhs), pagemap),
+        pl.BlockSpec((1, P, KVh * dhs), pagemap),
     ]
     operands = [qp, kp, vp]
     if kv_bits is not None:
-        in_specs += [pl.BlockSpec((1, P, 1), smap),
-                     pl.BlockSpec((1, P, 1), smap)]
+        in_specs += [pl.BlockSpec((1, P, KVh), pagemap),
+                     pl.BlockSpec((1, P, KVh), pagemap)]
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KVh, Lp),
+        grid=(B, Lp),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, gp, dhp), qmap),
+        out_specs=pl.BlockSpec((1, KVh, gp, dh), qmap),
         scratch_shapes=[
-            pltpu.VMEM((gp, _LANES), jnp.float32),
-            pltpu.VMEM((gp, _LANES), jnp.float32),
+            pltpu.VMEM((KVh, gp, _LANES), jnp.float32),
+            pltpu.VMEM((KVh, gp, _LANES), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, chunk=P, nchunks=Lp, scale=scale,
-                          kv_bits=kv_bits),
+                          kv_bits=kv_bits, kvh=KVh, dhs=dhs),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KVh, gp, dhp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, KVh, gp, dh), jnp.float32),
         interpret=interpret,
     )(nv, pt, *operands)
-    return out[:, :, :g, :dh]
+    return out[:, :, :g]

@@ -18,10 +18,12 @@ Selection order (first match wins):
      False -> pallas-tpu),
   3. process-wide override (`set_backend()` / `use_backend()` /
      ``REPRO_KERNEL_BACKEND`` env var),
-  4. platform default: pallas-tpu on TPU hosts, xla-ref elsewhere.
+  4. the mesh the traced program runs on (`on_mesh(mesh)`, see
+     `backend_for_mesh`),
+  5. platform default: pallas-tpu on TPU hosts, xla-ref elsewhere.
 
-This replaces the per-module ``_interpret_default()`` platform sniffing the
-three seed kernels each carried.
+The choice depends on the program's mesh, never on how many devices the
+host shows: a one-chip program on a four-chip host runs the kernels.
 """
 from __future__ import annotations
 
@@ -32,7 +34,7 @@ import jax
 
 BACKENDS = ("pallas-tpu", "pallas-interpret", "xla-ref")
 
-_state = {"override": None}
+_state = {"override": None, "mesh_default": None}
 
 
 def available_backends() -> tuple[str, ...]:
@@ -40,47 +42,38 @@ def available_backends() -> tuple[str, ...]:
 
 
 def platform_default() -> str:
-    """pallas-tpu on a single-device TPU host, xla-ref everywhere else.
-
-    Under a multi-device GSPMD mesh a pallas_call is an opaque custom call
-    with no partitioning rule — GSPMD would all-gather the full weight per
-    call — so sharded programs default to XLA's native (partitionable) dot
-    until the kernels grow shard_map integration. Override explicitly to
-    opt in."""
-    if jax.default_backend() == "tpu" and jax.device_count() == 1:
-        return "pallas-tpu"
-    return "xla-ref"
+    """pallas-tpu on a TPU host, xla-ref everywhere else (XLA's native
+    dot is the fastest correct implementation on CPU/GPU hosts)."""
+    return "pallas-tpu" if jax.default_backend() == "tpu" else "xla-ref"
 
 
 def backend_for_mesh(mesh) -> str:
-    """Mesh-aware backend pick for sharded programs.
+    """Backend for a program jitted over `mesh` (None = no mesh).
 
-    Any mesh spanning more than one device routes to the partitionable XLA
-    path regardless of platform — a pallas_call is an opaque custom call
-    with no GSPMD partitioning rule, so letting it into a sharded program
-    means a full-weight all-gather per call. A 1-device mesh (the parity
-    reference, or a single-TPU host) keeps the platform default so the MXU
-    kernels stay on the hot path."""
-    if mesh is None:
+    A mesh spanning more than one device routes to the partitionable XLA
+    path: a pallas_call is an opaque custom call with no GSPMD
+    partitioning rule, so inside a sharded program it would all-gather
+    the full weight per call. No mesh or a one-device mesh keeps the
+    platform default, so the MXU kernels stay on the hot path whatever
+    else the host holds. Kernels *inside* `shard_map` (`tp_gemm`,
+    `tp_decode_attn`) are already partitioned and take the platform
+    default directly."""
+    if mesh is None or mesh.size == 1:
         return platform_default()
-    size = getattr(mesh, "size", None)
-    if size is None:  # AbstractMesh on older JAX: fall back to axis product
-        size = 1
-        for s in dict(mesh.shape).values():
-            size *= int(s)
-    return "xla-ref" if size > 1 else platform_default()
+    return "xla-ref"
 
 
-def shard_local_default() -> str:
-    """Backend for kernels running *inside* `shard_map`.
-
-    Per-device code under shard_map is no longer opaque to GSPMD — the
-    partitioning already happened at the shard_map boundary — so the
-    device-count guard in `platform_default` doesn't apply: TPU hosts keep
-    the MXU Pallas kernels regardless of mesh size, everything else stays
-    on the XLA oracle. This is what the `tp_gemm` / `tp_decode_attn`
-    wrappers resolve when no explicit backend is passed."""
-    return "pallas-tpu" if jax.default_backend() == "tpu" else "xla-ref"
+@contextlib.contextmanager
+def on_mesh(mesh):
+    """Scope the default backend (rule 4) to `mesh` while a program is
+    traced — the trainer's and the TP engine's jits trace under it. An
+    explicit or process-wide choice still wins."""
+    prev = _state["mesh_default"]
+    _state["mesh_default"] = backend_for_mesh(mesh)
+    try:
+        yield
+    finally:
+        _state["mesh_default"] = prev
 
 
 def set_backend(name: str | None) -> None:
@@ -128,4 +121,4 @@ def resolve(backend: str | None = None,
             raise ValueError(f"REPRO_KERNEL_BACKEND={env!r} is not one of "
                              f"{BACKENDS}")
         return env
-    return platform_default()
+    return _state["mesh_default"] or platform_default()

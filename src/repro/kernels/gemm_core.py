@@ -296,13 +296,12 @@ def tp_gemm(x: jax.Array, w: jax.Array, rhs_ops: tuple[RhsOp, ...] = (), *,
     laid out column-sharded over `axis`.
 
     Unlike a bare `gemm` inside a sharded program (an opaque custom call
-    GSPMD would all-gather around — see `dispatch.platform_default`),
+    GSPMD would all-gather around — see `dispatch.backend_for_mesh`),
     the kernel here runs per device *inside* shard_map, so TPU hosts keep
     the MXU path under TP: the default backend is
-    `dispatch.shard_local_default()`, not the mesh-demoted default.
+    `dispatch.platform_default()`, not the mesh-demoted default.
     Block sizes resolve per *local* shape, so the autotune table tunes
     the (M, N/tp, K) shard, not the full width."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     tp = int(mesh.shape[axis])
@@ -311,7 +310,7 @@ def tp_gemm(x: jax.Array, w: jax.Array, rhs_ops: tuple[RhsOp, ...] = (), *,
     if N % tp:
         raise ValueError(f"tp_gemm: N={N} must divide the {axis!r} axis "
                          f"size {tp}")
-    backend = backend or dispatch.shard_local_default()
+    backend = backend or dispatch.platform_default()
 
     in_specs = [P(), P(None, axis)]
     operands = []
@@ -331,6 +330,6 @@ def tp_gemm(x: jax.Array, w: jax.Array, rhs_ops: tuple[RhsOp, ...] = (), *,
         return gemm(xl, wl, tuple(ops_l), blocks=blocks, backend=backend,
                     out_dtype=out_dtype)
 
-    return shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=P(None, axis), check_rep=False)(
-                         x, w, *operands)
+    return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=P(None, axis), check_vma=False)(
+                             x, w, *operands)

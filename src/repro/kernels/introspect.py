@@ -63,22 +63,26 @@ class GemmLaunch:
 
 @dataclasses.dataclass(frozen=True)
 class AttnLaunch:
-    """One flash-decode attention dispatch (contiguous or paged)."""
+    """One flash-decode attention dispatch (contiguous or paged). Each
+    grid step takes every KV head: q/out blocks (KVh, gp, dh), K/V blocks
+    (chunk, KVh * dhs) — see `kernels.decode_attn`."""
     kind: str                         # "decode_attn" | "paged_decode_attn"
     B: int
     KVh: int
     g: int                            # query heads per KV head
     dh: int
-    gp: int                           # padded block dims (compiled align)
-    dhp: int
+    gp: int                           # query-group rows padded to 8
+    dhs: int                          # stored K/V width per head (dh, or
+    #                                   dh // 2 nibble pairs at kv_bits=4)
     chunk: int                        # K rows per grid step (page_size
     #                                   for the paged kernel)
+    backend: str = "xla-ref"          # what this dispatch resolved to
     kv_itemsize: int = 4              # pool element bytes (1 for codes)
     scaled: bool = False              # per-row scale blocks ride along
 
     def describe(self) -> str:
         return (f"{self.kind} B={self.B} KVh={self.KVh} g={self.g} "
-                f"dh={self.dh} tile gp={self.gp} dhp={self.dhp} "
+                f"dh={self.dh} tile gp={self.gp} dhs={self.dhs} "
                 f"chunk={self.chunk}")
 
 
@@ -127,17 +131,19 @@ def gemm_vmem_bytes(launch: GemmLaunch) -> int:
 
 
 def attn_vmem_bytes(launch: AttnLaunch) -> int:
-    """Estimated VMEM bytes for one flash-decode tile-program: q and out
-    blocks, double-buffered K/V chunk blocks (+ per-row scales when the
-    pool holds codes), the running max/denom scratch, and the (gp, chunk)
-    f32 probability tile the online softmax materializes per chunk."""
-    q_tile = launch.gp * launch.dhp * _F32
-    kv = 2 * launch.chunk * launch.dhp * launch.kv_itemsize
-    scales = 2 * launch.chunk * _F32 if launch.scaled else 0
-    out_tile = launch.gp * launch.dhp * _F32
-    scratch = 2 * launch.gp * _LANES * _F32
-    probs = launch.gp * launch.chunk * _F32
-    return 2 * (q_tile + kv + scales) + 2 * out_tile + scratch + probs
+    """Estimated VMEM bytes for one flash-decode tile-program: the
+    all-heads q and out blocks, double-buffered K/V chunk blocks (+ per-row
+    scales when the pool holds codes), the per-head running max/denom
+    scratch, and — one head at a time — the f32 (chunk, dh) K/V tiles the
+    kernel decodes and the (gp, chunk) probability tile."""
+    heads = launch.KVh
+    q_tile = heads * launch.gp * launch.dh * _F32
+    kv = 2 * launch.chunk * heads * launch.dhs * launch.kv_itemsize
+    scales = 2 * launch.chunk * heads * _F32 if launch.scaled else 0
+    out_tile = heads * launch.gp * launch.dh * _F32
+    scratch = 2 * heads * launch.gp * _LANES * _F32
+    per_head = (2 * launch.chunk * launch.dh + launch.gp * launch.chunk) * _F32
+    return 2 * (q_tile + kv + scales) + 2 * out_tile + scratch + per_head
 
 
 def launch_vmem_bytes(launch) -> int:

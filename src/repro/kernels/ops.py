@@ -167,10 +167,10 @@ def decode_attn_op(q, k, v, pos, *, window=0, chunk=None, interpret=None,
         # record the compiled-TPU tile geometry regardless of which
         # backend this trace routes to (see kernels.introspect)
         B, KVh, g, dh = q.shape
-        gp, dhp, ch = _da.plan_tiles(g, dh, k.shape[1], chunk)
+        gp, ch = _da.plan_tiles(g, k.shape[1], chunk)
         introspect.note(introspect.AttnLaunch(
-            kind="decode_attn", B=B, KVh=KVh, g=g, dh=dh, gp=gp, dhp=dhp,
-            chunk=ch, kv_itemsize=k.dtype.itemsize))
+            kind="decode_attn", B=B, KVh=KVh, g=g, dh=dh, gp=gp, dhs=dh,
+            chunk=ch, backend=backend, kv_itemsize=k.dtype.itemsize))
     if backend == "xla-ref":
         return _ref.decode_attn_ref(q, k, v, pos, window=window)
     return _da.decode_attn_pallas(q, k, v, pos, window=window, chunk=chunk,
@@ -196,11 +196,12 @@ def paged_decode_attn_op(q, kpool, vpool, pos, page_table, *, page_size,
     backend = dispatch.resolve(backend, interpret)
     if introspect.recording():
         B, KVh, g, dh = q.shape
-        gp, dhp, _ = _da.plan_paged_tiles(g, dh, kpool.shape[-1], kv_bits)
+        dhs = kpool.shape[-1]
+        gp = _da.plan_paged_tiles(g, dh, dhs, kv_bits)
         introspect.note(introspect.AttnLaunch(
             kind="paged_decode_attn", B=B, KVh=KVh, g=g, dh=dh, gp=gp,
-            dhp=dhp, chunk=int(page_size), kv_itemsize=kpool.dtype.itemsize,
-            scaled=kv_bits is not None))
+            dhs=dhs, chunk=int(page_size), backend=backend,
+            kv_itemsize=kpool.dtype.itemsize, scaled=kv_bits is not None))
     if backend == "xla-ref":
         return _ref.paged_decode_attn_ref(
             q, kpool, vpool, pos, page_table, page_size=page_size,

@@ -10,7 +10,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.core.quant import _EPS, clip_qmt
+from repro.core.quant import _EPS, clip_qmt, pos_pow
 
 
 def fake_quant_fwd_ref(x, d, q_m, t):
@@ -42,7 +42,7 @@ def fake_quant_bwd_ref(x, d, q_m, t, g):
     dd = jnp.sum(g32 * sign * (jnp.round(v) - v))
 
     base = jnp.where(inside, safe_ax, qm32)
-    dt = jnp.sum(g32 * sign * jnp.power(base, t32) * jnp.log(base))
+    dt = jnp.sum(g32 * sign * pos_pow(base, t32) * jnp.log(base))
 
     dqm = jnp.sum(
         g32 * jnp.where(inside, 0.0, sign * t32 * jnp.power(qm32, t32 - 1.0))

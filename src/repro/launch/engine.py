@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Optional
@@ -67,6 +68,7 @@ from repro.core.quant import kv_quant_decode, kv_quant_encode
 from repro.core.subnet import (compression_report, prepare_serving,
                                tree_bytes)
 from repro.data.synthetic import batch_for
+from repro.kernels import dispatch
 from repro.launch import paging
 from repro.models import layers as model_layers
 from repro.models.transformer import LM
@@ -172,11 +174,30 @@ class Engine:
 
         def _jit(fn, static_argnums=(), out_shardings=None):
             # every engine jit pins its output shardings under TP so the
-            # arena never silently de-shards between dispatches; without a
-            # mesh this is exactly jax.jit
-            if mesh_ is None or out_shardings is None:
+            # arena never silently de-shards between dispatches, and traces
+            # under the mesh's kernel backend (`dispatch.backend_for_mesh`)
+            # with the residual stream pinned replicated (`LM._constrain`:
+            # norms then reduce over whole rows, as on one device); without
+            # a mesh this is exactly jax.jit
+            if mesh_ is None:
                 return jax.jit(fn, static_argnums=static_argnums)
-            return jax.jit(fn, static_argnums=static_argnums,
+            lms = [lm] + ([draft.lm] if draft is not None else [])
+
+            @functools.wraps(fn)
+            def on_mesh(*args):
+                saved = [m.act_sharding for m in lms]
+                for m in lms:
+                    m.act_sharding = self._rep
+                try:
+                    with dispatch.on_mesh(mesh_):
+                        return fn(*args)
+                finally:
+                    for m, a in zip(lms, saved):
+                        m.act_sharding = a
+
+            if out_shardings is None:
+                return jax.jit(on_mesh, static_argnums=static_argnums)
+            return jax.jit(on_mesh, static_argnums=static_argnums,
                            out_shardings=out_shardings)
         self.paged = bool(paged)
         self.page_size = int(page_size)

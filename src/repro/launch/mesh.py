@@ -3,10 +3,6 @@
 A FUNCTION, not a module constant — importing this module never touches JAX
 device state, so tests/benches keep their 1-CPU view and only dryrun.py
 (which sets XLA_FLAGS first) sees 512 host devices.
-
-`make_mesh` is the version-compatible entry point: newer JAX grows an
-`axis_types=` kwarg (explicit-sharding work) whose Auto value matches the
-older default — pass it when supported, omit it when not.
 """
 from __future__ import annotations
 
@@ -15,21 +11,9 @@ import numpy as np
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types across JAX versions."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto (GSPMD-partitioned)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
-def abstract_mesh(shape, axes):
-    """jax.sharding.AbstractMesh across the signature change: newer JAX
-    takes (axis_sizes, axis_names); older takes ((name, size), ...) pairs."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -73,3 +57,19 @@ def make_tp_mesh(n: int, axes=("data", "model")):
         raise ValueError(f"requested {n} devices, host has {len(devs)}")
     return jax.sharding.Mesh(
         np.asarray(devs[:n]).reshape((1, n)), axes)
+
+
+def force_host_devices(n: int) -> None:
+    """Give XLA's CPU backend `n` devices, so the multi-device paths run
+    on a CPU host. Only where the program is held to the CPU
+    (``JAX_PLATFORMS=cpu``): an accelerator host builds its meshes from
+    the real chips, and the flag is never set there. Must run before the
+    first device query; an explicit device-count flag in ``XLA_FLAGS``
+    wins."""
+    import os
+    if n <= 1 or os.environ.get("JAX_PLATFORMS", "") != "cpu":
+        return
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={n}").strip()

@@ -532,10 +532,10 @@ def main():
                          "asserts decode tokens are identical to the "
                          "single-device engine")
     ap.add_argument("--devices", type=int, default=0,
-                    help="force N XLA host-platform devices (CPU only; "
-                         "sets --xla_force_host_platform_device_count "
-                         "before the backend initializes) so --tp runs "
-                         "on a single-CPU host")
+                    help="CPU runs (JAX_PLATFORMS=cpu) only: force N XLA "
+                         "host devices before the backend initializes so "
+                         "--tp runs on a single-CPU host; ignored on an "
+                         "accelerator host, whose chips form the mesh")
     ap.add_argument("--chunked-prefill", type=int, default=None,
                     metavar="CHUNK",
                     help="engine mode: split each prompt's prefill into "
@@ -556,16 +556,10 @@ def main():
                          "tokens are identical (the decode-attn CI smoke; "
                          "honors --compressed/--packed/--pruned)")
     args = ap.parse_args()
-    if args.devices and args.devices > 1:
-        # must land before the first backend touch; harmless if XLA is
-        # already up with enough devices, fatal (jax raises in
-        # make_tp_mesh) if it's up with fewer
-        import os
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + f" --xla_force_host_platform_device_count="
-                        f"{args.devices}").strip()
+    from repro.launch import compile_cache
+    from repro.launch.mesh import force_host_devices
+    force_host_devices(args.devices)
+    compile_cache.enable()
     if not args.decode_attn:
         from repro.models.layers import set_decode_attn
         set_decode_attn(False)

@@ -263,7 +263,6 @@ def make_ordered_loss_grads(lm, mesh, param_specs_tree=None,
         return lg
 
     from jax.sharding import PartitionSpec
-    from repro.distributed.collectives import shard_map
 
     def body(params, qparams, batch):
         if param_specs_tree is not None:
@@ -283,12 +282,12 @@ def make_ordered_loss_grads(lm, mesh, param_specs_tree=None,
 
     p_specs = (dict(param_specs_tree) if param_specs_tree is not None
                else PartitionSpec())
-    lg = shard_map(body, mesh=mesh,
-                   in_specs=(p_specs, PartitionSpec(),
-                             PartitionSpec(axis)),
-                   out_specs=(PartitionSpec(),
-                              (PartitionSpec(), PartitionSpec())),
-                   check_vma=False)
+    lg = jax.shard_map(body, mesh=mesh,
+                       in_specs=(p_specs, PartitionSpec(),
+                                 PartitionSpec(axis)),
+                       out_specs=(PartitionSpec(),
+                                  (PartitionSpec(), PartitionSpec())),
+                       check_vma=False)
     return lg
 
 
@@ -313,8 +312,9 @@ def make_sharded_geta_train_step(lm, qasso: QASSO, mesh, params, qparams, *,
       cooldown hard-zeroing are identical on every device — and identical
       to the 1-device run, since full-tensor reductions then happen
       locally in a mesh-size-invariant order;
-    - the kernel backend resolves mesh-aware (`dispatch.backend_for_mesh`):
-      >1 device routes GEMMs to the partitionable XLA path.
+    - the step traces under `dispatch.on_mesh(mesh)`: a mesh of more than
+      one device routes GEMMs to the partitionable XLA path, a one-device
+      mesh keeps the platform's kernels.
 
     Returns (jitted_step, (param_sh, qparam_sh, qstate_sh, batch_sh)).
     Callers `jax.device_put` the initial state and each batch with the
@@ -323,7 +323,7 @@ def make_sharded_geta_train_step(lm, qasso: QASSO, mesh, params, qparams, *,
     """
     import copy
 
-    from repro.kernels.dispatch import backend_for_mesh, use_backend
+    from repro.kernels.dispatch import on_mesh
 
     # the step closes over a COPY so the caller's qasso keeps working in
     # non-mesh contexts (replica_consistent pins stat layouts to `mesh`,
@@ -333,7 +333,6 @@ def make_sharded_geta_train_step(lm, qasso: QASSO, mesh, params, qparams, *,
                                             param_shardings)
     batch_sh = NamedSharding(mesh, batch_spec(mesh))
     rep = NamedSharding(mesh, P())
-    backend = backend_for_mesh(mesh)
 
     if deterministic:
         if microbatches > 1:
@@ -347,7 +346,7 @@ def make_sharded_geta_train_step(lm, qasso: QASSO, mesh, params, qparams, *,
                                      grad_slices=grad_slices)
 
         def step(params, qparams, qstate, batch):
-            with use_backend(backend):
+            with on_mesh(mesh):
                 loss, (gx, gq) = lg(params, qparams, batch)
                 params, qparams, qstate, metrics = qasso.update(
                     params, qparams, gx, gq, qstate)
@@ -360,7 +359,7 @@ def make_sharded_geta_train_step(lm, qasso: QASSO, mesh, params, qparams, *,
             grad_shardings=(p_sh, q_sh) if microbatches > 1 else None)
 
         def step(params, qparams, qstate, batch):
-            with use_backend(backend):
+            with on_mesh(mesh):
                 return base_step(params, qparams, qstate, batch)
 
     jstep = jax.jit(step,
@@ -500,11 +499,15 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--devices", type=int, default=None,
                     help="data-parallel mesh over the first N local devices "
-                         "(CPU hosts: also set XLA_FLAGS="
-                         "--xla_force_host_platform_device_count=N)")
+                         "(CPU runs: JAX_PLATFORMS=cpu forces N host "
+                         "devices)")
     ap.add_argument("--fsdp", action="store_true",
                     help="shard params/opt-state over the data axis")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    from repro.launch.mesh import force_host_devices
+    force_host_devices(args.devices or 0)
+    compile_cache.enable()
     mesh = None
     if args.devices:
         from repro.launch.mesh import make_subset_mesh

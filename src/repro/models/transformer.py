@@ -111,7 +111,8 @@ class LM:
         # Optional NamedSharding for the (B, S, D) residual stream. Without
         # this pin, GSPMD's fixed-point for the scan carry can settle on
         # (batch-replicated, D-model-sharded) — measured 16x activation
-        # blow-up on the 398B configs. Set by launch/dryrun/train.
+        # blow-up on the 398B configs. Set by launch/dryrun/train, and
+        # replicated while a tensor-parallel engine traces its jits.
         self.act_sharding = None
         # Optional dict name -> NamedSharding: pins fake-quantized weights
         # to their parameter sharding so the f32 quantization chain runs at
@@ -522,6 +523,7 @@ class LM:
         rope = (jnp.cos(ang)[:, None], jnp.sin(ang)[:, None])   # (B, 1, dh/2)
 
         def body(x, inp):
+            x = self._constrain(x)
             lp = inp["p"]
             cc = inp["c"]
             new_c = {}
@@ -556,7 +558,7 @@ class LM:
                         lp, qp_body, cfg, h, prefix=f"{pre}.rwkv", shapes=shp,
                         state=(cc[f"{pre}.tm_shift"], cc[f"{pre}.wkv"]))
                     new_c[f"{pre}.tm_shift"], new_c[f"{pre}.wkv"] = ns
-                x = x + mix
+                x = self._constrain(x + mix)
                 if sub.ffn == "none":
                     continue
                 h2 = Lyr.rmsnorm(x, lp[f"{pre}.norm2"], cfg.norm_eps)
@@ -570,7 +572,7 @@ class LM:
                         lp, qp_body, cfg, h2, prefix=f"{pre}.rwkv",
                         state=cc[f"{pre}.cm_shift"])
                     new_c[f"{pre}.cm_shift"] = ns
-                x = x + f
+                x = self._constrain(x + f)
             return x, new_c
 
         bp = self._block_params(params)
@@ -584,7 +586,7 @@ class LM:
                           for k in new_list[0]}
         else:
             x, new_caches = jax.lax.scan(body, x, {"p": bp, "c": caches})
-        x = Lyr.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        x = Lyr.rmsnorm(self._constrain(x), params["final_norm"], cfg.norm_eps)
         logits = self._head(params, x)
         if cfg.num_codebooks:
             B = logits.shape[0]
@@ -632,6 +634,7 @@ class LM:
         rope = (jnp.cos(ang), jnp.sin(ang))                 # (B, T, dh/2)
 
         def body(x, inp):
+            x = self._constrain(x)
             lp = inp["p"]
             cc = inp["c"]
             new_c = {}
@@ -643,7 +646,7 @@ class LM:
                     prefix=f"{pre}.attn", shapes=shp, chunked=True,
                     cache=(cc[f"{pre}.k"], cc[f"{pre}.v"], pos))
                 new_c[f"{pre}.k"], new_c[f"{pre}.v"], _ = nc
-                x = x + mix
+                x = self._constrain(x + mix)
                 if sub.ffn == "none":
                     continue
                 h2 = Lyr.rmsnorm(x, lp[f"{pre}.norm2"], cfg.norm_eps)
@@ -658,7 +661,7 @@ class LM:
                     f = Lyr.moe_apply(lp, qp_body, cfg, h2,
                                       prefix=f"{pre}.moe",
                                       full_capacity=True, shapes=shp)
-                x = x + f
+                x = self._constrain(x + f)
             return x, new_c
 
         bp = self._block_params(params)
@@ -674,7 +677,7 @@ class LM:
             x, new_caches = jax.lax.scan(body, x, {"p": bp, "c": caches})
         if last_logit_only:
             x = x[:, -1:]
-        x = Lyr.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        x = Lyr.rmsnorm(self._constrain(x), params["final_norm"], cfg.norm_eps)
         logits = self._head(params, x)
         return logits, new_caches
 
@@ -702,6 +705,7 @@ class LM:
         rope = Lyr.rope_tables(S, cfg.d_head, cfg.rope_theta)
 
         def body(x, inp):
+            x = self._constrain(x)
             lp = inp["p"]
             cc = inp["c"]
             new_c = {}
@@ -725,7 +729,7 @@ class LM:
                                                      prefix=f"{pre}.rwkv",
                                                      shapes=shp)
                     new_c[f"{pre}.tm_shift"], new_c[f"{pre}.wkv"] = ns
-                x = x + mix
+                x = self._constrain(x + mix)
                 if sub.ffn == "none":
                     continue
                 h2 = Lyr.rmsnorm(x, lp[f"{pre}.norm2"], cfg.norm_eps)
@@ -742,7 +746,7 @@ class LM:
                     f, ns = Lyr.rwkv_chanmix_apply(lp, qp_body, cfg, h2,
                                                    prefix=f"{pre}.rwkv")
                     new_c[f"{pre}.cm_shift"] = ns
-                x = x + f
+                x = self._constrain(x + f)
             return x, new_c
 
         bp = self._block_params(params)
@@ -758,7 +762,7 @@ class LM:
             x, new_caches = jax.lax.scan(body, x, {"p": bp, "c": caches})
         if last_logit_only:
             x = x[:, -1:]
-        x = Lyr.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        x = Lyr.rmsnorm(self._constrain(x), params["final_norm"], cfg.norm_eps)
         logits = self._head(params, x)
         if cfg.num_codebooks:
             B, St = logits.shape[:2]

@@ -136,8 +136,6 @@ class CellCost:
 
 def cost_from_compiled(compiled) -> CellCost:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older JAX wraps the dict in a list
-        ca = ca[0] if ca else {}
     coll = parse_collectives(compiled.as_text())
     ma = compiled.memory_analysis()
     return CellCost(

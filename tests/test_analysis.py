@@ -18,7 +18,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.analysis import jaxpr_utils as ju
 from repro.analysis import passes, registry, report, verify, vmem
-from repro.distributed.collectives import shard_map
 from repro.kernels import autotune, gemm_core, introspect
 from repro.launch.mesh import make_tp_mesh
 from repro.launch.scheduler import chunk_buckets, chunk_plan, \
@@ -42,13 +41,13 @@ def _entry(name, fn, args, kind="serving", group="test", expected_out=None,
 # --------------------------------------------------- jaxpr walk utilities
 def test_walk_finds_psum_inside_shard_map():
     mesh = make_tp_mesh(1)
-    f = jax.jit(shard_map(lambda x: jax.lax.psum(x, "model"),
-                          mesh=mesh, in_specs=P("model"), out_specs=P()))
+    f = jax.jit(jax.shard_map(lambda x: jax.lax.psum(x, "model"),
+                              mesh=mesh, in_specs=P("model"), out_specs=P()))
     jaxpr = jax.make_jaxpr(f)(jnp.ones((4,)))
-    hits = ju.find_prims(jaxpr, {"psum", "psum2"})
-    assert hits, "walk must descend through pjit into the shard_map body"
+    hits = ju.find_prims(jaxpr, passes.REDUCTION_PRIMS)
+    assert hits, "walk must descend through jit into the shard_map body"
     assert all(ju.in_shard_map(path) for _, path in hits)
-    assert "pjit" in hits[0][1]
+    assert "jit" in hits[0][1]
 
 
 def test_walk_descends_into_scan():
@@ -64,7 +63,7 @@ def test_walk_descends_into_scan():
 def test_outer_pjit_and_unspecified_out_shardings():
     f = jax.jit(lambda x: x * 2)
     jaxpr = jax.make_jaxpr(f)(jnp.ones((2,)))
-    eqn = ju.outer_pjit_eqn(jaxpr)
+    eqn = ju.outer_jit_eqn(jaxpr)
     assert eqn is not None
     outs = ju.out_shardings_of(eqn)
     assert len(outs) == 1 and ju.is_unspecified(outs[0])
@@ -72,7 +71,7 @@ def test_outer_pjit_and_unspecified_out_shardings():
     mesh = make_tp_mesh(1)
     sh = NamedSharding(mesh, P())
     g = jax.jit(lambda x: x * 2, out_shardings=sh)
-    eqn2 = ju.outer_pjit_eqn(jax.make_jaxpr(g)(jnp.ones((2,))))
+    eqn2 = ju.outer_jit_eqn(jax.make_jaxpr(g)(jnp.ones((2,))))
     outs2 = ju.out_shardings_of(eqn2)
     assert not ju.is_unspecified(outs2[0])
     assert ju.spec_of(outs2[0]) == P()
@@ -89,8 +88,8 @@ def test_collect_consts_sees_closure_capture():
 # ------------------------------------------------ pass 1: identity audit
 def test_identity_flags_injected_psum_in_serving():
     mesh = make_tp_mesh(1)
-    f = jax.jit(shard_map(lambda x: jax.lax.psum(x, "model"),
-                          mesh=mesh, in_specs=P("model"), out_specs=P()))
+    f = jax.jit(jax.shard_map(lambda x: jax.lax.psum(x, "model"),
+                              mesh=mesh, in_specs=P("model"), out_specs=P()))
     te = _entry("decode", f, (jnp.ones((4,)),), kind="serving")
     findings = passes.audit_identity([te])
     assert findings and findings[0].pass_name == "identity"
@@ -104,8 +103,8 @@ def test_identity_allows_training_all_gather_in_shard_map_only():
     def gather(x):
         return jax.lax.all_gather(x, "model")
 
-    f = jax.jit(shard_map(gather, mesh=mesh, in_specs=P("model"),
-                          out_specs=P(None, "model")))
+    f = jax.jit(jax.shard_map(gather, mesh=mesh, in_specs=P("model"),
+                              out_specs=P(None, "model")))
     te_train = _entry("train_step", f, (jnp.ones((4,)),), kind="training")
     assert passes.audit_identity([te_train]) == []
     # the same jaxpr viewed as a serving entry is a violation
@@ -115,8 +114,8 @@ def test_identity_allows_training_all_gather_in_shard_map_only():
 
 def test_identity_flags_training_psum_anywhere():
     mesh = make_tp_mesh(1)
-    f = jax.jit(shard_map(lambda x: jax.lax.psum(x, "model"),
-                          mesh=mesh, in_specs=P("model"), out_specs=P()))
+    f = jax.jit(jax.shard_map(lambda x: jax.lax.psum(x, "model"),
+                              mesh=mesh, in_specs=P("model"), out_specs=P()))
     te = _entry("train_step", f, (jnp.ones((4,)),), kind="training")
     findings = passes.audit_identity([te])
     assert findings and "psum" in findings[0].fid
@@ -265,7 +264,7 @@ def test_constants_audit_flags_megaconstant():
 
 
 def test_constants_audit_flags_f64_widen():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         f = jax.jit(lambda x: x.astype(jnp.float64).sum())
         te = _entry("decode", f, (jnp.ones((2,), jnp.float32),))
     findings = passes.audit_constants([te])
@@ -335,7 +334,7 @@ def test_insert_is_pinned_on_every_group(analysis_matrix):
     for t in traced:
         if not t.name.startswith("insert"):
             continue
-        eqn = ju.outer_pjit_eqn(t.jaxpr)
+        eqn = ju.outer_jit_eqn(t.jaxpr)
         assert eqn is not None, t.key
         outs = ju.out_shardings_of(eqn)
         assert outs and not any(ju.is_unspecified(s) for s in outs), t.key

@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.core.bops import LayerMacs, model_bops
 from repro.core.qadg import build_qadg
@@ -11,7 +11,7 @@ from repro.core.saliency import SaliencyConfig, global_redundancy_partition
 from repro.distributed.collectives import (_dequantize_blockwise,
                                            _quantize_blockwise)
 from repro.distributed.sharding import batch_spec, make_plan
-from repro.launch.mesh import abstract_mesh, make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.cnn import CNN, VGG7
 
 
@@ -30,7 +30,7 @@ def test_sharding_plan_divisibility_fallback():
 
 def test_sharding_plan_records_fallbacks():
     # fake a mesh-like object with a model axis of 16 via abstract mesh
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     plan = make_plan(mesh)
     spec = plan.spec_for("w", ("embed", "kv_heads"), (64, 24))
     # 24 % 16 != 0 -> fallback recorded, axis replicated
@@ -39,14 +39,14 @@ def test_sharding_plan_records_fallbacks():
 
 
 def test_fsdp_rules():
-    mesh = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    mesh = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     plan = make_plan(mesh, fsdp=True)
     spec = plan.spec_for("w", ("embed", "mlp"), (8192, 32768))
     assert spec == P(("pod", "data"), "model")
 
 
 def test_arch_overrides_respected():
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     plan = make_plan(mesh, overrides={"fsdp": True, "experts_axis": None,
                                       "expert_mlp_axis": "model",
                                       "base_optimizer": "momentum"})
@@ -56,7 +56,7 @@ def test_arch_overrides_respected():
 
 
 def test_batch_spec_sp():
-    mesh = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    mesh = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     assert batch_spec(mesh) == P(("pod", "data"))
     assert batch_spec(mesh, shard_seq=True) == P(None, ("pod", "data"))
 
@@ -74,7 +74,7 @@ def test_blockwise_quantization_error_bound():
 
 def test_compressed_psum_semantics():
     """compressed all-reduce ~= psum within int8 quantization error."""
-    from repro.distributed.collectives import compressed_psum, shard_map
+    from repro.distributed.collectives import compressed_psum
     n = len(jax.devices())
     mesh = make_mesh((n,), ("data",))
     x = jax.random.normal(jax.random.PRNGKey(1), (n, 512))
@@ -82,8 +82,8 @@ def test_compressed_psum_semantics():
     def f(xs):
         return compressed_psum(xs[0], "data")
 
-    out = shard_map(f, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
-                    check_vma=False)(x)
+    out = jax.shard_map(f, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
+                        check_vma=False)(x)
     expect = jnp.sum(x, axis=0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-2, atol=2e-2)

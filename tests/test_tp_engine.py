@@ -155,6 +155,29 @@ def test_serving_axes_for_suffixes():
     assert serving_axes_for("missing.codes", axes) is None
 
 
+def test_serving_param_specs_never_split_a_contraction():
+    """Row-parallel projections (o-proj, down-proj) shard their output
+    columns: split along K, GSPMD would sum partial dots across devices
+    and tp>1 logits would drift from tp=1. Shapes only, so an abstract
+    mesh stands in for four devices."""
+    mesh = jax.sharding.AbstractMesh((1, 4), ("data", "model"))
+    plan = make_plan(mesh, mode="tp")
+    axes = {"wq": ("layers", "embed", "q_heads"),
+            "wo": ("layers", "q_heads", "embed"),
+            "w_down": ("layers", "mlp", "embed"),
+            "w_odd": ("layers", "mlp", "embed")}
+    params = {"wq": np.zeros((2, 128, 64), np.float32),
+              "wo": np.zeros((2, 64, 128), np.float32),
+              "w_down.packed4": np.zeros((2, 32, 128), np.int32),
+              "w_odd": np.zeros((2, 64, 126), np.float32)}
+    specs = serving_param_specs(plan, axes, params)
+    assert tuple(specs["wq"]) == (None, None, "model")
+    assert tuple(specs["wo"]) == (None, None, "model")
+    assert tuple(specs["w_down.packed4"]) == (None, None, "model")
+    # an output width the mesh cannot divide replicates
+    assert tuple(specs["w_odd"]) == (None, None, None)
+
+
 @needs4
 def test_kv_cache_specs_head_axis():
     mesh = make_tp_mesh(4)
