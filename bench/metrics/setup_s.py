@@ -1,0 +1,3 @@
+"""Set-up: process start to the first timed request (host clock)."""
+def read(ctx):
+    return ctx["setup_s"]
