@@ -214,6 +214,7 @@ def decode_attn_pallas(q, k, v, pos, *, window: int = 0,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVh, gp, dh), jnp.float32),
         interpret=interpret,
+        name="flash_decode",
     )(nv, qp, kp, vp)
     return out[:, :, :g]
 
@@ -386,5 +387,6 @@ def paged_decode_attn_pallas(q, kpool, vpool, pos, page_table, *, page_size,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVh, gp, dh), jnp.float32),
         interpret=interpret,
+        name="flash_decode_paged",
     )(nv, pt, *operands)
     return out[:, :, :g]

@@ -127,6 +127,7 @@ def fake_quant_fwd_pallas(x, d, q_m, t, *, block=DEFAULT_BLOCK, interpret=False)
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         interpret=interpret,
+        name="fake_quant",
     )(xp, scal(d), scal(q_m), scal(t))
     return y[: x2.shape[0], : x2.shape[1]].reshape(orig_shape)
 
@@ -161,6 +162,7 @@ def fake_quant_bwd_pallas(x, d, q_m, t, g, *, block=DEFAULT_BLOCK,
             pl.BlockSpec((1, 128), lambda i, j: (i * gn + j, 0)),
         ),
         interpret=interpret,
+        name="fake_quant_grad",
     )(xp, scal(d), scal(q_m), scal(t), gp)
 
     dx = dx[: x2.shape[0], : x2.shape[1]].reshape(orig_shape)

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.quant import _EPS, clip_qmt, unpack_codes
-from repro.kernels import dispatch, introspect
+from repro.kernels import autotune, dispatch, introspect
 
 DEFAULT_BLOCKS = (128, 128, 128)  # bm, bn, bk
 
@@ -210,7 +210,6 @@ def gemm(x: jax.Array, w: jax.Array, rhs_ops: tuple[RhsOp, ...] = (), *,
     out_dtype = out_dtype or x.dtype
 
     if blocks is None:
-        from repro.kernels import autotune
         blocks = autotune.lookup(M, N, K, autotune.ops_key(rhs_ops),
                                  backend) or DEFAULT_BLOCKS
 
@@ -219,7 +218,6 @@ def gemm(x: jax.Array, w: jax.Array, rhs_ops: tuple[RhsOp, ...] = (), *,
         # the tile the compiled-TPU path would launch, recorded even when
         # this trace routes to xla-ref (CPU CI statically audits the TPU
         # footprint — see kernels.introspect)
-        from repro.kernels import autotune
         introspect.note(introspect.GemmLaunch(
             M=M, N=N, K=K, k_pack=k_pack,
             n_col=sum(k == COL for op in rhs_ops for k in op.kinds),
@@ -274,6 +272,7 @@ def gemm(x: jax.Array, w: jax.Array, rhs_ops: tuple[RhsOp, ...] = (), *,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         interpret=(backend == "pallas-interpret"),
+        name=f"gemm_core.{autotune.ops_key(rhs_ops)}",
     )(xp, wp, *operands)
     return y[:M, :N].astype(out_dtype)
 

@@ -46,6 +46,15 @@ Two orthogonal scaling axes ride on top (PR 9, DESIGN.md §4.12):
   active slots; the finished row hands off to a free slot through the
   engine's handoff queue exactly like a one-shot prefill row would.
 
+Measurement is built in and costs nothing without a profiler: every
+dispatch runs inside an `engine.*` `TraceAnnotation` (`_Span`) —
+`engine.iteration` (one `_window` or `step`), `engine.prefill` (rid,
+prompt_len), `engine.insert` (slot), `engine.decode` (k, slots) with its
+`.dispatch`, `.wait` and `.emit` children, `engine.finish` (rid) — which
+also keeps the `stats` counters; the decode jits wrap `LM.decode_step`
+in the `decode_step` name scope, under which the model scopes its parts
+(`proj`, `kv_write`, `attn_core`, `pointwise`) for the device trace.
+
 Smoke:
   PYTHONPATH=src python -m repro.launch.serve --smoke --compressed \
       --prompt-lens 12,5 --gen 8
@@ -62,6 +71,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import get_arch
 from repro.core.quant import kv_quant_decode, kv_quant_encode
@@ -92,12 +102,61 @@ class Request:
     tokens: list[int] = dataclasses.field(default_factory=list)
     slot: int = -1
     submit_t: float = 0.0
-    admit_t: float = 0.0
+    admit_t: float = 0.0    # end of the wait that yields the first token
     finish_t: float = 0.0
 
     @property
     def done(self) -> bool:
         return len(self.tokens) >= self.max_new_tokens
+
+
+class _Span:
+    """One engine span and its counters. The span is a `TraceAnnotation`
+    (on the profiler's clock, inert when no profiler runs); `dispatch`
+    calls a jitted function inside `<name>.dispatch` and adds the time
+    until it returns to `stats["dispatch_s"]`; `wait` blocks on a result
+    inside `<name>.wait` (and copies it to the host unless `fetch` is
+    off); `child` opens any other `<name>.<part>`. On exit `counter`, if
+    given, gets the time from entry to the end of the last wait, or to
+    the exit where nothing was waited on. Durations use `perf_counter`."""
+
+    __slots__ = ("stats", "name", "counter", "ann", "t0", "t_wait")
+
+    def __init__(self, stats: dict, name: str, counter: Optional[str],
+                 meta: dict):
+        self.stats, self.name, self.counter = stats, name, counter
+        self.ann = TraceAnnotation(name, **meta)
+
+    def __enter__(self) -> "_Span":
+        self.ann.__enter__()
+        self.t_wait = None
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self.counter is not None:
+            end = self.t_wait if self.t_wait is not None \
+                else time.perf_counter()
+            self.stats[self.counter] += end - self.t0
+        self.ann.__exit__(*exc)
+
+    def child(self, part: str) -> TraceAnnotation:
+        return TraceAnnotation(f"{self.name}.{part}")
+
+    def dispatch(self, fn, *args):
+        with self.child("dispatch"):
+            t = time.perf_counter()
+            out = fn(*args)
+            self.stats["dispatch_s"] += time.perf_counter() - t
+        return out
+
+    def wait(self, x, fetch: bool = True):
+        with self.child("wait"):
+            x = jax.block_until_ready(x)
+            if fetch:
+                x = jax.tree_util.tree_map(np.asarray, x)
+        self.t_wait = time.perf_counter()
+        return x
 
 
 class Engine:
@@ -249,11 +308,19 @@ class Engine:
         self.queue: deque[Request] = deque()
         self.done: dict[int, Request] = {}
         self._next_rid = 0
+        # counters; every duration is `perf_counter` seconds (see _Span):
+        # decode_s / prefill_s run from a dispatch to the end of its wait,
+        # dispatch_s sums every jitted call until it returns, engine_s the
+        # driven iterations (`_window`, `step`). decode_dispatches counts
+        # fused windows, single steps and speculative rounds;
+        # kv_rows_attended the cache rows each decode step attends, summed
+        # over active slots (a full-attention layer's rows)
         self.stats = {"decode_steps": 0, "decode_tokens": 0, "decode_s": 0.0,
+                      "decode_dispatches": 0, "kv_rows_attended": 0,
                       "prefills": 0, "prefill_tokens": 0, "prefill_s": 0.0,
                       "draft_prefills": 0, "draft_prefill_tokens": 0,
                       "draft_prefill_s": 0.0, "prefix_hits": 0,
-                      "admitted": 0, "evicted": 0,
+                      "evicted": 0, "dispatch_s": 0.0, "engine_s": 0.0,
                       "spec_steps": 0, "spec_drafted": 0, "spec_accepted": 0,
                       "prefill_chunks": 0, "chunked_prefills": 0,
                       "decode_steps_mid_prefill": 0}
@@ -359,7 +426,9 @@ class Engine:
             return jax.tree_util.tree_map(ins, caches, row)
 
         def _decode(params, qparams, caches, tok, pos):
-            logits, caches = lm.decode_step(params, qparams, caches, tok, pos)
+            with jax.named_scope("decode_step"):
+                logits, caches = lm.decode_step(params, qparams, caches,
+                                                tok, pos)
             nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
             return nxt, caches
 
@@ -370,8 +439,9 @@ class Engine:
             # the loop runs on-device and syncs once per window.
             def body(carry, _):
                 caches, tok, pos = carry
-                logits, caches = lm.decode_step(params, qparams, caches,
-                                                tok, pos)
+                with jax.named_scope("decode_step"):
+                    logits, caches = lm.decode_step(params, qparams, caches,
+                                                    tok, pos)
                 nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
                 return (caches, nxt[:, None], pos + 1), nxt
 
@@ -470,8 +540,10 @@ class Engine:
                 return _jit(cp, out_shardings=out_sh)
 
             def _decode_paged(params, qparams, caches, tok, pos, pt):
-                logits, caches = lm.decode_step(params, qparams, caches, tok,
-                                                pos, pages=_pages_view(pt))
+                with jax.named_scope("decode_step"):
+                    logits, caches = lm.decode_step(
+                        params, qparams, caches, tok, pos,
+                        pages=_pages_view(pt))
                 nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
                 return nxt, caches
 
@@ -481,8 +553,9 @@ class Engine:
 
                 def body(carry, _):
                     caches, tok, pos = carry
-                    logits, caches = lm.decode_step(params, qparams, caches,
-                                                    tok, pos, pages=pages)
+                    with jax.named_scope("decode_step"):
+                        logits, caches = lm.decode_step(
+                            params, qparams, caches, tok, pos, pages=pages)
                     nxt = jnp.argmax(logits[:, -1],
                                      axis=-1).astype(jnp.int32)
                     return (caches, nxt[:, None], pos + 1), nxt
@@ -681,6 +754,63 @@ class Engine:
         return (bool(self.queue) or self.n_active > 0
                 or self._prefill_job is not None or bool(self._handoff))
 
+    # ---------------------------------------------------------------- spans
+    def _span(self, name: str, counter: Optional[str] = None,
+              **meta) -> _Span:
+        return _Span(self.stats, name, counter, meta)
+
+    def _dispatch(self, name: str, fn, *args, **meta):
+        """One jitted call that nothing waits on, in its own span."""
+        with self._span(name, **meta) as sp:
+            return sp.dispatch(fn, *args)
+
+    def _run_prefill(self, req: Request):
+        """One-shot prefill of `req`'s prompt: (first token, row cache).
+        The request is stamped admitted at the end of the wait, inside
+        its `engine.prefill` span."""
+        S = int(req.prompt.size)
+        with self._span("engine.prefill", "prefill_s", rid=req.rid,
+                        prompt_len=S) as sp:
+            nxt, row = sp.dispatch(self._prefill, self.params, self.qparams,
+                                   jnp.asarray(req.prompt)[None])
+            first = int(sp.wait(nxt)[0])
+            req.admit_t = time.time()
+        self.stats["prefills"] += 1
+        self.stats["prefill_tokens"] += S
+        return first, row
+
+    def _run_prefill_draft(self, req: Request, insert, *args) -> None:
+        """The draft arena's one-shot prefill of `req` (at the draft's
+        sliced shapes), put in place by `insert(dcaches, row, *args)`. Its
+        wall time and tokens are draft work: they ride their own
+        counters, not the target prefill rate's."""
+        S = int(req.prompt.size)
+        with self._span("engine.prefill_draft", "draft_prefill_s",
+                        rid=req.rid, prompt_len=S) as sp:
+            drow = sp.dispatch(self._prefill_draft, self.draft.params,
+                               self.draft.qparams,
+                               jnp.asarray(req.prompt)[None])
+            self.dcaches = sp.dispatch(insert, self.dcaches, drow, *args)
+            sp.wait(jax.tree_util.tree_leaves(self.dcaches)[0], fetch=False)
+        self.stats["draft_prefills"] += 1
+        self.stats["draft_prefill_tokens"] += S
+
+    def _count_decode(self, steps: int, slots: list[int]) -> None:
+        """Counters of one decode dispatch that scores `steps` positions
+        in each of the active `slots`, from the host's positions before
+        it (no device read): a step at position p attends rows [0, p],
+        or the ring's rows once a windowed arena wraps."""
+        rows = (self.pos[slots].astype(np.int64)[:, None] + 1
+                + np.arange(steps)[None, :])
+        if self.lm.cfg.window > 0:
+            rows = np.minimum(rows, min(self.max_seq, self.lm.cfg.window))
+        self.stats["kv_rows_attended"] += int(rows.sum())
+        self.stats["decode_steps"] += steps
+        self.stats["decode_dispatches"] += 1
+
+    def _active_slots(self) -> list[int]:
+        return [s for s, r in enumerate(self.active) if r is not None]
+
     # ------------------------------------------------------------ lifecycle
     def _admit(self) -> int:
         """Prefill queued requests into free slots. Returns #admitted."""
@@ -706,38 +836,20 @@ class Engine:
                         break
                     admitted += int(got)
                     continue
-                t0 = time.time()
-                nxt, row = self._prefill(self.params, self.qparams,
-                                         jnp.asarray(req.prompt)[None])
-                first = int(jax.block_until_ready(nxt)[0])
-                self.stats["prefill_s"] += time.time() - t0
-                self.stats["prefills"] += 1
-                self.stats["prefill_tokens"] += int(req.prompt.size)
-                self.stats["admitted"] += 1
-                req.admit_t = time.time()
+                first, row = self._run_prefill(req)
                 req.tokens.append(first)
                 if req.done:    # one-token request: never occupies a slot
                     self._finish(req)
                     continue
-                self.caches = self._insert(self.caches, row, jnp.int32(slot))
+                self.caches = self._dispatch("engine.insert", self._insert,
+                                             self.caches, row,
+                                             jnp.int32(slot), slot=slot)
                 if self.draft is not None:
                     # the draft arena admits in lockstep: its own one-shot
-                    # prefill (at the draft's sliced shapes) into the same
-                    # slot, so both arenas agree on position bookkeeping
-                    # from the first speculative round. Its wall time and
-                    # tokens are draft work — they ride their own
-                    # counters, not the target prefill rate's
-                    t1 = time.time()
-                    drow = self._prefill_draft(self.draft.params,
-                                               self.draft.qparams,
-                                               jnp.asarray(req.prompt)[None])
-                    self.dcaches = self._insert_d(self.dcaches, drow,
-                                                  jnp.int32(slot))
-                    jax.block_until_ready(
-                        jax.tree_util.tree_leaves(self.dcaches)[0])
-                    self.stats["draft_prefill_s"] += time.time() - t1
-                    self.stats["draft_prefills"] += 1
-                    self.stats["draft_prefill_tokens"] += int(req.prompt.size)
+                    # prefill into the same slot, so both arenas agree on
+                    # position bookkeeping from the first speculative round
+                    self._run_prefill_draft(req, self._insert_d,
+                                            jnp.int32(slot))
                 self.pos[slot] = req.prompt.size
                 self.last_tok[slot] = first
                 req.slot = slot
@@ -760,9 +872,12 @@ class Engine:
         ids = np.full((m,), paging.ZERO_PAGE, np.int32)
         ids[:len(dirty)] = dirty
         ids = jnp.asarray(ids)
-        self.caches = self._zero_pages(self.caches, ids)
+        self.caches = self._dispatch("engine.zero_pages", self._zero_pages,
+                                     self.caches, ids)
         if self.dcaches is not None:
-            self.dcaches = self._zero_pages_d(self.dcaches, ids)
+            self.dcaches = self._dispatch("engine.zero_pages",
+                                          self._zero_pages_d, self.dcaches,
+                                          ids)
         self.alloc.mark_zeroed(dirty)
 
     def _reserve_pages(self, n: int, keep_last: bool = False) -> bool:
@@ -804,15 +919,9 @@ class Engine:
             elif prefilled is not None:
                 first = int(prefilled[0])
             else:
-                t0 = time.time()
-                nxt, _ = self._prefill(self.params, self.qparams,
-                                       jnp.asarray(req.prompt)[None])
-                first = int(jax.block_until_ready(nxt)[0])
-                self.stats["prefill_s"] += time.time() - t0
-                self.stats["prefills"] += 1
-                self.stats["prefill_tokens"] += S
-            self.stats["admitted"] += 1
-            req.admit_t = time.time()
+                first, _ = self._run_prefill(req)
+            if prefilled is None and ent is not None:
+                req.admit_t = time.time()   # a prefix hit waits on nothing
             req.tokens.append(first)
             self._finish(req)
             return False
@@ -831,11 +940,17 @@ class Engine:
             if partial:
                 src = jnp.int32(ent.tail_page)
                 dst = jnp.int32(owned[0])
-                self.caches = self._copy_page(self.caches, src, dst)
+                self.caches = self._dispatch("engine.copy_page",
+                                             self._copy_page, self.caches,
+                                             src, dst)
                 if self.dcaches is not None:
-                    self.dcaches = self._copy_page_d(self.dcaches, src, dst)
+                    self.dcaches = self._dispatch("engine.copy_page",
+                                                  self._copy_page_d,
+                                                  self.dcaches, src, dst)
             first = int(ent.first_token)
             self.stats["prefix_hits"] += 1
+            if prefilled is None:
+                req.admit_t = time.time()   # a prefix hit waits on nothing
         else:
             if not self._reserve_pages(npg_req):
                 return None
@@ -845,29 +960,14 @@ class Engine:
                 first, row = prefilled
                 first = int(first)
             else:
-                t0 = time.time()
-                nxt, row = self._prefill(self.params, self.qparams,
-                                         jnp.asarray(req.prompt)[None])
-                first = int(jax.block_until_ready(nxt)[0])
-                self.stats["prefill_s"] += time.time() - t0
-                self.stats["prefills"] += 1
-                self.stats["prefill_tokens"] += S
+                first, row = self._run_prefill(req)
             phys = jnp.asarray(np.asarray(pages[:npp], np.int32))
-            self.caches = self._insert_pages(self.caches, row,
-                                             jnp.int32(slot), phys, npp)
+            self.caches = self._dispatch("engine.insert", self._insert_pages,
+                                         self.caches, row, jnp.int32(slot),
+                                         phys, npp, slot=slot)
             if self.draft is not None:
-                t1 = time.time()
-                drow = self._prefill_draft(self.draft.params,
-                                           self.draft.qparams,
-                                           jnp.asarray(req.prompt)[None])
-                self.dcaches = self._insert_pages_d(self.dcaches, drow,
-                                                    jnp.int32(slot), phys,
-                                                    npp)
-                jax.block_until_ready(
-                    jax.tree_util.tree_leaves(self.dcaches)[0])
-                self.stats["draft_prefill_s"] += time.time() - t1
-                self.stats["draft_prefills"] += 1
-                self.stats["draft_prefill_tokens"] += S
+                self._run_prefill_draft(req, self._insert_pages_d,
+                                        jnp.int32(slot), phys, npp)
             if cache is not None:
                 # register the prompt for sharing (best effort): the
                 # cache takes its own refcount on the full pages and a
@@ -880,11 +980,13 @@ class Engine:
                 if partial and self.alloc.can_alloc(1):
                     tmpl = self.alloc.alloc(1)[0]
                     src = jnp.int32(pages[n_full])
-                    self.caches = self._copy_page(self.caches, src,
-                                                  jnp.int32(tmpl))
+                    self.caches = self._dispatch(
+                        "engine.copy_page", self._copy_page, self.caches,
+                        src, jnp.int32(tmpl))
                     if self.dcaches is not None:
-                        self.dcaches = self._copy_page_d(self.dcaches, src,
-                                                         jnp.int32(tmpl))
+                        self.dcaches = self._dispatch(
+                            "engine.copy_page", self._copy_page_d,
+                            self.dcaches, src, jnp.int32(tmpl))
                 if (n_full or tmpl is not None) and not (partial
                                                          and tmpl is None):
                     self.alloc.retain(pages[:n_full])
@@ -899,30 +1001,29 @@ class Engine:
         self.slot_pages[slot] = list(pages)
         self.pos[slot] = S
         self.last_tok[slot] = first
-        self.stats["admitted"] += 1
-        req.admit_t = time.time()
         req.tokens.append(first)
         req.slot = slot
         self.active[slot] = req
         return True
 
     def _finish(self, req: Request) -> None:
-        req.finish_t = time.time()
-        if req.slot >= 0:
-            if self.paged:
-                # eviction is a page release: refcounts drop, pages whose
-                # last owner left go to the dirty quarantine (zeroed at
-                # the next admission / drain), and the slot's table rows
-                # point back at the trash page so its idle decode writes
-                # can't touch live pages
-                self.alloc.release(self.slot_pages[req.slot])
-                self.slot_pages[req.slot] = []
-                self.page_table[req.slot, :] = paging.TRASH_PAGE
-                self.pos[req.slot] = 0
-            self.active[req.slot] = None
-            req.slot = -1
-            self.stats["evicted"] += 1
-        self.done[req.rid] = req
+        with self._span("engine.finish", rid=req.rid):
+            req.finish_t = time.time()
+            if req.slot >= 0:
+                if self.paged:
+                    # eviction is a page release: refcounts drop, pages
+                    # whose last owner left go to the dirty quarantine
+                    # (zeroed at the next admission / drain), and the
+                    # slot's table rows point back at the trash page so
+                    # its idle decode writes can't touch live pages
+                    self.alloc.release(self.slot_pages[req.slot])
+                    self.slot_pages[req.slot] = []
+                    self.page_table[req.slot, :] = paging.TRASH_PAGE
+                    self.pos[req.slot] = 0
+                self.active[req.slot] = None
+                req.slot = -1
+                self.stats["evicted"] += 1
+            self.done[req.rid] = req
 
     def step(self) -> bool:
         """One engine iteration, shaped by the scheduler policy: the
@@ -932,8 +1033,9 @@ class Engine:
         iteration, verbatim. Returns False when no action made progress
         (idle)."""
         progress = False
-        for act in self.scheduler.plan_step(self):
-            progress = bool(getattr(self, "_act_" + act)()) or progress
+        with self._span("engine.iteration", "engine_s"):
+            for act in self.scheduler.plan_step(self):
+                progress = bool(getattr(self, "_act_" + act)()) or progress
         return progress
 
     def _act_admit(self) -> bool:
@@ -949,31 +1051,34 @@ class Engine:
             return self._spec_round()
         tok = jnp.asarray(self.last_tok)[:, None]
         pos = jnp.asarray(self.pos)
-        t0 = time.time()
-        if self.paged:
-            nxt, self.caches = self._decode_paged(
-                self.params, self.qparams, self.caches, tok, pos,
-                jnp.asarray(self.page_table))
-        else:
-            nxt, self.caches = self._decode(self.params, self.qparams,
-                                            self.caches, tok, pos)
-        nxt = np.asarray(jax.block_until_ready(nxt))
-        self.stats["decode_s"] += time.time() - t0
-        self.stats["decode_steps"] += 1
-        if self._prefill_job is not None:
-            # the disaggregation liveness stat: decode batches that ran
-            # while a prompt was mid-prefill. The one-shot engine's value
-            # is identically zero — it cannot decode during a prefill.
-            self.stats["decode_steps_mid_prefill"] += 1
-        for slot, req in enumerate(self.active):
-            if req is None:
-                continue
-            self.stats["decode_tokens"] += 1
-            req.tokens.append(int(nxt[slot]))
-            self.last_tok[slot] = nxt[slot]
-            self.pos[slot] += 1
-            if req.done:
-                self._finish(req)
+        slots = self._active_slots()
+        with self._span("engine.decode", "decode_s", k=1,
+                        slots=len(slots)) as sp:
+            if self.paged:
+                nxt, self.caches = sp.dispatch(
+                    self._decode_paged, self.params, self.qparams,
+                    self.caches, tok, pos, jnp.asarray(self.page_table))
+            else:
+                nxt, self.caches = sp.dispatch(
+                    self._decode, self.params, self.qparams, self.caches,
+                    tok, pos)
+            nxt = sp.wait(nxt)
+            self._count_decode(1, slots)
+            if self._prefill_job is not None:
+                # the disaggregation liveness stat: decode batches that
+                # ran while a prompt was mid-prefill. The one-shot
+                # engine's value is identically zero — it cannot decode
+                # during a prefill.
+                self.stats["decode_steps_mid_prefill"] += 1
+            with sp.child("emit"):
+                for slot in slots:
+                    req = self.active[slot]
+                    self.stats["decode_tokens"] += 1
+                    req.tokens.append(int(nxt[slot]))
+                    self.last_tok[slot] = nxt[slot]
+                    self.pos[slot] += 1
+                    if req.done:
+                        self._finish(req)
         return True
 
     # ------------------------------------------------- chunked prefill path
@@ -1001,12 +1106,15 @@ class Engine:
         c = job.chunks.pop(0)
         toks = jnp.asarray(
             job.req.prompt[job.done_rows:job.done_rows + c])[None]
-        t0 = time.time()
-        nxt, job.caches = self._prefill_chunk(
-            self.params, self.qparams, job.caches, toks,
-            jnp.full((1,), job.done_rows, jnp.int32))
-        first = int(jax.block_until_ready(nxt)[0])
-        self.stats["prefill_s"] += time.time() - t0
+        with self._span("engine.prefill", "prefill_s", rid=job.req.rid,
+                        prompt_len=int(job.req.prompt.size),
+                        chunk=c) as sp:
+            nxt, job.caches = sp.dispatch(
+                self._prefill_chunk, self.params, self.qparams, job.caches,
+                toks, jnp.full((1,), job.done_rows, jnp.int32))
+            first = int(sp.wait(nxt)[0])
+            if not job.chunks:      # the last chunk yields the first token
+                job.req.admit_t = time.time()
         self.stats["prefill_chunks"] += 1
         job.done_rows += c
         if not job.chunks:
@@ -1046,8 +1154,6 @@ class Engine:
                     break
             elif req.max_new_tokens == 1:
                 # one-token request: the staged first token IS the answer
-                self.stats["admitted"] += 1
-                req.admit_t = time.time()
                 req.tokens.append(int(first))
                 self._finish(req)
             else:
@@ -1064,24 +1170,14 @@ class Engine:
         """Contiguous-arena tail of admission from a staged row cache:
         the one-shot path's post-prefill bookkeeping, reused verbatim by
         the handoff queue."""
-        self.caches = self._insert(self.caches, row, jnp.int32(slot))
+        self.caches = self._dispatch("engine.insert", self._insert,
+                                     self.caches, row, jnp.int32(slot),
+                                     slot=slot)
         if self.draft is not None:
             # the draft arena still prefills one-shot at handoff (its
             # sliced shapes make this the cheap half); chunking the draft
             # too would need a second staging row per job
-            t1 = time.time()
-            drow = self._prefill_draft(self.draft.params,
-                                       self.draft.qparams,
-                                       jnp.asarray(req.prompt)[None])
-            self.dcaches = self._insert_d(self.dcaches, drow,
-                                          jnp.int32(slot))
-            jax.block_until_ready(
-                jax.tree_util.tree_leaves(self.dcaches)[0])
-            self.stats["draft_prefill_s"] += time.time() - t1
-            self.stats["draft_prefills"] += 1
-            self.stats["draft_prefill_tokens"] += int(req.prompt.size)
-        self.stats["admitted"] += 1
-        req.admit_t = time.time()
+            self._run_prefill_draft(req, self._insert_d, jnp.int32(slot))
         req.tokens.append(first)
         self.pos[slot] = req.prompt.size
         self.last_tok[slot] = first
@@ -1118,38 +1214,40 @@ class Engine:
         k = pow2_floor(min(self.draft_k, rem - 1))
         tok = jnp.asarray(self.last_tok)[:, None]
         pos = jnp.asarray(self.pos)
-        t0 = time.time()
-        if self.paged:
-            tgt, ncm, self.caches, self.dcaches = self._spec_paged(
-                self.params, self.qparams, self.draft.params,
-                self.draft.qparams, self.caches, self.dcaches, tok, pos,
-                jnp.asarray(self.page_table), k)
-        else:
-            tgt, ncm, self.caches, self.dcaches = self._spec(
-                self.params, self.qparams, self.draft.params,
-                self.draft.qparams, self.caches, self.dcaches, tok, pos, k)
-        tgt = np.asarray(jax.block_until_ready(tgt))
-        ncm = np.asarray(ncm)
-        self.stats["decode_s"] += time.time() - t0
-        # one round advances k+1 positions' worth of scoring in one
-        # dispatch: decode_steps counts positions scored (slot_occupancy
-        # keeps its meaning), decode_tokens counts only *committed*
-        # tokens — drafted-but-rejected work shows up as the gap between
-        # spec_drafted and spec_accepted, never as throughput
-        self.stats["decode_steps"] += k + 1
-        self.stats["spec_steps"] += 1
-        for slot, req in enumerate(self.active):
-            if req is None:
-                continue
-            n = int(ncm[slot])
-            self.stats["decode_tokens"] += n
-            self.stats["spec_drafted"] += k
-            self.stats["spec_accepted"] += n - 1
-            req.tokens.extend(int(t) for t in tgt[slot, :n])
-            self.last_tok[slot] = tgt[slot, n - 1]
-            self.pos[slot] += n
-            if req.done:
-                self._finish(req)
+        slots = self._active_slots()
+        with self._span("engine.decode", "decode_s", k=k,
+                        slots=len(slots)) as sp:
+            if self.paged:
+                tgt, ncm, self.caches, self.dcaches = sp.dispatch(
+                    self._spec_paged, self.params, self.qparams,
+                    self.draft.params, self.draft.qparams, self.caches,
+                    self.dcaches, tok, pos, jnp.asarray(self.page_table), k)
+            else:
+                tgt, ncm, self.caches, self.dcaches = sp.dispatch(
+                    self._spec, self.params, self.qparams,
+                    self.draft.params, self.draft.qparams, self.caches,
+                    self.dcaches, tok, pos, k)
+            tgt, ncm = sp.wait((tgt, ncm))
+            # one round advances k+1 positions' worth of scoring in one
+            # dispatch: decode_steps counts positions scored
+            # (slot_occupancy keeps its meaning), decode_tokens counts
+            # only *committed* tokens — drafted-but-rejected work shows up
+            # as the gap between spec_drafted and spec_accepted, never as
+            # throughput
+            self._count_decode(k + 1, slots)
+            self.stats["spec_steps"] += 1
+            with sp.child("emit"):
+                for slot in slots:
+                    req = self.active[slot]
+                    n = int(ncm[slot])
+                    self.stats["decode_tokens"] += n
+                    self.stats["spec_drafted"] += k
+                    self.stats["spec_accepted"] += n - 1
+                    req.tokens.extend(int(t) for t in tgt[slot, :n])
+                    self.last_tok[slot] = tgt[slot, n - 1]
+                    self.pos[slot] += n
+                    if req.done:
+                        self._finish(req)
         return True
 
     MAX_WINDOW = 32
@@ -1376,37 +1474,42 @@ class Engine:
                 "chunked-prefill engines decode through step(): a fused "
                 "window cannot interleave prefill chunks — it would "
                 "reintroduce the head-of-line block chunking removes")
-        self._admit()
-        if self.n_active == 0:
-            return False
-        k = min(req.max_new_tokens - len(req.tokens)
-                for req in self.active if req is not None)
-        # quantize to powers of two so the set of compiled window lengths
-        # is bounded (and warmable) instead of one compile per workload
-        k = min(1 << (k.bit_length() - 1), self.MAX_WINDOW)
-        tok = jnp.asarray(self.last_tok)[:, None]
-        pos = jnp.asarray(self.pos)
-        t0 = time.time()
-        if self.paged:
-            toks, self.caches = self._decode_window_paged(
-                self.params, self.qparams, self.caches, tok, pos,
-                jnp.asarray(self.page_table), k)
-        else:
-            toks, self.caches = self._decode_window(
-                self.params, self.qparams, self.caches, tok, pos, k)
-        toks = np.asarray(jax.block_until_ready(toks))   # (k, slots)
-        self.stats["decode_s"] += time.time() - t0
-        self.stats["decode_steps"] += k
-        for slot, req in enumerate(self.active):
-            if req is None:
-                continue
-            self.stats["decode_tokens"] += k
-            req.tokens.extend(int(t) for t in toks[:, slot])
-            self.last_tok[slot] = toks[-1, slot]
-            self.pos[slot] += k
-            if req.done:
-                self._finish(req)
-        return True
+        with self._span("engine.iteration", "engine_s"):
+            self._admit()
+            slots = self._active_slots()
+            if not slots:
+                return False
+            k = min(self.active[s].max_new_tokens
+                    - len(self.active[s].tokens) for s in slots)
+            # quantize to powers of two so the set of compiled window
+            # lengths is bounded (and warmable) instead of one compile
+            # per workload
+            k = min(1 << (k.bit_length() - 1), self.MAX_WINDOW)
+            tok = jnp.asarray(self.last_tok)[:, None]
+            pos = jnp.asarray(self.pos)
+            with self._span("engine.decode", "decode_s", k=k,
+                            slots=len(slots)) as sp:
+                if self.paged:
+                    toks, self.caches = sp.dispatch(
+                        self._decode_window_paged, self.params,
+                        self.qparams, self.caches, tok, pos,
+                        jnp.asarray(self.page_table), k)
+                else:
+                    toks, self.caches = sp.dispatch(
+                        self._decode_window, self.params, self.qparams,
+                        self.caches, tok, pos, k)
+                toks = sp.wait(toks)                     # (k, slots)
+                self._count_decode(k, slots)
+                with sp.child("emit"):
+                    for slot in slots:
+                        req = self.active[slot]
+                        self.stats["decode_tokens"] += k
+                        req.tokens.extend(int(t) for t in toks[:, slot])
+                        self.last_tok[slot] = toks[-1, slot]
+                        self.pos[slot] += k
+                        if req.done:
+                            self._finish(req)
+            return True
 
     def run(self) -> dict[int, np.ndarray]:
         """Drain the queue; returns rid -> generated tokens (prompt not

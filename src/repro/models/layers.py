@@ -192,8 +192,15 @@ def dense_proj(x: jax.Array, lp: dict, qp: Optional[dict], name: str, *,
       at 32//bits codes each stream from HBM — the `--packed` path)
 
     A column mask may also ride the param dict as `<name>.colmask` so it
-    stacks over the layer axis and scans with the block body.
+    stacks over the layer axis and scans with the block body. Every op it
+    emits sits under the `proj` name scope (the device trace's weight
+    stream).
     """
+    with jax.named_scope("proj"):
+        return _dense_proj(x, lp, qp, name, mask=mask, backend=backend)
+
+
+def _dense_proj(x, lp, qp, name, *, mask, backend):
     codes = lp.get(name + ".codes")
     if mask is None:
         mask = lp.get(name + ".colmask")
@@ -245,10 +252,11 @@ def dense_proj(x: jax.Array, lp: dict, qp: Optional[dict], name: str, *,
 
 # ------------------------------------------------------------------ norms
 def rmsnorm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    y = x32 * jax.lax.rsqrt(var + eps)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+    with jax.named_scope("pointwise"):
+        x32 = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        y = x32 * jax.lax.rsqrt(var + eps)
+        return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
 def groupnorm_heads(x: jax.Array, scale: jax.Array, bias: jax.Array,
@@ -276,15 +284,16 @@ def rope_tables(seq_len: int, d_head: int, theta: float,
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     """x: (B, S, H, dh); cos/sin: (S, dh/2), or (B, S, dh/2) when every
     sequence sits at its own absolute position (per-slot decode)."""
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    if cos.ndim == 3:
-        c = cos[:, :, None, :]
-        s = sin[:, :, None, :]
-    else:
-        c = cos[None, :, None, :]
-        s = sin[None, :, None, :]
-    return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c],
-                           axis=-1).astype(x.dtype)
+    with jax.named_scope("pointwise"):
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        if cos.ndim == 3:
+            c = cos[:, :, None, :]
+            s = sin[:, :, None, :]
+        else:
+            c = cos[None, :, None, :]
+            s = sin[None, :, None, :]
+        return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c],
+                               axis=-1).astype(x.dtype)
 
 
 # -------------------------------------------------------------- attention
@@ -461,8 +470,9 @@ def attn_apply(lp: dict, qp: Optional[dict], cfg: ModelConfig, x, *,
         pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
         row_upd = lambda c, u, s: jax.lax.dynamic_update_slice(
             c, u, (s, 0, 0))
-        ck = jax.vmap(row_upd)(ck, k.astype(ck.dtype), pos)
-        cv = jax.vmap(row_upd)(cv, v.astype(cv.dtype), pos)
+        with jax.named_scope("kv_write"):
+            ck = jax.vmap(row_upd)(ck, k.astype(ck.dtype), pos)
+            cv = jax.vmap(row_upd)(cv, v.astype(cv.dtype), pos)
         g = H // KVh
         qh = q.reshape(B, S, KVh, g, dh)
         scores = jnp.einsum("bqkgd,bskd->bkgqs", qh.astype(jnp.float32),
@@ -483,10 +493,11 @@ def attn_apply(lp: dict, qp: Optional[dict], cfg: ModelConfig, x, *,
         # is only position-faithful while the prompt fits the ring.
         ck, cv, pos = cache
         assert window <= 0 or S <= ck.shape[1], (S, ck.shape[1])
-        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, 0, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, 0, 0, 0))
+        with jax.named_scope("kv_write"):
+            ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
+                                              (0, 0, 0, 0))
+            cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
+                                              (0, 0, 0, 0))
         out = attention(q, k, v, cfg, window=window, q_offset=0)
         out = out.reshape(B, S, H * dh)
         out = qa(out, qp, f"{prefix}.attn_out.aq")
@@ -511,27 +522,29 @@ def attn_apply(lp: dict, qp: Optional[dict], cfg: ModelConfig, x, *,
         phys = jnp.take_along_axis(pages.table, (pos // P)[:, None],
                                    axis=1)[:, 0] * P + pos % P      # (B,)
         rowk, rowv = k[:, 0], v[:, 0]                     # (B, KVh, dh)
-        if pages.kv_bits is not None:
-            from repro.core.quant import kv_quant_encode
-            rowk, rsk = kv_quant_encode(rowk, pages.kv_bits)
-            rowv, rsv = kv_quant_encode(rowv, pages.kv_bits)
-            ksc = ksc.reshape(n_pages * P, KVh).at[phys].set(rsk).reshape(
-                ksc.shape)
-            vsc = vsc.reshape(n_pages * P, KVh).at[phys].set(rsv).reshape(
-                vsc.shape)
-        flat = (n_pages * P,) + ck.shape[2:]
-        ck = ck.reshape(flat).at[phys].set(rowk.astype(ck.dtype)).reshape(
-            ck.shape)
-        cv = cv.reshape(flat).at[phys].set(rowv.astype(cv.dtype)).reshape(
-            cv.shape)
+        with jax.named_scope("kv_write"):
+            if pages.kv_bits is not None:
+                from repro.core.quant import kv_quant_encode
+                rowk, rsk = kv_quant_encode(rowk, pages.kv_bits)
+                rowv, rsv = kv_quant_encode(rowv, pages.kv_bits)
+                ksc = ksc.reshape(n_pages * P, KVh).at[phys].set(
+                    rsk).reshape(ksc.shape)
+                vsc = vsc.reshape(n_pages * P, KVh).at[phys].set(
+                    rsv).reshape(vsc.shape)
+            flat = (n_pages * P,) + ck.shape[2:]
+            ck = ck.reshape(flat).at[phys].set(
+                rowk.astype(ck.dtype)).reshape(ck.shape)
+            cv = cv.reshape(flat).at[phys].set(
+                rowv.astype(cv.dtype)).reshape(cv.shape)
         g = H // KVh
         use_kernel = (_DECODE_ATTN["enabled"] and _KERNEL_DISPATCH["enabled"]
                       and DECODE_SCORE_SHARDING is None)
-        out = Kops.paged_decode_attn_op(
-            q.reshape(B, KVh, g, dh), ck, cv, pos, pages.table,
-            page_size=P, seq_len=pages.seq_len, kv_bits=pages.kv_bits,
-            k_scale=ksc, v_scale=vsc, window=window,
-            backend=(None if use_kernel else "xla-ref"))
+        with jax.named_scope("attn_core"):
+            out = Kops.paged_decode_attn_op(
+                q.reshape(B, KVh, g, dh), ck, cv, pos, pages.table,
+                page_size=P, seq_len=pages.seq_len, kv_bits=pages.kv_bits,
+                k_scale=ksc, v_scale=vsc, window=window,
+                backend=(None if use_kernel else "xla-ref"))
         out = out.reshape(B, 1, H, dh).astype(x.dtype)
         out = out.reshape(B, S, H * dh)
         out = qa(out, qp, f"{prefix}.attn_out.aq")
@@ -547,38 +560,42 @@ def attn_apply(lp: dict, qp: Optional[dict], cfg: ModelConfig, x, *,
         slot = jnp.mod(pos, ck.shape[1]) if window > 0 else pos
         row_upd = lambda c, u, s: jax.lax.dynamic_update_slice(
             c, u, (s, 0, 0))
-        ck = jax.vmap(row_upd)(ck, k.astype(ck.dtype), slot)
-        cv = jax.vmap(row_upd)(cv, v.astype(cv.dtype), slot)
+        with jax.named_scope("kv_write"):
+            ck = jax.vmap(row_upd)(ck, k.astype(ck.dtype), slot)
+            cv = jax.vmap(row_upd)(cv, v.astype(cv.dtype), slot)
         k_all, v_all = ck, cv
         # attention of the single query over the cache
         g = H // KVh
-        if (_DECODE_ATTN["enabled"] and _KERNEL_DISPATCH["enabled"]
-                and DECODE_SCORE_SHARDING is None):
-            # fused flash-decode kernel: split-K online softmax over the
-            # arena, valid-length/ring masking inside the kernel
-            out = Kops.decode_attn_op(q.reshape(B, KVh, g, dh),
-                                      k_all, v_all, pos, window=window)
-            out = out.reshape(B, 1, H, dh).astype(x.dtype)
-        else:
-            qh = q.reshape(B, 1, KVh, g, dh)
-            scores = jnp.einsum("bqkgd,bskd->bkgqs", qh.astype(jnp.float32),
-                                k_all.astype(jnp.float32)) / math.sqrt(dh)
-            if DECODE_SCORE_SHARDING is not None:
-                scores = jax.lax.with_sharding_constraint(
-                    scores, DECODE_SCORE_SHARDING)
-            # mask unwritten arena rows: row b has written exactly
-            # min(pos[b]+1, S) slots — rows [0, pos] of a full arena, or
-            # the whole ring once a windowed arena wraps (softmax is
-            # permutation-invariant over KV rows, so ring order is moot).
-            # A fresh (pos < ring_len) windowed cache *must* mask its
-            # zero-initialized tail, same as the full arena.
-            valid = (jnp.arange(ck.shape[1])[None, :]
-                     < jnp.minimum(pos + 1, ck.shape[1])[:, None])
-            scores = jnp.where(valid[:, None, None, None, :], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            out = jnp.einsum("bkgqs,bskd->bqkgd", probs,
-                             v_all.astype(jnp.float32))
-            out = out.reshape(B, 1, H, dh).astype(x.dtype)
+        with jax.named_scope("attn_core"):
+            if (_DECODE_ATTN["enabled"] and _KERNEL_DISPATCH["enabled"]
+                    and DECODE_SCORE_SHARDING is None):
+                # fused flash-decode kernel: split-K online softmax over the
+                # arena, valid-length/ring masking inside the kernel
+                out = Kops.decode_attn_op(q.reshape(B, KVh, g, dh),
+                                          k_all, v_all, pos, window=window)
+                out = out.reshape(B, 1, H, dh).astype(x.dtype)
+            else:
+                qh = q.reshape(B, 1, KVh, g, dh)
+                scores = jnp.einsum("bqkgd,bskd->bkgqs",
+                                    qh.astype(jnp.float32),
+                                    k_all.astype(jnp.float32)) / math.sqrt(dh)
+                if DECODE_SCORE_SHARDING is not None:
+                    scores = jax.lax.with_sharding_constraint(
+                        scores, DECODE_SCORE_SHARDING)
+                # mask unwritten arena rows: row b has written exactly
+                # min(pos[b]+1, S) slots — rows [0, pos] of a full arena, or
+                # the whole ring once a windowed arena wraps (softmax is
+                # permutation-invariant over KV rows, so ring order is moot).
+                # A fresh (pos < ring_len) windowed cache *must* mask its
+                # zero-initialized tail, same as the full arena.
+                valid = (jnp.arange(ck.shape[1])[None, :]
+                         < jnp.minimum(pos + 1, ck.shape[1])[:, None])
+                scores = jnp.where(valid[:, None, None, None, :], scores,
+                                   -1e30)
+                probs = jax.nn.softmax(scores, axis=-1)
+                out = jnp.einsum("bkgqs,bskd->bqkgd", probs,
+                                 v_all.astype(jnp.float32))
+                out = out.reshape(B, 1, H, dh).astype(x.dtype)
         new_cache = (ck, cv, pos + 1)
     else:
         out = attention(q, k, v, cfg, window=window, q_offset=q_offset)
@@ -612,8 +629,9 @@ def mlp_apply(lp: dict, qp: Optional[dict], cfg: ModelConfig, x, *,
               prefix: str):
     g = dense_proj(x, lp, qp, f"{prefix}.w_gate")
     u = dense_proj(x, lp, qp, f"{prefix}.w_up")
-    h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
-    h = qa(h, qp, f"{prefix}.mlp_act.aq")
+    with jax.named_scope("pointwise"):
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+        h = qa(h, qp, f"{prefix}.mlp_act.aq")
     return dense_proj(h, lp, qp, f"{prefix}.w_down")
 
 

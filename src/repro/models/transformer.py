@@ -508,7 +508,10 @@ class LM:
         (continuous batching: each slot at its own absolute position).
         `pages` (a `Lyr.PagedView`) switches attention caches to the
         paged pools of `init_paged_cache` — the view's table indirects
-        every K/V write and read; recurrent state is untouched.
+        every K/V write and read; recurrent state is untouched. Its
+        norms, rope, activation and residual adds sit under the
+        `pointwise` name scope, beside the layers' `proj`, `kv_write`
+        and `attn_core`.
         Returns (logits, new_caches)."""
         cfg = self.cfg
         params, qp_body = self._prequantize(params, qparams)
@@ -516,11 +519,13 @@ class LM:
         B = x.shape[0]
         # rope at each sequence's absolute position
         pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
-        posf = pos.astype(jnp.float32)
-        freqs = cfg.rope_theta ** (-jnp.arange(0, cfg.d_head, 2,
-                                               dtype=jnp.float32) / cfg.d_head)
-        ang = posf[:, None] * freqs[None, :]
-        rope = (jnp.cos(ang)[:, None], jnp.sin(ang)[:, None])   # (B, 1, dh/2)
+        with jax.named_scope("pointwise"):
+            posf = pos.astype(jnp.float32)
+            freqs = cfg.rope_theta ** (-jnp.arange(
+                0, cfg.d_head, 2, dtype=jnp.float32) / cfg.d_head)
+            ang = posf[:, None] * freqs[None, :]
+            rope = (jnp.cos(ang)[:, None],
+                    jnp.sin(ang)[:, None])                  # (B, 1, dh/2)
 
         def body(x, inp):
             x = self._constrain(x)
@@ -558,7 +563,8 @@ class LM:
                         lp, qp_body, cfg, h, prefix=f"{pre}.rwkv", shapes=shp,
                         state=(cc[f"{pre}.tm_shift"], cc[f"{pre}.wkv"]))
                     new_c[f"{pre}.tm_shift"], new_c[f"{pre}.wkv"] = ns
-                x = self._constrain(x + mix)
+                with jax.named_scope("pointwise"):
+                    x = self._constrain(x + mix)
                 if sub.ffn == "none":
                     continue
                 h2 = Lyr.rmsnorm(x, lp[f"{pre}.norm2"], cfg.norm_eps)
@@ -572,7 +578,8 @@ class LM:
                         lp, qp_body, cfg, h2, prefix=f"{pre}.rwkv",
                         state=cc[f"{pre}.cm_shift"])
                     new_c[f"{pre}.cm_shift"] = ns
-                x = self._constrain(x + f)
+                with jax.named_scope("pointwise"):
+                    x = self._constrain(x + f)
             return x, new_c
 
         bp = self._block_params(params)

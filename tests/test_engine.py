@@ -220,8 +220,9 @@ def test_draft_prefill_time_rides_its_own_counters(monkeypatch):
     and token count must land in draft_prefill_* — folding it into
     prefill_s (as it used to) inflated the target prefill denominator
     and corrupted prefill_tok_per_s for every speculative serve. A fake
-    clock that ticks 1.0 per time() call makes every timed block weigh
-    exactly 1.0, so the split is assertable without real timing."""
+    clock that ticks 1.0 per call makes every timed block weigh exactly
+    its clock reads less one (entry, before and after each dispatch, end
+    of the wait), so the split is assertable without real timing."""
     import itertools
     import types
 
@@ -233,17 +234,18 @@ def test_draft_prefill_time_rides_its_own_counters(monkeypatch):
         eng.submit(p, 4)
     eng.warmup()
     ticks = itertools.count()
+    tick = lambda: float(next(ticks))
     monkeypatch.setattr(engine_mod, "time",
-                        types.SimpleNamespace(
-                            time=lambda: float(next(ticks))))
+                        types.SimpleNamespace(time=tick, perf_counter=tick))
     eng.run()
     s = eng.stats
     assert s["prefills"] == 2 and s["prefill_tokens"] == 12
     assert s["draft_prefills"] == 2 and s["draft_prefill_tokens"] == 12
     # one timed block each per admission — target and draft prefill time
-    # no longer pool into one counter
-    assert s["prefill_s"] == pytest.approx(2.0)
-    assert s["draft_prefill_s"] == pytest.approx(2.0)
+    # no longer pool into one counter: the target's one dispatch weighs
+    # 3.0, the draft's two (prefill, insert) 5.0
+    assert s["prefill_s"] == pytest.approx(2 * 3.0)
+    assert s["draft_prefill_s"] == pytest.approx(2 * 5.0)
 
 
 def test_kv_bytes_counts_both_arenas():

@@ -86,15 +86,17 @@ def test_chunked_prefill_chunk_one_token_identity():
 
 # ------------------------------------------------------- disaggregation
 class _FakeTime:
-    """Deterministic clock: every time() call advances 1 ms. Makes the
-    wall-time stats exact integers of the call count instead of host
-    noise, so the interleaving assertions can't flake."""
+    """Deterministic clock: every time() or perf_counter() call advances
+    1 ms. Makes the wall-time stats exact integers of the call count
+    instead of host noise, so the interleaving assertions can't flake."""
     def __init__(self):
         self.t = 0.0
 
     def time(self):
         self.t += 0.001
         return self.t
+
+    perf_counter = time
 
 
 def test_decode_runs_mid_prefill(monkeypatch):
@@ -114,12 +116,16 @@ def test_decode_runs_mid_prefill(monkeypatch):
     assert eng.stats["decode_steps_mid_prefill"] >= 8
     assert eng.stats["chunked_prefills"] == 2
     assert eng.stats["prefills"] == 2
-    # fake clock: every timed section advanced exactly 1 ms per
-    # time()-pair, so the stats are pure call counts — nonzero and exact
+    # fake clock: every timed section reads it at entry, before and
+    # after its one dispatch, and at the end of its wait — exactly 3 ms —
+    # so the stats are pure call counts, nonzero and exact
     assert eng.stats["prefill_s"] == pytest.approx(
-        0.001 * eng.stats["prefill_chunks"])
+        0.003 * eng.stats["prefill_chunks"])
     assert eng.stats["decode_s"] == pytest.approx(
-        0.001 * eng.stats["decode_steps"])
+        0.003 * eng.stats["decode_steps"])
+    assert eng.stats["dispatch_s"] == pytest.approx(
+        0.001 * (eng.stats["prefill_chunks"] + eng.stats["decode_steps"]
+                 + eng.stats["prefills"]))   # + one slot insert each
 
 
 def test_oneshot_never_decodes_mid_prefill():
