@@ -174,9 +174,11 @@ def make_probe(lm, max_seq: int, paged=None):
             lp = -(-max_seq // P)
             pool = lm.init_paged_cache(1, lp + 1, P, dtype=dt,
                                        kv_bits=kv_bits)
-            for key, rows in cache.items():          # (nb, 1, S, KVh, dh)
-                r = jnp.pad(rows[:, 0], ((0, 0), (0, lp * P - max_seq),
-                                         (0, 0), (0, 0)))
+            for key, rows in cache.items():        # (nb, 1, S, KVh * dh)
+                r = rows[:, 0].reshape(rows.shape[:1] + (max_seq,)
+                                       + pool[key].shape[3:4] + (-1,))
+                r = jnp.pad(r, ((0, 0), (0, lp * P - max_seq),
+                                (0, 0), (0, 0)))
                 r = r.reshape((r.shape[0], lp, P) + r.shape[2:])
                 if kv_bits is None:
                     pool[key] = pool[key].at[:, 1:].set(r)

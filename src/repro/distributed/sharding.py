@@ -194,29 +194,34 @@ def serving_param_specs(plan: ShardingPlan, params_axes: dict[str, tuple],
     return specs
 
 
-def kv_cache_specs(mesh: Mesh, cache_shapes: dict[str, tuple]
+def kv_cache_specs(mesh: Mesh, cache_shapes: dict[str, tuple],
+                   kv_heads: Optional[dict[str, int]] = None
                    ) -> dict[str, P]:
     """PartitionSpecs for an engine KV arena, contiguous or paged.
 
-    Attention K/V leaves shard their KV-head axis over `model` — axis 3
-    in both the contiguous (nb, B, S, KVh, dh) arena and the paged
-    (nb, n_pages, P, KVh, dh) pools, and likewise the paged per-row
-    scale planes (nb, n_pages, P, KVh). The page/slot/row axes are never
-    split: page tables stay host-side and every logical page maps to one
-    local tile per device. A KVh that doesn't divide the mesh replicates
-    (GQA smoke configs with 2 kv heads on 4 devices); recurrent-state
-    leaves (mamba h/conv, rwkv shift/wkv) are O(1)-per-slot and
-    replicate."""
+    Attention K/V leaves shard their KV heads over `model`. A contiguous
+    arena stores them merged, (nb, B, S, KVh * dh): its last axis shards
+    when `kv_heads[name]` (`LM.kv_heads`) divides the mesh, which splits
+    it at whole-head boundaries. The paged (nb, n_pages, P, KVh, dh)
+    pools shard axis 3, and likewise the paged per-row scale planes
+    (nb, n_pages, P, KVh). The page/slot/row axes are never split: page
+    tables stay host-side and every logical page maps to one local tile
+    per device. A KVh that doesn't divide the mesh replicates (GQA smoke
+    configs with 2 kv heads on 4 devices); recurrent-state leaves (mamba
+    h/conv, rwkv shift/wkv) are O(1)-per-slot and replicate."""
     size = int(mesh.shape.get("model", 1))
+    heads = kv_heads or {}
     specs: dict[str, P] = {}
     for name, shape in cache_shapes.items():
         kv = name.endswith(".k") or name.endswith(".v")
         sc = name.endswith("_scale")
-        if size > 1 and ((kv and len(shape) == 5) or (sc and len(shape) == 4)) \
-                and shape[3] % size == 0:
-            specs[name] = P(None, None, None, "model")
+        if kv and len(shape) == 4:
+            split = heads.get(name, 1) % size == 0
         else:
-            specs[name] = P()
+            split = ((kv and len(shape) == 5) or (sc and len(shape) == 4)) \
+                and shape[3] % size == 0
+        specs[name] = P(None, None, None, "model") if size > 1 and split \
+            else P()
     return specs
 
 

@@ -8,15 +8,20 @@ that grows with max_seq. This kernel streams each cache row once:
 
   grid = (batch-slot b, split-K chunk c)   — c innermost
 
-Every program takes *all* KV heads of its chunk: the arena
-(B, S, KVh, dh) is viewed as (B, S, KVh * dh) — a free row-major
-reshape — and one (chunk, KVh * dh) block streams per step. Its last two
-dims are (8, 128)-aligned (chunk) or equal to the array's (KVh * dh), so
-the block is legal for the TPU lowering at any head count and head
-width; the per-head (chunk, dh) tiles are static lane slices in VMEM.
-The q / output blocks are (KVh, gp, dh), again whole in their last two
-dims. The slot's valid length rides in SMEM through scalar prefetch
-(`PrefetchScalarGridSpec`), never as a (1, 1) VMEM block.
+Every program takes *all* KV heads of its chunk. The engine's arena
+stores each attention layer's K and V as (nb, B, S, KVh * dh), stacked
+over the layer blocks, and the kernel reads that stored buffer itself:
+the layer index rides in scalar prefetch and the k/v index map returns
+(layer, b, chunk, 0), so one (chunk, KVh * dh) block of the layer
+streams per step with no slice, copy or relayout of the arena outside
+the kernel. Its last two dims are (8, 128)-aligned (chunk) or equal to
+the array's (KVh * dh), so the block is legal for the TPU lowering at
+any head count and head width; the per-head (chunk, dh) tiles are
+static lane slices in VMEM. The q / output blocks are (KVh, gp, dh),
+again whole in their last two dims. The slot's valid length rides in
+SMEM through scalar prefetch (`PrefetchScalarGridSpec`), never as a
+(1, 1) VMEM block. A caller holding one layer's (B, S, KVh, dh) rows
+passes no layer index; they are viewed as a one-layer arena.
 
 Chunk programs for one slot run consecutively (the same accumulator
 pattern `gemm_core` uses for its K axis), carrying the online-softmax
@@ -101,8 +106,9 @@ def _finalize(o_ref, l_ref):
     o_ref[0] = o_ref[0] / jnp.maximum(l_ref[:, :, :1], 1e-30)
 
 
-def _kernel(nv_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
-            chunk: int, nchunks: int, scale: float, kvh: int, dh: int):
+def _kernel(nv_ref, layer_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+            *, chunk: int, nchunks: int, scale: float, kvh: int, dh: int):
+    del layer_ref   # consumed by the k/v index maps
     b = pl.program_id(0)
     c = pl.program_id(1)
 
@@ -116,8 +122,8 @@ def _kernel(nv_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
     def _chunk():
         for h in range(kvh):
             q = q_ref[0, h].astype(jnp.float32)                   # (gp, dh)
-            kt = k_ref[0, :, h * dh:(h + 1) * dh].astype(jnp.float32)
-            vt = v_ref[0, :, h * dh:(h + 1) * dh].astype(jnp.float32)
+            kt = k_ref[0, 0, :, h * dh:(h + 1) * dh].astype(jnp.float32)
+            vt = v_ref[0, 0, :, h * dh:(h + 1) * dh].astype(jnp.float32)
             _head_update(h, q, kt, vt, c * chunk, n_valid, scale,
                          o_ref, m_ref, l_ref)
 
@@ -148,14 +154,17 @@ def plan_paged_tiles(g: int, dh: int, dhs: int, kv_bits: int | None) -> int:
     return _round_up(g, 8)
 
 
-def decode_attn_pallas(q, k, v, pos, *, window: int = 0,
+def decode_attn_pallas(q, k, v, pos, *, layer=None, window: int = 0,
                        chunk: int | None = None,
                        interpret: bool = False) -> jax.Array:
-    """Single-query attention of q over the (k, v) slot arena.
+    """Single-query attention of q over one layer of the (k, v) arena.
 
     q:   (B, KVh, g, dh) — the token's query heads, grouped per KV head
          (g = H // KVh; GQA ratio 1 for MHA).
-    k,v: (B, S, KVh, dh) — arena rows, post update of the current token.
+    k,v: (nb, B, S, KVh * dh) — the stacked arena, rows post update of
+         the current token, read at layer `layer` (a Python int or a
+         traced int32 scalar); or, with `layer` None, one layer's
+         (B, S, KVh, dh) rows.
     pos: (B,) int32 per-slot absolute positions of the token being
          decoded; row b has min(pos[b] + 1, S) valid arena rows (ring
          arenas wrap, full arenas write row `pos` directly — same rule).
@@ -167,9 +176,14 @@ def decode_attn_pallas(q, k, v, pos, *, window: int = 0,
     """
     del window   # the min(pos+1, S) rule covers ring and full arenas
     B, KVh, g, dh = q.shape
-    assert k.shape == v.shape == (B, k.shape[1], KVh, dh), (
+    if layer is None:
+        assert k.shape == v.shape == (B, k.shape[1], KVh, dh), (
+            q.shape, k.shape, v.shape)
+        k, v = (a.reshape((1,) + a.shape[:2] + (KVh * dh,)) for a in (k, v))
+        layer = 0
+    assert k.shape == v.shape == (k.shape[0], B, k.shape[2], KVh * dh), (
         q.shape, k.shape, v.shape)
-    S = k.shape[1]
+    S = k.shape[2]
     scale = 1.0 / math.sqrt(dh)
 
     # Compiled TPU blocks want 128-row chunks; the interpreter (CPU
@@ -178,29 +192,33 @@ def decode_attn_pallas(q, k, v, pos, *, window: int = 0,
     gp, chunk = plan_tiles(g, S, chunk, align=align)
     Sp = _round_up(S, chunk)
     nchunks = Sp // chunk
+    lay = jnp.asarray(layer, jnp.int32).reshape(1)
+    if Sp != S:
+        # a partial edge block would feed rows from past the arena into
+        # p @ v unmasked: read this one layer, padded to whole chunks
+        pad = ((0, 0), (0, 0), (0, Sp - S), (0, 0))
+        k, v = (jnp.pad(jax.lax.dynamic_index_in_dim(a, lay[0], 0), pad)
+                for a in (k, v))
+        lay = jnp.zeros((1,), jnp.int32)
 
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
-    kp = jnp.pad(k, ((0, 0), (0, Sp - S), (0, 0), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, Sp - S), (0, 0), (0, 0)))
-    kp = kp.reshape(B, Sp, KVh * dh)
-    vp = vp.reshape(B, Sp, KVh * dh)
     nv = jnp.minimum(jnp.asarray(pos, jnp.int32).reshape(B) + 1, S)
 
-    def qmap(b, c, nv_ref):
+    def qmap(b, c, nv_ref, layer_ref):
         return (b, 0, 0, 0)
 
-    def kvmap(b, c, nv_ref):
+    def kvmap(b, c, nv_ref, layer_ref):
         # chunks past the slot's valid rows are skipped by the kernel;
         # pinning their block to the last valid chunk skips the fetch
-        return (b, jnp.minimum(c, (nv_ref[b] - 1) // chunk), 0)
+        return (layer_ref[0], b, jnp.minimum(c, (nv_ref[b] - 1) // chunk), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, nchunks),
         in_specs=[
             pl.BlockSpec((1, KVh, gp, dh), qmap),
-            pl.BlockSpec((1, chunk, KVh * dh), kvmap),
-            pl.BlockSpec((1, chunk, KVh * dh), kvmap),
+            pl.BlockSpec((1, 1, chunk, KVh * dh), kvmap),
+            pl.BlockSpec((1, 1, chunk, KVh * dh), kvmap),
         ],
         out_specs=pl.BlockSpec((1, KVh, gp, dh), qmap),
         scratch_shapes=[
@@ -215,22 +233,25 @@ def decode_attn_pallas(q, k, v, pos, *, window: int = 0,
         out_shape=jax.ShapeDtypeStruct((B, KVh, gp, dh), jnp.float32),
         interpret=interpret,
         name="flash_decode",
-    )(nv, qp, kp, vp)
+    )(nv, lay, qp, k, v)
     return out[:, :, :g]
 
 
-def tp_decode_attn(q, k, v, pos, *, mesh, axis: str = "model",
+def tp_decode_attn(q, k, v, pos, *, mesh, layer=None, axis: str = "model",
                    window: int = 0, chunk: int | None = None,
                    backend: str | None = None) -> jax.Array:
     """KV-head-parallel flash decode over one mesh axis via `shard_map`.
 
     Decode attention is embarrassingly parallel over KV heads — softmax
     normalizes within a head and GQA groups ride their KV head — so the
-    TP layout splits q on its head axis (1) and the k/v arenas on theirs
-    (2), each device runs the single-device kernel over KVh/tp local
-    heads, and the (B, KVh, g, dh) output concatenates over heads with
-    **no cross-device reduction**: per-head numerics are exactly the
-    1-device kernel's. `pos` replicates (it is per-slot, not per-head).
+    TP layout splits q on its head axis (1) and the k/v arenas on theirs:
+    the merged (KVh * dh) axis (3) of a stacked (nb, B, S, KVh * dh)
+    arena read at `layer`, which splits at whole heads, or the head axis
+    (2) of one layer's (B, S, KVh, dh) rows. Each device runs the
+    single-device kernel over KVh/tp local heads, and the (B, KVh, g, dh)
+    output concatenates over heads with **no cross-device reduction**:
+    per-head numerics are exactly the 1-device kernel's. `pos` and the
+    layer index replicate.
 
     Like `tp_gemm`, the kernel call sits *inside* shard_map where it is
     partitioned already, so the default backend is
@@ -247,16 +268,20 @@ def tp_decode_attn(q, k, v, pos, *, mesh, axis: str = "model",
         raise ValueError(f"tp_decode_attn: KVh={KVh} must divide the "
                          f"{axis!r} axis size {tp}")
     backend = backend or dispatch.platform_default()
+    stacked = layer is not None
+    kv_spec = P(None, None, None, axis) if stacked else P(None, None, axis)
 
-    def body(ql, kl, vl, pl_):
-        return _ops.decode_attn_op(ql, kl, vl, pl_, window=window,
-                                   chunk=chunk, backend=backend)
+    def body(ql, kl, vl, pl_, ll):
+        return _ops.decode_attn_op(ql, kl, vl, pl_,
+                                   layer=ll if stacked else None,
+                                   window=window, chunk=chunk,
+                                   backend=backend)
 
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(None, axis), P(None, None, axis), P(None, None, axis),
-                  P()),
-        out_specs=P(None, axis), check_vma=False)(q, k, v, pos)
+        in_specs=(P(None, axis), kv_spec, kv_spec, P(), P()),
+        out_specs=P(None, axis), check_vma=False)(
+            q, k, v, pos, jnp.asarray(layer if stacked else 0, jnp.int32))
 
 
 def _page_dequant(w, scale, bits):

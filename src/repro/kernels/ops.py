@@ -149,31 +149,39 @@ def packed_quant_matmul_op(x, packed, bits, scale, *, interpret=None,
 
 
 # --------------------------------------------------- flash-decode attention
-def decode_attn_op(q, k, v, pos, *, window=0, chunk=None, interpret=None,
-                   backend=None):
-    """Single-query flash-decode attention over the slot KV arena.
+def decode_attn_op(q, k, v, pos, *, layer=None, window=0, chunk=None,
+                   interpret=None, backend=None):
+    """Single-query flash-decode attention over one layer of the slot KV
+    arena.
 
     q: (B, KVh, g, dh) query heads grouped per KV head (g = H // KVh);
-    k/v: (B, S, KVh, dh) arena rows with the current token written;
-    pos: (B,) int32 per-slot positions. Row b attends over its
-    min(pos[b] + 1, S) valid rows — full and ring (windowed) arenas
-    share the rule, enforced inside the kernel. Returns (B, KVh, g, dh)
-    f32 — inference-only (decode holds no gradients), like
-    `quant_matmul_op`. The split-K online-softmax kernel lives in
-    `kernels.decode_attn`; the xla-ref backend runs the legacy einsum
-    composition (`ref.decode_attn_ref`) bit-for-bit."""
+    k/v: the stacked (nb, B, S, KVh * dh) arena as the engine stores it,
+    read at `layer` (int or traced int32 scalar) in place, or with
+    `layer` None one layer's (B, S, KVh, dh) rows — the current token's
+    row already written; pos: (B,) int32 per-slot positions. Row b
+    attends over its min(pos[b] + 1, S) valid rows — full and ring
+    (windowed) arenas share the rule, enforced inside the kernel. Returns
+    (B, KVh, g, dh) f32 — inference-only (decode holds no gradients),
+    like `quant_matmul_op`. The split-K online-softmax kernel lives in
+    `kernels.decode_attn`; the xla-ref backend slices the layer out,
+    views it per head and runs the legacy einsum composition
+    (`ref.decode_attn_ref`) bit-for-bit."""
     backend = dispatch.resolve(backend, interpret)
     if introspect.recording():
         # record the compiled-TPU tile geometry regardless of which
         # backend this trace routes to (see kernels.introspect)
         B, KVh, g, dh = q.shape
-        gp, ch = _da.plan_tiles(g, k.shape[1], chunk)
+        gp, ch = _da.plan_tiles(g, k.shape[1 if layer is None else 2],
+                                chunk)
         introspect.note(introspect.AttnLaunch(
             kind="decode_attn", B=B, KVh=KVh, g=g, dh=dh, gp=gp, dhs=dh,
             chunk=ch, backend=backend, kv_itemsize=k.dtype.itemsize))
     if backend == "xla-ref":
+        if layer is not None:
+            k, v = (_ref.arena_layer(a, layer, q.shape[1]) for a in (k, v))
         return _ref.decode_attn_ref(q, k, v, pos, window=window)
-    return _da.decode_attn_pallas(q, k, v, pos, window=window, chunk=chunk,
+    return _da.decode_attn_pallas(q, k, v, pos, layer=layer, window=window,
+                                  chunk=chunk,
                                   interpret=(backend == "pallas-interpret"))
 
 
@@ -289,6 +297,7 @@ def fq_masked_matmul_op(x, w, mask, d, q_m, t, *, interpret=None,
 
 
 # Re-export oracles for tests/benchmarks.
+arena_layer = _ref.arena_layer
 decode_attn_ref = _ref.decode_attn_ref
 paged_decode_attn_ref = _ref.paged_decode_attn_ref
 fake_quant_fwd_ref = _ref.fake_quant_fwd_ref

@@ -75,6 +75,13 @@ def quant_matmul_ref(x, codes, scale):
     return (x.astype(jnp.float32) @ w).astype(x.dtype)
 
 
+def arena_layer(arena, layer, kvh: int):
+    """One layer of a stacked (nb, B, S, KVh * dh) arena, viewed per head
+    as (B, S, KVh, dh) — what the einsum oracles read."""
+    x = jax.lax.dynamic_index_in_dim(arena, layer, 0, keepdims=False)
+    return x.reshape(x.shape[:-1] + (kvh, x.shape[-1] // kvh))
+
+
 def decode_attn_ref(q, k, v, pos, *, window=0):
     """Single-query attention over the slot KV arena — the oracle for
     `kernels.decode_attn`.

@@ -231,15 +231,18 @@ class Engine:
         self.qparams = qparams
         mesh_ = mesh
 
-        def _jit(fn, static_argnums=(), out_shardings=None):
+        def _jit(fn, static_argnums=(), out_shardings=None,
+                 donate_argnums=()):
             # every engine jit pins its output shardings under TP so the
             # arena never silently de-shards between dispatches, and traces
             # under the mesh's kernel backend (`dispatch.backend_for_mesh`)
             # with the residual stream pinned replicated (`LM._constrain`:
             # norms then reduce over whole rows, as on one device); without
             # a mesh this is exactly jax.jit
+            kw = dict(static_argnums=static_argnums,
+                      donate_argnums=donate_argnums)
             if mesh_ is None:
-                return jax.jit(fn, static_argnums=static_argnums)
+                return jax.jit(fn, **kw)
             lms = [lm] + ([draft.lm] if draft is not None else [])
 
             @functools.wraps(fn)
@@ -254,10 +257,9 @@ class Engine:
                     for m, a in zip(lms, saved):
                         m.act_sharding = a
 
-            if out_shardings is None:
-                return jax.jit(on_mesh, static_argnums=static_argnums)
-            return jax.jit(on_mesh, static_argnums=static_argnums,
-                           out_shardings=out_shardings)
+            if out_shardings is not None:
+                kw["out_shardings"] = out_shardings
+            return jax.jit(on_mesh, **kw)
         self.paged = bool(paged)
         self.page_size = int(page_size)
         self.kv_bits = kv_bits
@@ -289,7 +291,8 @@ class Engine:
             from jax.sharding import NamedSharding
             from repro.distributed import sharding as shlib
             arena_specs = shlib.kv_cache_specs(
-                mesh, {k: v.shape for k, v in self.caches.items()})
+                mesh, {k: v.shape for k, v in self.caches.items()},
+                lm.kv_heads())
             self._arena_sh = {k: NamedSharding(mesh, s)
                               for k, s in arena_specs.items()}
             self.caches = jax.device_put(self.caches, self._arena_sh)
@@ -298,7 +301,8 @@ class Engine:
             row_tmpl = jax.eval_shape(
                 lambda: lm.init_cache(1, max_seq, dtype=dt))
             row_specs = shlib.kv_cache_specs(
-                mesh, {k: v.shape for k, v in row_tmpl.items()})
+                mesh, {k: v.shape for k, v in row_tmpl.items()},
+                lm.kv_heads())
             self._row_sh = {k: NamedSharding(mesh, s)
                             for k, s in row_specs.items()}
         # host-side slot table: position, last emitted token, owner
@@ -366,14 +370,16 @@ class Engine:
                 # the draft arena shards by *its* (sliced) KV heads; the
                 # draft's served params shard through the same TP rules
                 dspecs = shlib.kv_cache_specs(
-                    mesh, {k: v.shape for k, v in self.dcaches.items()})
+                    mesh, {k: v.shape for k, v in self.dcaches.items()},
+                    draft.lm.kv_heads())
                 self._darena_sh = {k: NamedSharding(mesh, s)
                                    for k, s in dspecs.items()}
                 self.dcaches = jax.device_put(self.dcaches, self._darena_sh)
                 drow_tmpl = jax.eval_shape(
                     lambda: draft.lm.init_cache(1, max_seq, dtype=dt))
                 drow_specs = shlib.kv_cache_specs(
-                    mesh, {k: v.shape for k, v in drow_tmpl.items()})
+                    mesh, {k: v.shape for k, v in drow_tmpl.items()},
+                    draft.lm.kv_heads())
                 self._drow_sh = {k: NamedSharding(mesh, s)
                                  for k, s in drow_specs.items()}
                 dcap: dict = {}
@@ -460,8 +466,15 @@ class Engine:
         # _insert writes target rows under the target arena's sharding
         # tree, _insert_d (created only with a draft attached) under the
         # draft's. Same function, same per-arena compile count as before.
-        self._insert = _jit(_insert, out_shardings=self._arena_sh)
-        self._insert_d = (_jit(_insert, out_shardings=self._darena_sh)
+        # the slot insert donates the arena it is handed (the caller
+        # rebinds it to the result), so an admission writes one slot's
+        # rows into the buffer in place instead of copying all of it. The
+        # decode jits keep value semantics for the arena they are handed
+        # (one copy on entry, then in place throughout the window)
+        self._insert = _jit(_insert, out_shardings=self._arena_sh,
+                            donate_argnums=(0,))
+        self._insert_d = (_jit(_insert, out_shardings=self._darena_sh,
+                               donate_argnums=(0,))
                           if draft is not None else None)
         self._decode = _jit(_decode,
                             out_shardings=((self._rep, self._arena_sh)
@@ -490,7 +503,9 @@ class Engine:
                 def ins(caches, row, slot, phys, npp):
                     new = dict(caches)
                     for kk in kv:
-                        r = row[kk][:, 0]                 # (nb, S, KVh, dh)
+                        r = row[kk][:, 0]              # (nb, S, KVh * dh)
+                        kvh = caches[kk].shape[3]
+                        r = r.reshape(r.shape[:2] + (kvh, -1))
                         pad = npp * P - r.shape[1]
                         if pad > 0:
                             r = jnp.pad(r, ((0, 0), (0, pad))
@@ -602,9 +617,9 @@ class Engine:
                                 sc = jnp.take(caches[kk + "_scale"], pt,
                                               axis=1)
                                 pages_ = kv_quant_decode(pages_, sc, kvb)
+                            # the contiguous arena's merged row layout
                             rows = pages_.reshape(
-                                pages_.shape[:2] + (Lp * P,)
-                                + pages_.shape[4:])
+                                pages_.shape[:2] + (Lp * P, -1))
                             views[kk] = rows[:, :, :max_seq].astype(dt)
                         for sk in state:
                             views[sk] = caches[sk]
@@ -635,6 +650,9 @@ class Engine:
                                     lambda vb, s: jax.lax.dynamic_slice_in_dim(
                                         vb, s, P, axis=1))(vB, lp * P)
                                 blk = jnp.moveaxis(blk, 0, 1)
+                                blk = blk.reshape(blk.shape[:3]
+                                                  + new[kk].shape[3:4]
+                                                  + (-1,))
                                 if kvb is not None:
                                     codes, scale = kv_quant_encode(blk, kvb)
                                     new[kk] = new[kk].at[:, phys].set(

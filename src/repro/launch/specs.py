@@ -63,24 +63,24 @@ def decode_specs(cfg: ModelConfig, shape: ShapeConfig,
     def cache_spec(name, s):
         if mesh is None:
             return jax.ShapeDtypeStruct(s.shape, s.dtype)
-        # (n_blocks, B, S, KV, dh) attn kv / (n_blocks, B, ...) states
+        # (n_blocks, B, S, KV*dh) attn kv / (n_blocks, B, ...) states
         if ".k" in name or ".v" in name:
             if cache_layout == "seq":
                 # shard the cache SEQUENCE on the model axis: attention
                 # reduces over seq, so only the tiny softmax statistics
                 # and the (B,1,H,dh) output cross devices (§Perf It.5)
-                parts = [None, dp, "model", None, None]
+                parts = [None, dp, "model", None]
                 if shard_seq:
-                    parts = [None, None, ("data", "model"), None, None]
+                    parts = [None, None, ("data", "model"), None]
                 return _sds(s.shape, s.dtype, mesh, P(*parts))
-            # TP the cache: KV-head axis when it divides, else d_head
-            # (always 128 = 8x16) — a replicated 32k cache costs 13-26
+            # TP the cache over its merged KV*dh axis: at whole KV heads
+            # when they divide the axis, else within heads (d_head is
+            # always 128 = 8x16) — a replicated 32k cache costs 13-26
             # GB/device on the large archs.
             kv_part = "model" if s.shape[3] % model_size == 0 else None
-            dh_part = "model" if kv_part is None else None
-            parts = [None, dp, None, kv_part, dh_part]
+            parts = [None, dp, None, kv_part]
             if shard_seq:
-                parts = [None, None, dp, kv_part, dh_part]
+                parts = [None, None, dp, kv_part]
             return _sds(s.shape, s.dtype, mesh, P(*parts))
         parts = [None] + [dp] + [None] * (len(s.shape) - 2)
         if shard_seq:

@@ -94,12 +94,14 @@ def reachable_spec_ks(draft_k: int, max_seq: int) -> set[int]:
 def rollback_rows(caches: dict, lo, hi) -> dict:
     """Zero arena rows s in [lo[b], hi[b]] for every slot b.
 
-    Cache leaves are (n_blocks, slots, S, ...): axis 1 is the slot, axis
-    2 the sequence row. Zeroing (not just abandoning) rejected rows
-    restores the full-arena invariant that everything beyond the written
-    prefix equals the zero init — the next write at those positions lands
-    on the same bits a never-drafted engine would see, and the decode
-    mask (`valid = s <= pos`) never reads them in between."""
+    Cache leaves are (n_blocks, slots, S, ...) — attention K/V as merged
+    (KVh * dh) rows, which zeroing never needs per head: axis 1 is the
+    slot, axis 2 the sequence row. Zeroing (not just abandoning)
+    rejected rows restores the full-arena invariant that everything
+    beyond the written prefix equals the zero init — the next write at
+    those positions lands on the same bits a never-drafted engine would
+    see, and the decode mask (`valid = s <= pos`) never reads them in
+    between."""
     lo = jnp.asarray(lo, jnp.int32)
     hi = jnp.asarray(hi, jnp.int32)
 

@@ -421,14 +421,29 @@ def init_attention(key, cfg: ModelConfig, prefix: str, n_layers: int,
     return p, axes
 
 
+def _rows(x, dtype):
+    """(B, S, KVh, dh) keys or values as cache rows (B, S, KVh * dh)."""
+    return x.reshape(x.shape[:2] + (-1,)).astype(dtype)
+
+
+def _heads(c, kvh: int):
+    """(B, S, KVh * dh) cache rows viewed per head: (B, S, KVh, dh)."""
+    return c.reshape(c.shape[:2] + (kvh, c.shape[-1] // kvh))
+
+
 def attn_apply(lp: dict, qp: Optional[dict], cfg: ModelConfig, x, *,
                rope: tuple, window: int = 0, prefix: str,
                cache: Optional[tuple] = None, q_offset: int = 0,
                shapes: Optional[LayerShapes] = None, chunked: bool = False,
-               pages: Optional[PagedView] = None):
+               pages: Optional[PagedView] = None, layer=None):
     """lp: per-layer (unstacked) params view. cache: (k_cache, v_cache,
-    write_pos) for decode. `shapes` carries this sublayer's physical dims
-    (pruned subnets run fewer heads than the config states); default is
+    write_pos) for decode; a contiguous cache stores one layer's K and V
+    as (B, S, KVh * dh) rows. With `layer` (an int or traced int32
+    scalar), the decode branch's k/v are instead the stacked
+    (nb, B, S, KVh * dh) arenas: the token's row is written at (layer,
+    b, pos_b) and the kernel reads that layer in place, and the same
+    stacked buffers come back. `shapes` carries this sublayer's physical
+    dims (pruned subnets run fewer heads than the config states); default is
     the dense config. `chunked` scores an S-token chunk mid-sequence
     against the live cache (the speculative verify pass) instead of
     treating S > 1 as a from-scratch prefill. With `pages`, the decode
@@ -469,19 +484,21 @@ def attn_apply(lp: dict, qp: Optional[dict], cfg: ModelConfig, x, *,
         ck, cv, pos = cache
         pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
         row_upd = lambda c, u, s: jax.lax.dynamic_update_slice(
-            c, u, (s, 0, 0))
+            c, u, (s, 0))
         with jax.named_scope("kv_write"):
-            ck = jax.vmap(row_upd)(ck, k.astype(ck.dtype), pos)
-            cv = jax.vmap(row_upd)(cv, v.astype(cv.dtype), pos)
+            ck = jax.vmap(row_upd)(ck, _rows(k, ck.dtype), pos)
+            cv = jax.vmap(row_upd)(cv, _rows(v, cv.dtype), pos)
         g = H // KVh
         qh = q.reshape(B, S, KVh, g, dh)
         scores = jnp.einsum("bqkgd,bskd->bkgqs", qh.astype(jnp.float32),
-                            ck.astype(jnp.float32)) / math.sqrt(dh)
+                            _heads(ck, KVh).astype(jnp.float32)
+                            ) / math.sqrt(dh)
         valid = (jnp.arange(ck.shape[1])[None, None, :]
                  <= pos[:, None, None] + jnp.arange(S)[None, :, None])
         scores = jnp.where(valid[:, None, None, :, :], scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bkgqs,bskd->bqkgd", probs, cv.astype(jnp.float32))
+        out = jnp.einsum("bkgqs,bskd->bqkgd", probs,
+                         _heads(cv, KVh).astype(jnp.float32))
         out = out.reshape(B, S, H * dh).astype(x.dtype)
         out = qa(out, qp, f"{prefix}.attn_out.aq")
         return dense_proj(out, lp, qp, f"{prefix}.wo"), (ck, cv, pos + S)
@@ -494,10 +511,10 @@ def attn_apply(lp: dict, qp: Optional[dict], cfg: ModelConfig, x, *,
         ck, cv, pos = cache
         assert window <= 0 or S <= ck.shape[1], (S, ck.shape[1])
         with jax.named_scope("kv_write"):
-            ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                              (0, 0, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                              (0, 0, 0, 0))
+            ck = jax.lax.dynamic_update_slice(ck, _rows(k, ck.dtype),
+                                              (0, 0, 0))
+            cv = jax.lax.dynamic_update_slice(cv, _rows(v, cv.dtype),
+                                              (0, 0, 0))
         out = attention(q, k, v, cfg, window=window, q_offset=0)
         out = out.reshape(B, S, H * dh)
         out = qa(out, qp, f"{prefix}.attn_out.aq")
@@ -557,14 +574,19 @@ def attn_apply(lp: dict, qp: Optional[dict], cfg: ModelConfig, x, *,
         # a (B,) vector (continuous batching: every slot at its own
         # progress); both normalize to the per-row path.
         pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
-        slot = jnp.mod(pos, ck.shape[1]) if window > 0 else pos
-        row_upd = lambda c, u, s: jax.lax.dynamic_update_slice(
-            c, u, (s, 0, 0))
+        one_layer = layer is None       # a (B, S, KVh*dh) cache: one layer
+        if one_layer:
+            ck, cv, layer = ck[None], cv[None], 0
+        slot = jnp.mod(pos, ck.shape[2]) if window > 0 else pos
+        # one in-place row write per slot into the stacked arena
+        rk = _rows(k, ck.dtype).reshape(B, 1, 1, 1, KVh * dh)
+        rv = _rows(v, cv.dtype).reshape(B, 1, 1, 1, KVh * dh)
         with jax.named_scope("kv_write"):
-            ck = jax.vmap(row_upd)(ck, k.astype(ck.dtype), slot)
-            cv = jax.vmap(row_upd)(cv, v.astype(cv.dtype), slot)
-        k_all, v_all = ck, cv
-        # attention of the single query over the cache
+            for b in range(B):
+                at = (layer, b, slot[b], 0)
+                ck = jax.lax.dynamic_update_slice(ck, rk[b], at)
+                cv = jax.lax.dynamic_update_slice(cv, rv[b], at)
+        # attention of the single query over this layer of the arena
         g = H // KVh
         with jax.named_scope("attn_core"):
             if (_DECODE_ATTN["enabled"] and _KERNEL_DISPATCH["enabled"]
@@ -572,9 +594,12 @@ def attn_apply(lp: dict, qp: Optional[dict], cfg: ModelConfig, x, *,
                 # fused flash-decode kernel: split-K online softmax over the
                 # arena, valid-length/ring masking inside the kernel
                 out = Kops.decode_attn_op(q.reshape(B, KVh, g, dh),
-                                          k_all, v_all, pos, window=window)
+                                          ck, cv, pos, layer=layer,
+                                          window=window)
                 out = out.reshape(B, 1, H, dh).astype(x.dtype)
             else:
+                k_all = Kops.arena_layer(ck, layer, KVh)
+                v_all = Kops.arena_layer(cv, layer, KVh)
                 qh = q.reshape(B, 1, KVh, g, dh)
                 scores = jnp.einsum("bqkgd,bskd->bkgqs",
                                     qh.astype(jnp.float32),
@@ -588,14 +613,17 @@ def attn_apply(lp: dict, qp: Optional[dict], cfg: ModelConfig, x, *,
                 # permutation-invariant over KV rows, so ring order is moot).
                 # A fresh (pos < ring_len) windowed cache *must* mask its
                 # zero-initialized tail, same as the full arena.
-                valid = (jnp.arange(ck.shape[1])[None, :]
-                         < jnp.minimum(pos + 1, ck.shape[1])[:, None])
+                n_rows = k_all.shape[1]
+                valid = (jnp.arange(n_rows)[None, :]
+                         < jnp.minimum(pos + 1, n_rows)[:, None])
                 scores = jnp.where(valid[:, None, None, None, :], scores,
                                    -1e30)
                 probs = jax.nn.softmax(scores, axis=-1)
                 out = jnp.einsum("bkgqs,bskd->bqkgd", probs,
                                  v_all.astype(jnp.float32))
                 out = out.reshape(B, 1, H, dh).astype(x.dtype)
+        if one_layer:
+            ck, cv = ck[0], cv[0]
         new_cache = (ck, cv, pos + 1)
     else:
         out = attention(q, k, v, cfg, window=window, q_offset=q_offset)

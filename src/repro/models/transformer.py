@@ -409,7 +409,10 @@ class LM:
         """Decode cache arena, sized from the per-sublayer shapes — a
         pruned subnet allocates KV rows for *surviving* kv heads (and
         mamba channels / rwkv heads) only, so the HBM the arena pins
-        shrinks with realized sparsity, not just the weight bytes."""
+        shrinks with realized sparsity, not just the weight bytes.
+        Attention K and V are (nb, batch, S, KVh * dh): the heads merged
+        into one lane-dense row, the layout the flash-decode kernel reads
+        in place (S = the window for ring arenas)."""
         cfg = self.cfg
         caches = {}
         for sub, shp in zip(self.plan, self.shapes):
@@ -417,10 +420,9 @@ class LM:
             nb = self.n_blocks
             if sub.mixer == "attn":
                 S = min(max_seq, cfg.window) if cfg.window > 0 else max_seq
-                caches[f"{pre}.k"] = jnp.zeros(
-                    (nb, batch, S, shp.n_kv_heads, shp.d_head), dtype)
-                caches[f"{pre}.v"] = jnp.zeros(
-                    (nb, batch, S, shp.n_kv_heads, shp.d_head), dtype)
+                row = shp.n_kv_heads * shp.d_head
+                caches[f"{pre}.k"] = jnp.zeros((nb, batch, S, row), dtype)
+                caches[f"{pre}.v"] = jnp.zeros((nb, batch, S, row), dtype)
             elif sub.mixer == "mamba":
                 Di = shp.mamba_inner
                 caches[f"{pre}.h"] = jnp.zeros(
@@ -438,6 +440,13 @@ class LM:
                 caches[f"{pre}.cm_shift"] = jnp.zeros((nb, batch, D),
                                                       jnp.float32)
         return caches
+
+    def kv_heads(self) -> dict[str, int]:
+        """KV-head count of each attention cache leaf: where a contiguous
+        arena's merged (KVh * dh) axis splits at whole heads."""
+        return {f"blocks.{sub.j}.{kv}": shp.n_kv_heads
+                for sub, shp in zip(self.plan, self.shapes)
+                if sub.mixer == "attn" for kv in ("k", "v")}
 
     def init_paged_cache(self, batch: int, n_pages: int, page_size: int,
                          dtype=jnp.bfloat16, kv_bits: Optional[int] = None):
@@ -512,6 +521,13 @@ class LM:
         norms, rope, activation and residual adds sit under the
         `pointwise` name scope, beside the layers' `proj`, `kv_write`
         and `attn_core`.
+
+        Contiguous attention arenas ride the layer scan's carry beside
+        the residual stream: each layer writes its token's rows into the
+        one stacked buffer at (layer, slot, row) and flash decode reads
+        that layer of it in place, so nothing slices, restacks or copies
+        the arena. Paged pools and the recurrent-state leaves (O(1) per
+        slot) stay per-layer scan inputs and outputs.
         Returns (logits, new_caches)."""
         cfg = self.cfg
         params, qp_body = self._prequantize(params, qparams)
@@ -527,10 +543,16 @@ class LM:
             rope = (jnp.cos(ang)[:, None],
                     jnp.sin(ang)[:, None])                  # (B, 1, dh/2)
 
-        def body(x, inp):
+        inplace = set() if pages is not None else set(self.kv_heads())
+        arena = {k: v for k, v in caches.items() if k in inplace}
+        per_layer = {k: v for k, v in caches.items() if k not in inplace}
+
+        def body(carry, inp):
+            x, arena = carry
             x = self._constrain(x)
             lp = inp["p"]
             cc = inp["c"]
+            arena = dict(arena)
             new_c = {}
             for sub, shp in zip(self.plan, self.shapes):
                 pre = f"blocks.{sub.j}"
@@ -550,9 +572,9 @@ class LM:
                 elif sub.mixer == "attn":
                     mix, nc = Lyr.attn_apply(
                         lp, qp_body, cfg, h, rope=rope, window=cfg.window,
-                        prefix=f"{pre}.attn", shapes=shp,
-                        cache=(cc[f"{pre}.k"], cc[f"{pre}.v"], pos))
-                    new_c[f"{pre}.k"], new_c[f"{pre}.v"], _ = nc
+                        prefix=f"{pre}.attn", shapes=shp, layer=inp["l"],
+                        cache=(arena[f"{pre}.k"], arena[f"{pre}.v"], pos))
+                    arena[f"{pre}.k"], arena[f"{pre}.v"], _ = nc
                 elif sub.mixer == "mamba":
                     mix, ns = Lyr.mamba_apply(
                         lp, qp_body, cfg, h, prefix=f"{pre}.mamba", shapes=shp,
@@ -580,19 +602,25 @@ class LM:
                     new_c[f"{pre}.cm_shift"] = ns
                 with jax.named_scope("pointwise"):
                     x = self._constrain(x + f)
-            return x, new_c
+            return (x, arena), new_c
 
         bp = self._block_params(params)
         if self.n_blocks <= 2:
             new_list = []
             for i in range(self.n_blocks):
-                x, nc = body(x, {"p": {k: v[i] for k, v in bp.items()},
-                                 "c": {k: v[i] for k, v in caches.items()}})
+                (x, arena), nc = body(
+                    (x, arena), {"p": {k: v[i] for k, v in bp.items()},
+                                 "c": {k: v[i] for k, v in per_layer.items()},
+                                 "l": i})
                 new_list.append(nc)
             new_caches = {k: jnp.stack([nc[k] for nc in new_list])
                           for k in new_list[0]}
         else:
-            x, new_caches = jax.lax.scan(body, x, {"p": bp, "c": caches})
+            (x, arena), new_caches = jax.lax.scan(
+                body, (x, arena),
+                {"p": bp, "c": per_layer,
+                 "l": jnp.arange(self.n_blocks, dtype=jnp.int32)})
+        new_caches.update(arena)
         x = Lyr.rmsnorm(self._constrain(x), params["final_norm"], cfg.norm_eps)
         logits = self._head(params, x)
         if cfg.num_codebooks:

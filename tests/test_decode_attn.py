@@ -138,6 +138,73 @@ def test_op_backend_dispatch():
                                rtol=1e-4, atol=ATOL)
 
 
+# ------------------------------------------------- the stacked arena read
+# (KVh, S, chunk, ring): the cells' 8 and 4 KV heads at GQA 2; an arena
+# that is not a whole number of chunks (the padded path); a ring past
+# its wrap
+ARENA_CASES = [
+    (8, 64, 16, False),
+    (4, 64, 16, False),
+    (4, 40, 16, False),
+    (8, 48, 16, True),
+]
+
+
+@pytest.mark.parametrize("case", ARENA_CASES,
+                         ids=[f"KV{h}S{s}c{c}{'ring' if r else ''}"
+                              for h, s, c, r in ARENA_CASES])
+def test_stacked_arena_layer_matches_sliced_layer(case):
+    """The kernel reading layer l of the stacked (nb, B, S, KVh*dh) arena
+    in place is bitwise the kernel on that layer sliced out beforehand,
+    for every l traced (as the layer scan passes it) and for a static l;
+    both match the oracle. Slots sit at row 0, mid-chunk and the last
+    row (a ring: past its wrap)."""
+    KVh, S, chunk, ring = case
+    nb, B, g, dh = 3, 3, 2, 32
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(S + KVh), 3)
+    q = jax.random.normal(k1, (B, KVh, g, dh))
+    ka = jax.random.normal(k2, (nb, B, S, KVh * dh))
+    va = jax.random.normal(k3, (nb, B, S, KVh * dh))
+    pos = (jnp.asarray([S + 3, 2 * S + chunk // 2, 3 * S - 1]) if ring
+           else jnp.asarray([0, chunk + chunk // 2, S - 1])).astype(jnp.int32)
+    window = S if ring else 0
+    kw = dict(window=window, chunk=chunk, interpret=True)
+    in_place = jax.jit(lambda l: da.decode_attn_pallas(q, ka, va, pos,
+                                                       layer=l, **kw))
+    sliced = jax.jit(lambda kh, vh: da.decode_attn_pallas(q, kh, vh, pos,
+                                                          **kw))
+    for l in range(nb):
+        kh = ka[l].reshape(B, S, KVh, dh)
+        vh = va[l].reshape(B, S, KVh, dh)
+        want = np.asarray(sliced(kh, vh))
+        np.testing.assert_array_equal(np.asarray(in_place(jnp.int32(l))),
+                                      want)
+        np.testing.assert_allclose(
+            want, np.asarray(ref.decode_attn_ref(q, kh, vh, pos)),
+            rtol=1e-4, atol=ATOL)
+    # a static index (the unrolled shallow-model branch) reads alike
+    np.testing.assert_array_equal(
+        np.asarray(da.decode_attn_pallas(q, ka, va, pos, layer=nb - 1, **kw)),
+        want)
+
+
+def test_op_reads_a_stacked_layer():
+    """`ops.decode_attn_op` with a layer index: xla-ref slices the layer
+    and is the oracle bit-for-bit; pallas-interpret reads it in place."""
+    nb, B, S, KVh, g, dh = 2, 2, 20, 2, 2, 8
+    q, _, _, pos = _case(19, B, S, KVh, g, dh)
+    ka = jax.random.normal(jax.random.PRNGKey(5), (nb, B, S, KVh * dh))
+    va = jax.random.normal(jax.random.PRNGKey(6), (nb, B, S, KVh * dh))
+    want = ref.decode_attn_ref(q, ka[1].reshape(B, S, KVh, dh),
+                               va[1].reshape(B, S, KVh, dh), pos)
+    got = ops.decode_attn_op(q, ka, va, pos, layer=1, backend="xla-ref")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    got = ops.decode_attn_op(q, ka, va, pos, layer=jnp.int32(1),
+                             backend="pallas-interpret")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=ATOL)
+
+
 # ------------------------------------------------- windowed decode masking
 def _tiny_cfg(window: int) -> ModelConfig:
     return ModelConfig(name="tiny-windowed", family="dense", n_layers=1,
@@ -161,9 +228,9 @@ def test_fresh_windowed_cache_masks_unwritten_rows(kernel):
     cfg0 = dataclasses.replace(cfgw, window=0)
     params, _ = Lyr.init_attention(jax.random.PRNGKey(0), cfgw,
                                    "blocks.0.attn", 0, jnp.float32)
-    B, KVh, dh = 2, cfgw.n_kv_heads, cfgw.d_head
-    ring = (jnp.zeros((B, W, KVh, dh)), jnp.zeros((B, W, KVh, dh)))
-    full = (jnp.zeros((B, 12, KVh, dh)), jnp.zeros((B, 12, KVh, dh)))
+    B, row = 2, cfgw.n_kv_heads * cfgw.d_head
+    ring = (jnp.zeros((B, W, row)), jnp.zeros((B, W, row)))
+    full = (jnp.zeros((B, 12, row)), jnp.zeros((B, 12, row)))
     with Lyr.use_decode_attn(kernel):
         for t in range(4):   # strictly pre-wrap: t < W
             x = jax.random.normal(jax.random.PRNGKey(100 + t),

@@ -275,3 +275,87 @@ def test_one_token_request_does_not_stall_the_queue():
             eng.submit(prompts[2], 1)]
     out = eng.run()
     assert [len(out[r]) for r in rids] == [1, 8, 1]
+
+
+# ------------------------------------------------ the arena decoded in place
+INPLACE_MODES = {
+    "dense": {},
+    "pruned_packed": dict(packed=True, bits_init=4.0, prune_sparsity=0.5),
+    "windowed": {},
+    "speculative": {},
+}
+
+
+def _sequential_tokens(lm, params, qparams, prompt, gen, max_seq):
+    """One request alone through `decode_step`, a token at a time."""
+    step = jax.jit(lm.decode_step)
+    caches = lm.init_cache(1, max_seq, dtype=jnp.float32)
+    toks = jnp.asarray(prompt)[None]
+    for q in range(len(prompt)):
+        lg, caches = step(params, qparams, caches, toks[:, q:q + 1],
+                          jnp.int32(q))
+    out = [int(jnp.argmax(lg[0, -1]))]
+    for q in range(gen - 1):
+        lg, caches = step(params, qparams, caches,
+                          jnp.asarray([[out[-1]]], jnp.int32),
+                          jnp.int32(len(prompt) + q))
+        out.append(int(jnp.argmax(lg[0, -1])))
+    return np.asarray(out, np.int32)
+
+
+@pytest.mark.parametrize("mode", sorted(INPLACE_MODES))
+def test_inplace_arena_token_identity(mode):
+    """On the stacked (nb, B, S, KVh*dh) arena that decode writes and
+    reads in place, the engine with the flash-decode kernel (interpreted)
+    is token-identical to the engine on the xla-ref oracle and to each
+    request decoded alone through `decode_step`: dense, pruned + packed
+    b4, a windowed ring that wraps, and a speculative engine whose draft
+    arena decodes in place too."""
+    from repro.kernels import use_backend
+    from repro.launch.speculative import build_draft
+    cfg = dataclasses.replace(get_arch(ARCH, smoke=True), dtype="float32",
+                              window=6 if mode == "windowed" else 0)
+    lm = LM(cfg)
+    ckpt, _ = lm.init(jax.random.PRNGKey(0))
+    params, qparams, _ = prepare_serving(lm, ckpt, **INPLACE_MODES[mode])
+    prompts = synthetic_prompts(cfg, [5, 3, 6])
+    gens = [7, 4, 6]
+
+    def serve(backend):
+        draft = (build_draft(ARCH, True, ckpt, sparsity=0.5, bits=8.0)
+                 if mode == "speculative" else None)
+        eng = Engine(lm, params, qparams, max_slots=2, max_seq=16,
+                     draft=draft, draft_k=2)
+        for p, g in zip(prompts, gens):
+            eng.submit(p, g)
+        with use_backend(backend):
+            return eng.run()
+
+    kernel, oracle = serve("pallas-interpret"), serve("xla-ref")
+    assert sorted(kernel) == sorted(oracle) == [0, 1, 2]
+    for rid, (p, g) in enumerate(zip(prompts, gens)):
+        np.testing.assert_array_equal(kernel[rid], oracle[rid])
+        np.testing.assert_array_equal(
+            oracle[rid], _sequential_tokens(lm, params, qparams, p, g, 16),
+            err_msg=f"{mode}: request {rid}")
+
+
+def test_arena_is_held_once():
+    """The slot insert takes the arena over (the arrays handed to it are
+    deleted), and after a decode window exactly one arena's worth of
+    attention-cache arrays is live."""
+    import gc
+    lm, params, qparams = _serving_lm()
+    eng = Engine(lm, params, qparams, max_slots=3, max_seq=21)
+    for p in synthetic_prompts(lm.cfg, [5, 4]):
+        eng.submit(p, 6)
+    kv = sorted(lm.kv_heads())
+    handed = [eng.caches[k] for k in kv]
+    assert eng._admit() == 2
+    assert all(a.is_deleted() for a in handed)
+    del handed
+    assert eng._window()
+    gc.collect()
+    shapes = {tuple(eng.caches[k].shape) for k in kv}
+    live = [a for a in jax.live_arrays() if tuple(a.shape) in shapes]
+    assert {id(a) for a in live} == {id(eng.caches[k]) for k in kv}

@@ -117,6 +117,26 @@ def test_tp_decode_attn_exact():
 
 
 @needs4
+def test_tp_decode_attn_stacked_layer_exact():
+    """The stacked (nb, B, S, KVh*dh) arena read at a layer index splits
+    its merged axis at whole heads: each device's kernel reads its heads
+    of that layer in place, identical to the unsharded op."""
+    mesh = make_tp_mesh(4)
+    k = jax.random.PRNGKey(4)
+    nb, B, S, KVh, dh, g = 3, 2, 32, 4, 16, 2
+    q = jax.random.normal(k, (B, KVh, g, dh), jnp.float32)
+    ka = jax.random.normal(jax.random.fold_in(k, 1), (nb, B, S, KVh * dh))
+    va = jax.random.normal(jax.random.fold_in(k, 2), (nb, B, S, KVh * dh))
+    pos = jnp.asarray([19, 11], jnp.int32)
+    for layer in range(nb):
+        want = ops.decode_attn_op(q, ka, va, pos, layer=layer,
+                                  backend="xla-ref")
+        got = da.tp_decode_attn(q, ka, va, pos, layer=jnp.int32(layer),
+                                mesh=mesh, backend="xla-ref")
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@needs4
 def test_tp_decode_attn_rejects_indivisible_heads():
     mesh = make_tp_mesh(4)
     q = jnp.zeros((1, 3, 2, 8), jnp.float32)      # 3 KV heads % 4 != 0
@@ -192,6 +212,28 @@ def test_kv_cache_specs_head_axis():
     assert tuple(specs["blocks.1.k"]) in ((), (None,) * 5)
     assert specs["blocks.0.k_scale"][3] == "model"
     assert tuple(specs["blocks.0.h"]) in ((), (None,) * 4)
+
+
+def test_kv_cache_specs_merged_axis_whole_heads():
+    """A contiguous arena stores K/V as (nb, B, S, KVh*dh): the merged
+    axis shards only when the leaf's KV heads divide the mesh, so a
+    split never lands inside a head; otherwise (or without head counts)
+    it replicates, however the merged width divides."""
+    mesh = jax.sharding.AbstractMesh((1, 4), ("data", "model"))
+    shapes = {"blocks.0.k": (2, 4, 64, 4 * 16),     # KVh=4: shard
+              "blocks.0.v": (2, 4, 64, 4 * 16),
+              "blocks.1.k": (2, 4, 64, 6 * 16),     # KVh=6: replicate
+              "blocks.1.v": (2, 4, 64, 6 * 16),
+              "blocks.0.h": (2, 4, 32, 7)}           # recurrent state
+    heads = {"blocks.0.k": 4, "blocks.0.v": 4, "blocks.1.k": 6,
+             "blocks.1.v": 6}
+    specs = kv_cache_specs(mesh, shapes, heads)
+    assert tuple(specs["blocks.0.k"]) == (None, None, None, "model")
+    assert tuple(specs["blocks.0.v"]) == (None, None, None, "model")
+    assert tuple(specs["blocks.1.k"]) in ((), (None,) * 4)
+    assert tuple(specs["blocks.0.h"]) in ((), (None,) * 4)
+    assert all(tuple(sp) in ((), (None,) * 4)
+               for sp in kv_cache_specs(mesh, shapes).values())
 
 
 # ------------------------------------------------------------ engine layer

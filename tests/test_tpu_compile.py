@@ -8,14 +8,18 @@ executes: shapes only.
 
 Widths are internlm2-1.8b's (d_model 2048, d_ff 8192, 8 KV heads of
 128, GQA 2): decode GEMMs at M=8 over (K, N) = (2048, 8192), flash
-decode over an 8-slot, 2048-row arena, paged at 16 rows per page.
+decode over an 8-slot, 2048-row arena, paged at 16 rows per page. A
+3-layer model's decode window and slot insert are compiled whole, to
+pin that the stacked KV arena is written and read in place.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU compiler library, and the
 suite runs under several workers. All compile tests live in this one
 file so they share that process.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -198,3 +202,95 @@ def test_decode_memory_matches_launch_model(one_chip):
     vmem = introspect.launch_vmem_bytes(launch)
     assert vmem <= introspect.VMEM_BUDGET_BYTES
     assert vmem < ma.argument_size_in_bytes
+
+
+# ----------------------------------------------- the decode window in place
+NB, SLOTS, ROWS = 3, 4, 1024          # layers, arena slots, arena rows
+_COPYING = {"copy", "copy-start", "dynamic-slice", "reshape", "transpose",
+            "pad", "slice", "concatenate", "fusion"}
+
+
+def _instructions(hlo: str):
+    """(name, opcode, element count) of every array-valued instruction."""
+    pat = re.compile(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+    for line in hlo.splitlines():
+        m = pat.match(line)
+        if m:
+            dims = [int(d) for d in m.group(2).split(",") if d]
+            yield m.group(1), m.group(3), math.prod(dims), line
+
+
+def _inplace_lm():
+    from repro.configs.base import ModelConfig
+    from repro.models.transformer import LM
+    return LM(ModelConfig(name="inplace", family="dense", n_layers=NB,
+                          d_model=512, n_heads=16, n_kv_heads=KVH,
+                          d_head=DH, d_ff=1536, vocab=1000,
+                          dtype="bfloat16"))
+
+
+def _arena_moves(hlo: str) -> list[str]:
+    """Ops that produce a layer's worth of the arena, or all of it, by
+    slicing, copying, restacking or relayout."""
+    layer = SLOTS * ROWS * KVH * DH
+    return [line.strip()[:160] for name, op, n, line in _instructions(hlo)
+            if n in (layer, NB * layer) and op in _COPYING
+            and "dynamic-update-slice" not in name
+            and "dynamic_update_slice" not in name]
+
+
+def test_decode_window_reads_the_arena_in_place(one_chip):
+    """`LM.decode_step` in a 2-step window scan with the arena donated,
+    compiled for the chip: the arena aliases the window's output, no op
+    slices, restacks, copies or relayouts a layer's worth of it (or all
+    of it), and flash decode takes the stacked arena itself as its
+    operand."""
+    from repro.kernels import use_backend
+    lm = _inplace_lm()
+
+    def window(params, caches, tok, pos):
+        def body(carry, _):
+            caches, tok, pos = carry
+            logits, caches = lm.decode_step(params, None, caches, tok, pos)
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            return (caches, nxt[:, None], pos + 1), nxt
+
+        (caches, _, _), toks = jax.lax.scan(body, (caches, tok, pos), None,
+                                            length=2)
+        return toks, caches
+
+    shapes = (jax.eval_shape(lambda: lm.init(jax.random.PRNGKey(0))[0]),
+              jax.eval_shape(lambda: lm.init_cache(SLOTS, ROWS)),
+              jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32),
+              jax.ShapeDtypeStruct((SLOTS,), jnp.int32))
+    args = jax.tree_util.tree_map(
+        lambda a: _sds(one_chip, a.shape, a.dtype), shapes)
+    with use_backend("pallas-tpu"):
+        compiled = jax.jit(window, donate_argnums=(1,)).lower(
+            *args).compile()
+    hlo = compiled.as_text()
+
+    arena_bytes = NB * SLOTS * ROWS * KVH * DH * 2
+    assert compiled.memory_analysis().alias_size_in_bytes == 2 * arena_bytes
+    moved = _arena_moves(hlo)
+    assert not moved, "\n".join(moved)
+    stacked = f"bf16[{NB},{SLOTS},{ROWS},{KVH * DH}]"
+    calls = [line for line in hlo.splitlines()
+             if "%flash_decode" in line and "custom-call(" in line]
+    assert calls and all(line.count(stacked) >= 2 for line in calls), calls
+
+
+def test_slot_insert_writes_the_arena_in_place(one_chip):
+    """The engine's slot insert donates the arena: a prefilled row lands
+    in it in place, with no copy of the arena."""
+    from repro.launch.engine import Engine
+    lm = _inplace_lm()
+    params, _ = lm.init(jax.random.PRNGKey(0))
+    eng = Engine(lm, params, None, max_slots=SLOTS, max_seq=ROWS)
+    args = jax.tree_util.tree_map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        (eng.caches, eng._fresh_row(), jnp.int32(0)))
+    compiled = eng._insert.lower(*args).compile()
+    arena_bytes = NB * SLOTS * ROWS * KVH * DH * 2
+    assert compiled.memory_analysis().alias_size_in_bytes == 2 * arena_bytes
+    assert not _arena_moves(compiled.as_text())
