@@ -1,53 +1,23 @@
-"""Operations and bytes the served model needs, counted from the served
-widths (`weights.served_widths`). Model FLOPs only: the embedding lookup
-is a gather and counts nothing, idle slots count nothing, and nothing
-recomputed counts. Bytes are the least a step has to move: every weight
-once per decode step, and the cached K/V rows each active request
-attends to.
-"""
+"""Counting helpers shared by the architecture modules
+(`bench/arch/<model_type>.py`), which count the operations and bytes of
+their own decode steps."""
 from __future__ import annotations
 
-from benchlib.weights import projection_shapes
 
-
-def matmul_params(w: dict) -> dict:
-    """Weights that multiply each token: per layer (all layers), and the
-    head."""
-    shp = projection_shapes(w)
-    per_layer = sum(k * n for name, (k, n) in shp.items() if name != "head")
-    return {"layers": w["L"] * per_layer, "head": shp["head"][0] * shp["head"][1]}
-
-
-def weight_bytes(w: dict) -> int:
-    """Bytes one decode step reads from the served weights (the embedding
-    rows it gathers are left out)."""
-    mp = matmul_params(w)
-    n = mp["layers"] + mp["head"]
-    if w["bits"] is None:
-        return 2 * n
-    return n * w["bits"] // 8
-
-
-def decode_context_sum(prompt_len: int, out_len: int) -> int:
+def decode_context_sum(prompt_len: int, out_len: int, window: int = 0
+                       ) -> int:
     """Keys attended over a request's decode steps: the first output
     token comes from the prefill, step i (1..out_len-1) attends the
-    prompt and i tokens."""
+    prompt and i tokens, or the `window` latest of them where a sliding
+    window is set."""
     n = out_len - 1
+    if window > 0:
+        return sum(min(prompt_len + i, window) for i in range(1, n + 1))
     return n * prompt_len + n * (n + 1) // 2
 
 
-def decode_flops(w: dict, tokens: int, context_sum: int) -> float:
-    """FLOPs of `tokens` decoded tokens that attend `context_sum` keys in
-    all: 2 per weight per token, and 4 * q_dim per key per layer (scores
-    and values)."""
-    mp = matmul_params(w)
-    q_dim = w["H"] * w["dh"]
-    return (2.0 * (mp["layers"] + mp["head"]) * tokens
-            + 4.0 * q_dim * w["L"] * context_sum)
-
-
-def decode_bytes(w: dict, steps: int, context_sum: int,
-                 kv_row_bytes: int) -> float:
-    """Least bytes `steps` decode steps move: the weights once a step,
-    and each attended cache row."""
-    return float(weight_bytes(w)) * steps + float(kv_row_bytes) * context_sum
+def context_sum(requests: list, window: int = 0) -> int:
+    """`decode_context_sum` over every request of a run (`ctx["requests"]`)
+    that has tokens."""
+    return sum(decode_context_sum(r["prompt"].size, len(r["tokens"]), window)
+               for r in requests if len(r["tokens"]) > 0)

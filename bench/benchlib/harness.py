@@ -2,10 +2,14 @@
 make the weights, warm up, measure, check, and print the result line.
 
 A cell is found by name. Its configuration file is the one
-`BENCHMARK.json` names; its traffic mix is `bench/traffic/<traffic>.json`;
-each metric is read by `bench/metrics/<metric>.py`, which defines
-`read(ctx)` and returns the number or None. So a cell, a mix or a metric
-is added with files and entries, and no edit here.
+`BENCHMARK.json` names; the file's `model_type` names its architecture
+module, `bench/arch/<model_type>.py` (weights, program config, arena
+bytes, FLOP and byte counts: see `bench/arch/internlm2.py`), and its
+`reference` the plain reference, `bench/reference/<reference>.py`; its
+traffic mix is `bench/traffic/<traffic>.json`; each metric is read by
+`bench/metrics/<metric>.py`, which defines `read(ctx)` and returns the
+number or None. So a cell, a mix, a metric or an architecture is added
+with files and entries, and no edit here.
 """
 from __future__ import annotations
 
@@ -17,7 +21,7 @@ import time
 
 import numpy as np
 
-from benchlib import traffic, weights
+from benchlib import traffic
 
 SAMPLE = 8          # requests compared with the reference in each run
 
@@ -50,6 +54,7 @@ def load_spec(root: str, workload: str) -> dict:
              if (workload in m["workloads"] if "workloads" in m
                  else m["moves"] in reported)]
     return {"root": root, "cell": cell, "config": cfg, "traffic": mix,
+            "arch": load_module(root, "arch", cfg["model_type"]),
             "end_to_end": e2e, "per_layer": layer}
 
 
@@ -143,19 +148,18 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     from benchlib import serve, trace_reduce
     from benchlib.peaks import peaks_for
 
-    cfg, mix, cell = spec["config"], spec["traffic"], spec["cell"]
-    widths = weights.served_widths(cfg)
-    kv_row = weights.kv_bytes_per_row(cfg)
-    slots, rows = traffic.arena(mix, kv_row)
+    cfg, mix, cell, arch = (spec["config"], spec["traffic"], spec["cell"],
+                            spec["arch"])
+    slots, rows = traffic.arena(mix, lambda n: arch.slot_bytes(cfg, n))
     dev = device_record()
     peaks = peaks_for(dev["kind"]) if dev["platform"] == "tpu" else None
     spans = serve.Spans(trace)
 
     with spans("bench.make_weights"):
-        params = weights.make_params(cfg, seed)
+        params = arch.make_params(cfg, seed)
         jax.block_until_ready(params)
     with spans("bench.build_engine"):
-        eng = serve.build_engine(cfg, params, slots, rows)
+        eng = serve.build_engine(arch, cfg, params, slots, rows)
     with spans("bench.warm"):
         serve.warm(eng, mix, cfg["vocab_size"])
     spans.wrap_engine(eng)
@@ -166,10 +170,18 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
 
     tracer = trace_reduce.Tracer(spec["root"]) if trace else None
     trace_s = min(float(seconds), 5.0)
+    traced = {}
+
+    def delta(now):
+        return {k: (v - base.get(k, 0)) for k, v in now.items()}
+
+    def stop_tracer():
+        tracer.stop()
+        traced.update(delta(eng.stats))
 
     def maybe_stop(elapsed):
         if tracer is not None and tracer.running and elapsed >= trace_s:
-            tracer.stop()
+            stop_tracer()
 
     compiles = CompileCount()
     if tracer is not None:
@@ -177,8 +189,8 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     out = serve.closed_loop(eng, mix, seed, seconds, cfg["vocab_size"],
                             spans, on_drive=maybe_stop)
     if tracer is not None and tracer.running:
-        tracer.stop()
-    stats = {k: (v - base.get(k, 0)) for k, v in out["stats"].items()}
+        stop_tracer()
+    stats = delta(out["stats"])
     mem_peak = peak_bytes()
     compiles.close()
 
@@ -206,13 +218,14 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     correct = verdict(checks)
 
     ctx = {"stats": stats, "requests": reqs, "window_s": window_s,
-           "setup_s": setup_s, "widths": widths, "kv_row_bytes": kv_row,
-           "slots": slots, "peaks": peaks, "trace": None}
+           "setup_s": setup_s, "arch": arch, "config": cfg,
+           "widths": arch.served_widths(cfg), "slots": slots,
+           "peaks": peaks, "trace": None}
     device = dict(dev, memory_peak_bytes=mem_peak)
     result = {"correct": correct, "attempted": len(done), "failed": failed}
     if trace:
         red = tracer.reduce()
-        ctx["trace"] = red
+        ctx.update(trace=red, parts=red["parts"], trace_stats=traced)
         device.update(busy_s=red["busy_s"], window_s=red["window_s"])
         result["metrics"] = read_metrics(spec, spec["per_layer"], ctx)
         result["device"] = device

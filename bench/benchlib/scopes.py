@@ -39,7 +39,7 @@ from __future__ import annotations
 import bisect
 import re
 
-from benchlib import flops, trace_reduce
+from benchlib import trace_reduce
 
 KERNELS = ("flash_decode", "flash_decode_paged")
 PARTS = ("proj", "kv_write", "attn_core", "pointwise")
@@ -266,17 +266,18 @@ def _roofline(ctx, part: str, nbytes) -> float | None:
 
 def flash_decode_roofline(ctx) -> float | None:
     """Least bytes of the attended cache rows in the traced window
-    (`kv_rows_attended` x bytes a row holds over all layers) over the
-    flash-decode kernel's device time x HBM bandwidth, in %."""
+    (`kv_rows_attended` x the bytes a row holds, from the architecture
+    module) over the flash-decode kernel's device time x HBM bandwidth,
+    in %."""
     return _roofline(ctx, "flash_decode",
-                     lambda st: ctx["kv_row_bytes"]
+                     lambda st: ctx["arch"].kv_row_bytes(ctx["config"])
                      * st.get("kv_rows_attended", 0))
 
 
 def proj_roofline(ctx) -> float | None:
-    """Served weight bytes (every projection and the head, once a decode
-    step) x the traced window's decode steps over `proj` device time x
+    """Served weight bytes a decode step reads (from the architecture
+    module) x the traced window's decode steps over `proj` device time x
     HBM bandwidth, in %."""
     return _roofline(ctx, "proj",
-                     lambda st: flops.weight_bytes(ctx["widths"])
+                     lambda st: ctx["arch"].weight_bytes(ctx)
                      * st.get("decode_steps", 0))

@@ -2,14 +2,14 @@
 weights, warm the cell's shapes, and run the closed loop.
 
 From the program this takes only the system under test: the model
-(`repro.models.transformer.LM`, told the served widths), the engine
+(`repro.models.transformer.LM`, configured by the architecture module
+at the served widths), the engine
 (`repro.launch.engine.Engine`) and its counters (`Engine.stats`) and
 request stamps (`Request.admit_t`, `finish_t`).
 """
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 import time
 
 import numpy as np
@@ -20,41 +20,20 @@ from benchlib import traffic
 MARK = 10.0         # seconds between the window's logged token counts
 
 
-def program_config(cfg: dict):
-    """The program's model config for a benchmark configuration file."""
-    from repro.configs.base import ModelConfig
-    return ModelConfig(
-        name=cfg["name"], family="dense", n_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], d_head=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]), dtype=cfg["torch_dtype"],
-        tie_embeddings=bool(cfg["tie_word_embeddings"]))
-
-
-def build_engine(cfg: dict, params: dict, slots: int, rows: int):
-    """An engine serving `params` over a (slots, rows) arena. A pruned
-    artifact's widths are installed on the model first, so prefill,
-    decode and the arena run at them. The configuration's `engine` group
-    holds the engine's own options (`paged`, `page_size`, `kv_bits`,
-    `n_pages`, `prefix_sharing`), and `prefill_chunk` there swaps in the
-    chunked-prefill scheduler."""
+def build_engine(arch, cfg: dict, params: dict, slots: int, rows: int):
+    """An engine serving `params` over a (slots, rows) arena, on the
+    model the architecture module `arch` configures: its program config,
+    and a pruned artifact's slim plan installed first, so prefill, decode
+    and the arena run at the served widths. The configuration's `engine`
+    group holds the engine's own options (`paged`, `page_size`,
+    `kv_bits`, `n_pages`, `prefix_sharing`), and `prefill_chunk` there
+    swaps in the chunked-prefill scheduler."""
     from repro.launch.engine import Engine
     from repro.models.transformer import LM
-    lm = LM(program_config(cfg))
-    art = cfg.get("artifact")
-    if art:
-        from repro.core.subnet import SlimPlan
-        from repro.models.layers import LayerShapes
-        shp = dataclasses.replace(
-            LayerShapes.from_config(lm.cfg),
-            n_heads=art["num_attention_heads"],
-            n_kv_heads=art["num_key_value_heads"],
-            d_ff=art["intermediate_size"])
-        lm.apply_slim_plan(SlimPlan(layer_shapes=[shp for _ in lm.plan],
-                                    kept_units={},
-                                    sparsity=float(art["sparsity"])))
+    lm = LM(arch.program_config(cfg))
+    plan = arch.slim_plan(cfg, lm)
+    if plan is not None:
+        lm.apply_slim_plan(plan)
     opts = dict(cfg.get("engine", {}))
     chunk = opts.pop("prefill_chunk", None)
     scheduler = None

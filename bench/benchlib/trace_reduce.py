@@ -3,9 +3,10 @@ that took the most time, and the longest idle gaps with what the host
 was doing in each.
 
 `Tracer` records one window with JAX's profiler, marked on the host by a
-`bench.window` span. `extract` reads the `.xplane.pb` file into plain
-event lists; `reduce` works on those lists alone, so a trimmed trace
-kept as JSON exercises it in the tests.
+`bench.window` span, and reads it back with the decode window's device
+time by part of the model (`scopes.parts`). `extract` reads the
+`.xplane.pb` file into plain event lists; `reduce` works on those lists
+alone, so a trimmed trace kept as JSON exercises it in the tests.
 
 Device operations are the events of each TPU plane's "XLA Ops" line.
 Host spans are the benchmark's own annotations (`bench.*`, `engine.*`)
@@ -51,12 +52,15 @@ class Tracer:
         self.running = False
 
     def reduce(self) -> dict:
+        """`reduce` of the trace, and its `parts`."""
+        from benchlib import scopes
         paths = glob.glob(os.path.join(self.dir, "**", "*.xplane.pb"),
                           recursive=True)
         if not paths:
             raise RuntimeError(f"no trace written under {self.dir}")
         try:
-            return reduce(extract(paths[0]))
+            return dict(reduce(extract(paths[0])),
+                        parts=scopes.parts(scopes.extract(paths[0])))
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
 

@@ -98,15 +98,17 @@ def block(mix: dict, seed: int, index: int, vocab: int) -> list[dict]:
             for s, m in sizes(mix, int(mix["block"]), rng)]
 
 
-def arena(mix: dict, kv_bytes_per_row: int) -> tuple[int, int]:
+def arena(mix: dict, slot_bytes) -> tuple[int, int]:
     """(slots, rows) of the KV arena: rows from the mix, slots as many as
-    the mix's KV byte budget holds, rounded down to `slot_multiple`."""
+    the mix's KV byte budget holds, rounded down to `slot_multiple`, where
+    one slot of `rows` rows takes `slot_bytes(rows)` device bytes (the
+    architecture module's count)."""
     a = mix["arena"]
     rows = int(a["rows"])
     if rows < longest_rows(mix):
         raise ValueError(f"arena rows {rows} < the {longest_rows(mix)} the "
                          f"longest request needs")
-    per_slot = rows * kv_bytes_per_row
+    per_slot = int(slot_bytes(rows))
     mult = int(a.get("slot_multiple", 8))
     slots = int(a["kv_bytes"]) // per_slot // mult * mult
     if slots < 1:

@@ -16,10 +16,11 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 
-def make_tiny_root(path) -> str:
-    """A checkout holding the benchmark's code and two tiny cells (dense
-    and packed closed loops) in place of the real ones, with their
-    metrics."""
+def make_tiny_root(path, window: bool = True) -> str:
+    """A checkout holding the benchmark's code and tiny cells in place of
+    the real ones, with their metrics: dense and packed closed loops, and
+    (with `window`) the `window_gqa` test architecture's, added after the
+    others by `add_window_arch`."""
     root = str(path)
     shutil.copytree(BENCH, os.path.join(root, "bench"),
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -60,7 +61,44 @@ def make_tiny_root(path) -> str:
                moves="decode_tok_s", **layer)]
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
+    if window:
+        add_window_arch(root)
     return root
+
+
+def add_cell(root, config: str, cell: str, traffic: str = "tiny_batch"
+             ) -> None:
+    """Entries for a configuration file and its cell added to a tiny
+    checkout's BENCHMARK.json, the cell reporting every metric that
+    `tiny.batch` reports."""
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        bench = json.load(f)
+    bench["configs"].append({"name": config, "source": "test",
+                             "file": f"bench/configs/{config}.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": cell, "config": config,
+                               "traffic": traffic, "chips": 1,
+                               "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "tiny.batch" in m.get("workloads", []):
+            m["workloads"].append(cell)
+    with open(path, "w") as f:
+        json.dump(bench, f)
+
+
+def add_window_arch(root) -> None:
+    """The `window_gqa` test architecture (sliding-window attention on a
+    ring arena) and its cell `tiny-win.batch`, added with new files and
+    entries only: its architecture module, its reference, its
+    configuration file, and BENCHMARK.json entries."""
+    bench = os.path.join(root, "bench")
+    for src, dst in (("arch_window_gqa.py", "arch/window_gqa.py"),
+                     ("reference_window_gqa.py", "reference/window_gqa.py"),
+                     ("tiny-win.json", "configs/tiny-win.json")):
+        assert not os.path.exists(os.path.join(bench, dst)), dst
+        shutil.copy(os.path.join(DATA, src), os.path.join(bench, dst))
+    add_cell(root, "tiny-win", "tiny-win.batch")
 
 
 @pytest.fixture

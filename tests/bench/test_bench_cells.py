@@ -8,6 +8,7 @@ import time
 import pytest
 
 from benchlib import harness
+from conftest import add_cell
 
 
 def _run(root, cell, seed=5, seconds=0.5):
@@ -19,6 +20,7 @@ def _run(root, cell, seed=5, seconds=0.5):
 @pytest.mark.parametrize("cell,metrics", [
     ("tiny.batch", {"decode_tok_s", "setup_s"}),
     ("tiny-b4.batch", {"decode_tok_s", "setup_s"}),
+    ("tiny-win.batch", {"decode_tok_s", "setup_s"}),
 ])
 def test_bench_tiny_cell_runs_correct(tiny_root, cell, metrics):
     res = _run(tiny_root, cell, seed=2**31 + 3)
@@ -57,21 +59,8 @@ def _add_config(root, name, base, **extra):
     with open(os.path.join(root, "bench", "configs", name + ".json"),
               "w") as f:
         json.dump(cfg, f)
-    path = os.path.join(root, "BENCHMARK.json")
-    with open(path) as f:
-        bench = json.load(f)
     cell = f"{name}.batch"
-    bench["configs"].append({"name": name, "source": "test",
-                             "file": f"bench/configs/{name}.json",
-                             "reduced": [], "why": "test"})
-    bench["workloads"].append({"name": cell, "config": name,
-                               "traffic": "tiny_batch", "chips": 1,
-                               "why": "test"})
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if "tiny.batch" in m.get("workloads", []):
-            m["workloads"].append(cell)
-    with open(path, "w") as f:
-        json.dump(bench, f)
+    add_cell(root, name, cell)
     return cell
 
 
@@ -83,10 +72,9 @@ def _add_config(root, name, base, **extra):
 ])
 def test_bench_engine_options_come_from_the_config_file(tiny_root, engine,
                                                         kv_row):
-    from benchlib import weights
     cell = _add_config(tiny_root, "tiny-opt", "tiny", engine=engine)
     spec = harness.load_spec(tiny_root, cell)
-    assert weights.kv_bytes_per_row(spec["config"]) == kv_row
+    assert spec["arch"].kv_row_bytes(spec["config"]) == kv_row
     seen = {}
     from repro.launch import engine as E
     init = E.Engine.__init__

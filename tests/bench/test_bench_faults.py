@@ -4,6 +4,8 @@ where the decode produces it, a decode that returns its cache unchanged,
 and the fp8 control put in the program's place."""
 import time
 
+import jax
+import jax.numpy as jnp
 import pytest
 
 from benchlib import harness
@@ -16,7 +18,9 @@ def _run(root, cell, seed=5, seconds=0.5):
 
 
 def _plant(monkeypatch, fault):
-    """Break the engine's decode dispatches where they are produced."""
+    """Break the engine's decode dispatches where they are produced. A
+    cache "returned unchanged" is a copy taken before the call, so the
+    decode may donate the one it is handed."""
     from repro.launch import engine as E
     init = E.Engine.__init__
 
@@ -25,22 +29,25 @@ def _plant(monkeypatch, fault):
         win, one = self._decode_window, self._decode
 
         def window(params, qparams, caches, tok, pos, k):
+            if fault == "state_unchanged":
+                before = jax.tree.map(jnp.copy, caches)
+                return win(params, qparams, caches, tok, pos, k)[0], before
             toks, new = win(params, qparams, caches, tok, pos, k)
-            if fault == "token":
-                return toks.at[-1].add(1), new
-            return toks, caches
+            return toks.at[-1].add(1), new
 
         def step(params, qparams, caches, tok, pos):
+            if fault == "state_unchanged":
+                before = jax.tree.map(jnp.copy, caches)
+                return one(params, qparams, caches, tok, pos)[0], before
             nxt, new = one(params, qparams, caches, tok, pos)
-            if fault == "token":
-                return nxt + 1, new
-            return nxt, caches
+            return nxt + 1, new
 
         self._decode_window, self._decode = window, step
     monkeypatch.setattr(E.Engine, "__init__", broken)
 
 
-@pytest.mark.parametrize("cell", ["tiny.batch", "tiny-b4.batch"])
+@pytest.mark.parametrize("cell", ["tiny.batch", "tiny-b4.batch",
+                                  "tiny-win.batch"])
 @pytest.mark.parametrize("fault", ["token", "state_unchanged"])
 def test_bench_broken_timed_path_is_not_correct(tiny_root, monkeypatch,
                                                 cell, fault):
@@ -51,7 +58,8 @@ def test_bench_broken_timed_path_is_not_correct(tiny_root, monkeypatch,
         res["checks"]["logit_gap"]["limit"]
 
 
-@pytest.mark.parametrize("cell", ["tiny.batch", "tiny-b4.batch"])
+@pytest.mark.parametrize("cell", ["tiny.batch", "tiny-b4.batch",
+                                  "tiny-win.batch"])
 def test_bench_fp8_control_is_not_correct(tiny_root, cell):
     """The reference computed with fp8 matmul operands, put in the
     program's place, comes out not correct on every seed tried, through

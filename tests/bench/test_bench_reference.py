@@ -12,6 +12,8 @@ import pytest
 from benchlib import harness, serve, weights
 from conftest import DATA, REPO
 
+ARCH = harness.load_module(REPO, "arch", "internlm2")
+
 
 def _ref():
     return harness.load_module(REPO, "reference", "internlm2")
@@ -35,8 +37,8 @@ def test_bench_reference_matches_prefill_and_decode(name):
     decode step's logits: program (float32 activations) vs reference."""
     ref = _ref()
     cfg = _cfg(name)
-    params = _f32(weights.make_params(cfg, 7))
-    eng = serve.build_engine(cfg, params, slots=1, rows=32)
+    params = _f32(ARCH.make_params(cfg, 7))
+    eng = serve.build_engine(ARCH, cfg, params, slots=1, rows=32)
     lm = eng.lm
     rng = np.random.default_rng(3)
     prompt = rng.integers(0, cfg["vocab_size"], 12).astype(np.int32)
@@ -79,7 +81,7 @@ def test_bench_served_gap_is_zero_on_reference_argmax():
     ranks lower has its gap below the best."""
     ref = _ref()
     cfg = _cfg("tiny", "bfloat16")
-    params = weights.make_params(cfg, 11)
+    params = ARCH.make_params(cfg, 11)
     prompt = np.arange(1, 9, dtype=np.int32)
     toks = []
     seq = list(prompt)

@@ -10,12 +10,14 @@ import pytest
 
 from conftest import DATA, REPO
 
-from benchlib import flops, harness, scopes
+from benchlib import harness, scopes
 
 MS = 1_000_000
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-WIDTHS = {"L": 2, "D": 8, "H": 2, "KVh": 1, "dh": 4, "F": 16, "Vp": 256,
-          "bits": None}
+ARCH = harness.load_module(REPO, "arch", "internlm2")
+CONFIG = {"num_hidden_layers": 2, "hidden_size": 8, "num_attention_heads": 2,
+          "num_key_value_heads": 1, "head_dim": 4, "intermediate_size": 16,
+          "vocab_size": 256}
 
 
 def _op(name, start_ms, dur_ms, scope, program="jit__decode_window"):
@@ -179,8 +181,8 @@ def _ctx(**kw):
     parts = {"proj": 0.4, "flash_decode": 0.1, "attn_core": 0.05,
              "kv_write": 0.05, "pointwise": 0.05, "decode_step": 0.25,
              "unscoped": 0.1, "window": 0.0}
-    ctx = {"parts": parts, "peaks": PEAKS, "widths": WIDTHS,
-           "kv_row_bytes": 2 * 2 * 1 * 2 * 4,
+    ctx = {"parts": parts, "peaks": PEAKS, "arch": ARCH, "config": CONFIG,
+           "widths": ARCH.served_widths(CONFIG),
            "trace_stats": {"decode_steps": 1000,
                            "kv_rows_attended": 5_000_000},
            "stats": {"decode_steps": 96, "decode_dispatches": 4,
@@ -197,10 +199,13 @@ def _read(name, ctx):
 def test_bench_scope_metrics_on_a_hand_made_context():
     ctx = _ctx()
     assert _read("kv_move_share.batch", ctx) == pytest.approx(40.0)
+    # a row: 2 layers x 1 KV head x (K, V) x 4 bf16 values
     assert _read("flash_decode_roofline.batch", ctx) == pytest.approx(
-        100 * ctx["kv_row_bytes"] * 5_000_000 / (0.1 * 819e9))
+        100 * (2 * 1 * 2 * 4 * 2) * 5_000_000 / (0.1 * 819e9))
+    # bf16 weights: 2 x (wq 8x8, wk/wv 8x4, wo 8x8, gate/up 8x16,
+    # down 16x8) and the head 8x256
     assert _read("proj_roofline.batch", ctx) == pytest.approx(
-        100 * flops.weight_bytes(WIDTHS) * 1000 / (0.4 * 819e9))
+        100 * 2 * (2 * 576 + 2048) * 1000 / (0.4 * 819e9))
     assert _read("decode_window_steps.batch", ctx) == pytest.approx(24.0)
     assert _read("host_dispatch_share.batch", ctx) == pytest.approx(10.0)
     # the engine's own time, not the window's: a traced run's window also
@@ -234,11 +239,12 @@ def test_bench_counter_metrics_of_a_tiny_cell(tiny_root):
     over (deltas over the window). The harness reads per-layer metrics
     only in a traced run, which needs a chip: the closed loop is driven
     here as it drives it."""
-    from benchlib import serve, traffic, weights
+    from benchlib import serve, traffic
     spec = harness.load_spec(tiny_root, "tiny.batch")
-    cfg, mix = spec["config"], spec["traffic"]
-    slots, rows = traffic.arena(mix, weights.kv_bytes_per_row(cfg))
-    eng = serve.build_engine(cfg, weights.make_params(cfg, 7), slots, rows)
+    cfg, mix, arch = spec["config"], spec["traffic"], spec["arch"]
+    slots, rows = traffic.arena(mix, lambda n: arch.slot_bytes(cfg, n))
+    eng = serve.build_engine(arch, cfg, arch.make_params(cfg, 7), slots,
+                             rows)
     serve.warm(eng, mix, cfg["vocab_size"])
     base = dict(eng.stats)
     out = serve.closed_loop(eng, mix, 7, 0.5, cfg["vocab_size"],
@@ -300,16 +306,14 @@ def test_bench_parts_of_a_chip_trace():
 def test_bench_scope_metrics_of_a_chip_trace():
     """The three trace metrics on the parts and counters of the traced
     stretch, as first read on the chip."""
-    from benchlib import weights
     from benchlib.peaks import peaks_for
     _, traced = _chip_trace()
     with open(os.path.join(REPO, "bench", "configs",
                            "internlm2-1.8b.json")) as f:
         cfg = json.load(f)
     ctx = {"parts": traced["parts"], "trace_stats": traced["trace_stats"],
-           "peaks": peaks_for("TPU v5 lite"),
-           "widths": weights.served_widths(cfg),
-           "kv_row_bytes": weights.kv_bytes_per_row(cfg)}
+           "peaks": peaks_for("TPU v5 lite"), "arch": ARCH, "config": cfg,
+           "widths": ARCH.served_widths(cfg)}
     kv = _read("kv_move_share.batch", ctx)
     fd = _read("flash_decode_roofline.batch", ctx)
     pj = _read("proj_roofline.batch", ctx)

@@ -63,7 +63,7 @@ def test_bench_arena_fits_the_longest_request():
     mix = _mix("batch_decode")
     mult = mix["arena"]["slot_multiple"]
     for kv_row in (98304, 49152):
-        slots, rows = traffic.arena(mix, kv_row)
+        slots, rows = traffic.arena(mix, lambda n: n * kv_row)
         assert rows >= traffic.longest_rows(mix)
         assert slots % mult == 0
         assert slots * rows * kv_row <= mix["arena"]["kv_bytes"]
