@@ -24,6 +24,7 @@ _ARCH_MODULES = {
     "internvl2-26b": "repro.configs.internvl2_26b",
     "llama4-maverick-400b-a17b": "repro.configs.llama4_maverick_400b_a17b",
     "grok-1-314b": "repro.configs.grok_1_314b",
+    "jamba2-3b": "repro.configs.jamba2_3b",
 }
 
 ASSIGNED_ARCHS = list(_ARCH_MODULES)

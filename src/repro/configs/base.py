@@ -51,10 +51,12 @@ class ModelConfig:
     mamba: Optional[MambaConfig] = None
     rwkv: Optional[RWKVConfig] = None
     attn_every: int = 1         # hybrid: 1 attention layer per `attn_every`
+    attn_offset: int = 0        # hybrid: the attention layer's index in it
     window: int = 0             # sliding-window attention (0 = full causal)
     num_codebooks: int = 0      # audio: EnCodec codebooks
     vision_patches: int = 0     # vlm: stub patch-embedding count
     tie_embeddings: bool = False
+    rope: bool = True           # False: attention with no positional encoding
     rope_theta: float = 10_000.0
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
@@ -92,16 +94,19 @@ class ModelConfig:
         mlp = 3 * D * F
         n = emb
         for i in range(L):
-            is_attn = (i % self.attn_every == 0) if self.family == "hybrid" \
-                else (self.family != "ssm_rwkv")
+            is_attn = (i % self.attn_every == self.attn_offset) \
+                if self.family == "hybrid" else (self.family != "ssm_rwkv")
             if is_attn and self.n_heads:
                 n += attn
             if self.family == "hybrid" and not is_attn and self.mamba:
-                di = self.mamba.expand * D
-                dtr = self.mamba.dt_rank or D // 16
-                n += D * 2 * di + di * (dtr + 2 * self.mamba.d_state) \
-                    + dtr * di + di * self.mamba.d_state + di * D \
-                    + self.mamba.d_conv * di
+                mc = self.mamba
+                di, N = mc.expand * D, mc.d_state
+                dtr = mc.dt_rank or D // 16
+                # in_proj, conv (+ bias), x_proj, dt_proj (+ bias), A_log,
+                # D, out_proj, and the dt / B / C norms
+                n += D * 2 * di + (mc.d_conv + 1) * di \
+                    + di * (dtr + 2 * N) + (dtr + 1) * di + di * N + di \
+                    + di * D + dtr + 2 * N
             if self.family == "ssm_rwkv":
                 n += 6 * D * D + 3 * D * F // 2  # time-mix + channel-mix
             if self.moe and (i % self.moe.every == self.moe.every - 1):

@@ -32,6 +32,7 @@ from repro.kernels import fake_quant as _fq
 from repro.kernels import gemm_core as _gc
 from repro.kernels import introspect
 from repro.kernels import ref as _ref
+from repro.kernels import selective_scan as _ss
 from repro.core.quant import fake_quant as _fake_quant_xla
 
 
@@ -296,6 +297,43 @@ def fq_masked_matmul_op(x, w, mask, d, q_m, t, *, interpret=None,
                              dispatch.resolve(backend, interpret))
 
 
+# ------------------------------------------------------------ selective scan
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _selective_scan(u, dt, Bm, Cm, A, h0, chunk, interpret):
+    return _ss.selective_scan_pallas(u, dt, Bm, Cm, A, h0,
+                                     interpret=interpret)
+
+
+def _ss_fwd(u, dt, Bm, Cm, A, h0, chunk, interpret):
+    return (_selective_scan(u, dt, Bm, Cm, A, h0, chunk, interpret),
+            (u, dt, Bm, Cm, A, h0))
+
+
+def _ss_bwd(chunk, interpret, res, g):
+    _, vjp = jax.vjp(functools.partial(_ref.selective_scan_ref, chunk=chunk),
+                     *res)
+    return vjp(g)
+
+
+_selective_scan.defvjp(_ss_fwd, _ss_bwd)
+
+
+def selective_scan_op(u, dt, Bm, Cm, A, h0, *, chunk=64, interpret=None,
+                      backend=None):
+    """Mamba-1's selective scan over S tokens from the state h0:
+    (y (B, S, Di) f32 without the D skip, h_last (B, Di, N) f32); shapes
+    as `ref.selective_scan_ref`. The Pallas kernel
+    (`kernels.selective_scan`, one launch over the whole sequence) takes
+    S > 1; a single decode token, and the xla-ref backend, run the
+    chunked composition. Differentiable: the kernel's backward is the
+    composition's."""
+    backend = dispatch.resolve(backend, interpret)
+    if backend == "xla-ref" or u.shape[1] == 1:
+        return _ref.selective_scan_ref(u, dt, Bm, Cm, A, h0, chunk=chunk)
+    return _selective_scan(u, dt, Bm, Cm, A, h0, chunk,
+                           backend == "pallas-interpret")
+
+
 # Re-export oracles for tests/benchmarks.
 arena_layer = _ref.arena_layer
 decode_attn_ref = _ref.decode_attn_ref
@@ -307,3 +345,4 @@ masked_matmul_ref = _ref.masked_matmul_ref
 quant_matmul_ref = _ref.quant_matmul_ref
 packed_quant_matmul_ref = _ref.packed_quant_matmul_ref
 fq_matmul_ref = _ref.fq_matmul_ref
+selective_scan_ref = _ref.selective_scan_ref

@@ -166,3 +166,52 @@ def packed_quant_matmul_ref(x, packed, bits, scale):
     from repro.core.quant import unpack_codes
     codes = unpack_codes(packed, bits, x.shape[-1], axis=0)
     return quant_matmul_ref(x, codes, scale)
+
+
+# ------------------------------------------------------------ selective scan
+def selective_scan_ref(u, dt, Bm, Cm, A, h0, *, chunk: int = 64):
+    """Chunked diagonal selective-SSM scan, memory-safe (the oracle of
+    `kernels.selective_scan`, and the path off the TPU and in decode).
+
+    h_t = exp(dt_t * A) * h_{t-1} + (dt_t * u_t) B_t ;  y_t = <h_t, C_t>.
+    The (B, S, Di, N) transition tensors are formed *per chunk inside the
+    checkpointed body* (never full-sequence — that costs S/chunk x more
+    HBM), and only y (B, S, Di) leaves the loop. A length that is not a
+    multiple of the chunk is padded at the end with dt = 0 and u = 0,
+    steps that leave the state as it stands (exp(0) = 1, no input).
+
+    u: (B,S,Di) activations; dt: (B,S,Di) f32; Bm/Cm: (B,S,N);
+    A: (Di,N) f32; h0: (B,Di,N) f32.
+    Returns (y (B,S,Di) f32 without the D skip, h_last).
+    """
+    B, S, Di = u.shape
+    C = min(chunk, S)
+    nch = -(-S // C)
+    pad = nch * C - S
+    if pad:
+        u, dt, Bm, Cm = (jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
+                         for t in (u, dt, Bm, Cm))
+
+    def combine(a, b):
+        a1, b1 = a
+        a2, b2 = b
+        return a1 * a2, b1 * a2 + b2
+
+    def chunk_step(h, inp):
+        ucc, dtc, bcc, ccc = inp           # (B, C, ...)
+        dA = jnp.exp(dtc[..., None] * A[None, None])          # (B,C,Di,N)
+        dBx = (dtc * ucc.astype(jnp.float32))[..., None] \
+            * bcc.astype(jnp.float32)[:, :, None, :]
+        accA, accB = jax.lax.associative_scan(combine, (dA, dBx), axis=1)
+        hs = accA * h[:, None] + accB
+        y = jnp.einsum("bcdn,bcn->bcd", hs, ccc.astype(jnp.float32))
+        return hs[:, -1], y
+
+    def chunked(t):
+        return jnp.moveaxis(t.reshape(B, nch, C, *t.shape[2:]), 1, 0)
+
+    h_last, ys = jax.lax.scan(
+        jax.checkpoint(chunk_step), h0,
+        (chunked(u), chunked(dt), chunked(Bm), chunked(Cm)))
+    y = jnp.moveaxis(ys, 0, 1).reshape(B, nch * C, Di)
+    return y[:, :S], h_last

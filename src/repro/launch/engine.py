@@ -1304,36 +1304,39 @@ class Engine:
         tok = jnp.zeros((self.max_slots, 1), jnp.int32)
         pos = jnp.zeros((self.max_slots,), jnp.int32)
         pt = jnp.asarray(self.page_table) if self.paged else None
+        # each call below keeps only its tokens: the arena it returns is
+        # dropped at once, so a warm-up never holds a third arena (the
+        # last call's) beside the engine's and the one being computed
         if self.draft is not None:
             for k in self._spec_ks():
                 if self.paged:
-                    tgt, _, _, _ = self._spec_paged(
+                    tgt = self._spec_paged(
                         self.params, self.qparams, self.draft.params,
                         self.draft.qparams, self.caches, self.dcaches,
-                        tok, pos, pt, k)
+                        tok, pos, pt, k)[0]
                 else:
-                    tgt, _, _, _ = self._spec(
+                    tgt = self._spec(
                         self.params, self.qparams, self.draft.params,
                         self.draft.qparams, self.caches, self.dcaches,
-                        tok, pos, k)
+                        tok, pos, k)[0]
                 jax.block_until_ready(tgt)
         elif self._chunk:
             if self.paged:
-                nxt, _ = self._decode_paged(self.params, self.qparams,
-                                            self.caches, tok, pos, pt)
+                nxt = self._decode_paged(self.params, self.qparams,
+                                         self.caches, tok, pos, pt)[0]
             else:
-                nxt, _ = self._decode(self.params, self.qparams,
-                                      self.caches, tok, pos)
+                nxt = self._decode(self.params, self.qparams,
+                                   self.caches, tok, pos)[0]
             jax.block_until_ready(nxt)
         else:
             for k in self.warmed_window_ks():
                 if self.paged:
-                    toks, _ = self._decode_window_paged(
+                    toks = self._decode_window_paged(
                         self.params, self.qparams, self.caches, tok, pos,
-                        pt, k)
+                        pt, k)[0]
                 else:
-                    toks, _ = self._decode_window(self.params, self.qparams,
-                                                  self.caches, tok, pos, k)
+                    toks = self._decode_window(self.params, self.qparams,
+                                               self.caches, tok, pos, k)[0]
                 jax.block_until_ready(toks)
         if self._chunk:
             from repro.launch.scheduler import chunk_buckets

@@ -432,11 +432,13 @@ def _heads(c, kvh: int):
 
 
 def attn_apply(lp: dict, qp: Optional[dict], cfg: ModelConfig, x, *,
-               rope: tuple, window: int = 0, prefix: str,
+               rope: Optional[tuple], window: int = 0, prefix: str,
                cache: Optional[tuple] = None, q_offset: int = 0,
                shapes: Optional[LayerShapes] = None, chunked: bool = False,
                pages: Optional[PagedView] = None, layer=None):
-    """lp: per-layer (unstacked) params view. cache: (k_cache, v_cache,
+    """lp: per-layer (unstacked) params view. `rope` is the (cos, sin)
+    tables of the queries' positions, or None for attention with no
+    positional encoding. cache: (k_cache, v_cache,
     write_pos) for decode; a contiguous cache stores one layer's K and V
     as (B, S, KVh * dh) rows. With `layer` (an int or traced int32
     scalar), the decode branch's k/v are instead the stacked
@@ -464,9 +466,10 @@ def attn_apply(lp: dict, qp: Optional[dict], cfg: ModelConfig, x, *,
     q = q.reshape(B, S, H, dh)
     k = k.reshape(B, S, KVh, dh)
     v = v.reshape(B, S, KVh, dh)
-    cos, sin = rope
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if rope is not None:
+        cos, sin = rope
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
     new_cache = None
     if cache is not None and chunked:
@@ -756,6 +759,9 @@ def moe_apply(lp: dict, qp: Optional[dict], cfg: ModelConfig, x, *,
 # ------------------------------------------------------------------ mamba
 def init_mamba(key, cfg: ModelConfig, prefix: str, n_layers: int, dtype
                ) -> tuple[dict, dict]:
+    """Jamba's Mamba-1 mixer (HF `JambaMambaMixer`): in_proj, a causal
+    depthwise conv with bias, x_proj to (dt, B, C) with an RMSNorm on
+    each, dt_proj with bias, and out_proj; no projection biases."""
     D = cfg.d_model
     mc = cfg.mamba
     Di = mc.expand * D
@@ -767,7 +773,11 @@ def init_mamba(key, cfg: ModelConfig, prefix: str, n_layers: int, dtype
     p = {
         f"{prefix}.in_proj": jax.random.normal(ks[0], L + (D, 2 * Di), dtype) * D ** -0.5,
         f"{prefix}.conv_w": jax.random.normal(ks[1], L + (mc.d_conv, Di), dtype) * 0.1,
+        f"{prefix}.conv_b": jnp.zeros(L + (Di,), dtype),
         f"{prefix}.x_proj": jax.random.normal(ks[2], L + (Di, dtr + 2 * N), dtype) * Di ** -0.5,
+        f"{prefix}.dt_norm": jnp.ones(L + (dtr,), jnp.float32),
+        f"{prefix}.b_norm": jnp.ones(L + (N,), jnp.float32),
+        f"{prefix}.c_norm": jnp.ones(L + (N,), jnp.float32),
         f"{prefix}.dt_proj": jax.random.normal(ks[3], L + (dtr, Di), dtype) * dtr ** -0.5,
         f"{prefix}.dt_bias": jnp.zeros(L + (Di,), dtype),
         f"{prefix}.A_log": jnp.broadcast_to(
@@ -779,7 +789,11 @@ def init_mamba(key, cfg: ModelConfig, prefix: str, n_layers: int, dtype
     axes = {
         f"{prefix}.in_proj": lax_ + ("embed", "mamba_inner2"),
         f"{prefix}.conv_w": lax_ + ("conv_k", "mamba_inner"),
+        f"{prefix}.conv_b": lax_ + ("mamba_inner",),
         f"{prefix}.x_proj": lax_ + ("mamba_inner", "mamba_lowrank"),
+        f"{prefix}.dt_norm": lax_ + ("mamba_lowrank_dt",),
+        f"{prefix}.b_norm": lax_ + ("mamba_state",),
+        f"{prefix}.c_norm": lax_ + ("mamba_state",),
         f"{prefix}.dt_proj": lax_ + ("mamba_lowrank_dt", "mamba_inner"),
         f"{prefix}.dt_bias": lax_ + ("mamba_inner",),
         f"{prefix}.A_log": lax_ + ("mamba_inner", "mamba_state"),
@@ -789,94 +803,69 @@ def init_mamba(key, cfg: ModelConfig, prefix: str, n_layers: int, dtype
     return p, axes
 
 
-def _mamba_chunk_scan(xc, dt, Bc, Cc, A, D_vec, h0, chunk=64):
-    """Chunked diagonal selective-SSM scan, memory-safe.
-
-    h_t = exp(dt_t * A) * h_{t-1} + (dt_t * x_t) B_t ;  y_t = <h_t, C_t>.
-    The (B, S, Di, N) transition tensors are formed *per chunk inside the
-    checkpointed body* (never full-sequence — that costs S/chunk x more
-    HBM), and only y (B, S, Di) leaves the loop.
-
-    xc: (B,S,Di) activations; dt: (B,S,Di) f32; Bc/Cc: (B,S,N);
-    A: (Di,N) f32; D_vec: (Di,) f32; h0: (B,Di,N) f32.
-    Returns (y (B,S,Di) f32, h_last).
-    """
-    B, S, Di = xc.shape
-    N = A.shape[1]
-    C = min(chunk, S)
-    assert S % C == 0, (S, C)
-    nch = S // C
-
-    def combine(a, b):
-        a1, b1 = a
-        a2, b2 = b
-        return a1 * a2, b1 * a2 + b2
-
-    def chunk_step(h, inp):
-        xcc, dtc, bcc, ccc = inp           # (B, C, ...)
-        dA = jnp.exp(dtc[..., None] * A[None, None])          # (B,C,Di,N)
-        dBx = (dtc * xcc.astype(jnp.float32))[..., None] \
-            * bcc.astype(jnp.float32)[:, :, None, :]
-        accA, accB = jax.lax.associative_scan(combine, (dA, dBx), axis=1)
-        hs = accA * h[:, None] + accB
-        y = jnp.einsum("bcdn,bcn->bcd", hs, ccc.astype(jnp.float32))
-        y = y + D_vec[None, None] * xcc.astype(jnp.float32)
-        return hs[:, -1], y
-
-    def chunked(t):
-        return jnp.moveaxis(t.reshape(B, nch, C, *t.shape[2:]), 1, 0)
-
-    h_last, ys = jax.lax.scan(
-        jax.checkpoint(chunk_step), h0,
-        (chunked(xc), chunked(dt), chunked(Bc), chunked(Cc)))
-    y = jnp.moveaxis(ys, 0, 1).reshape(B, S, Di)
-    return y, h_last
-
-
 def mamba_apply(lp: dict, qp: Optional[dict], cfg: ModelConfig, x, *,
                 prefix: str, state: Optional[tuple] = None,
                 shapes: Optional[LayerShapes] = None):
-    """Selective SSM block. state = (h (B,Di,N), conv (B,K-1,Di)) for decode.
-    Di comes from `shapes` (pruned subnets keep fewer inner channels).
-    Returns (out, new_state)."""
+    """Jamba's Mamba-1 mixer (HF `JambaMambaMixer`):
+
+        x_in, z = in_proj(x)
+        u       = silu(conv_b + causal depthwise conv(x_in))
+        dt, B, C = rmsnorm(.) of each part of x_proj(u)
+        dt      = softplus(dt_proj(dt) + dt_bias)
+        h_t     = exp(dt_t A) h_{t-1} + dt_t u_t B_t ;  y_t = <h_t, C_t>
+        out     = out_proj((y + D u) * silu(z))
+
+    state = (h (B,Di,N), conv (B,K-1,Di)) for decode: the last K-1 conv
+    inputs (before the bias). Di comes from `shapes` (pruned subnets keep
+    fewer inner channels); dt_rank and d_state are never pruned. The
+    discretisation, state update and readout run under the `ssm_update`
+    name scope. Returns (out, new_state)."""
     B, S, D = x.shape
     mc = cfg.mamba
     shapes = shapes or LayerShapes.from_config(cfg)
     Di = shapes.mamba_inner
     N = mc.d_state
     Kc = mc.d_conv
+    eps = cfg.norm_eps
 
     xi = dense_proj(x, lp, qp, f"{prefix}.in_proj_x")   # (B, S, Di)
     z = dense_proj(x, lp, qp, f"{prefix}.in_proj_z")
 
-    conv_w = lp[f"{prefix}.conv_w"].astype(jnp.float32)   # (K, Di)
-    if state is None:
-        pad = jnp.zeros((B, Kc - 1, Di), xi.dtype)
-        xpad = jnp.concatenate([pad, xi], axis=1)
-        new_conv = xpad[:, -(Kc - 1):] if Kc > 1 else pad
-    else:
-        h_prev, conv_prev = state
-        xpad = jnp.concatenate([conv_prev.astype(xi.dtype), xi], axis=1)
-        new_conv = xpad[:, -(Kc - 1):] if Kc > 1 else conv_prev
-    xc = sum(xpad[:, i:i + S].astype(jnp.float32) * conv_w[i]
-             for i in range(Kc))
-    xc = jax.nn.silu(xc).astype(x.dtype)
+    with jax.named_scope("pointwise"):
+        conv_w = lp[f"{prefix}.conv_w"].astype(jnp.float32)   # (K, Di)
+        if state is None:
+            pad = jnp.zeros((B, Kc - 1, Di), xi.dtype)
+            xpad = jnp.concatenate([pad, xi], axis=1)
+            new_conv = xpad[:, -(Kc - 1):] if Kc > 1 else pad
+        else:
+            h_prev, conv_prev = state
+            xpad = jnp.concatenate([conv_prev.astype(xi.dtype), xi], axis=1)
+            new_conv = xpad[:, -(Kc - 1):] if Kc > 1 else conv_prev
+        xc = sum(xpad[:, i:i + S].astype(jnp.float32) * conv_w[i]
+                 for i in range(Kc))
+        xc = xc + lp[f"{prefix}.conv_b"].astype(jnp.float32)
+        xc = jax.nn.silu(xc).astype(x.dtype)
 
     proj = dense_proj(xc, lp, qp, f"{prefix}.x_proj")
     dtr = (cfg.mamba.dt_rank or D // 16)
     dt_low, Bc, Cc = jnp.split(proj, [dtr, dtr + N], axis=-1)
-    dt = jax.nn.softplus(
-        dense_proj(dt_low, lp, qp, f"{prefix}.dt_proj").astype(jnp.float32)
-        + lp[f"{prefix}.dt_bias"].astype(jnp.float32))     # (B, S, Di)
-    A = -jnp.exp(lp[f"{prefix}.A_log"].astype(jnp.float32))  # (Di, N)
+    dt_low = rmsnorm(dt_low, lp[f"{prefix}.dt_norm"], eps)
+    Bc = rmsnorm(Bc, lp[f"{prefix}.b_norm"], eps)
+    Cc = rmsnorm(Cc, lp[f"{prefix}.c_norm"], eps)
+    dt_raw = dense_proj(dt_low, lp, qp, f"{prefix}.dt_proj")
 
-    h0 = jnp.zeros((B, Di, N), jnp.float32) if state is None \
-        else state[0]
-    y, h_last = _mamba_chunk_scan(
-        xc, dt, Bc, Cc, A, lp[f"{prefix}.D"].astype(jnp.float32), h0,
-        chunk=mc.chunk)
-    y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
-    y = qa(y, qp, f"{prefix}.mamba_out.aq")
+    with jax.named_scope("ssm_update"):
+        dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
+                             + lp[f"{prefix}.dt_bias"].astype(jnp.float32))
+        A = -jnp.exp(lp[f"{prefix}.A_log"].astype(jnp.float32))  # (Di, N)
+        h0 = jnp.zeros((B, Di, N), jnp.float32) if state is None \
+            else state[0]
+        y, h_last = Kops.selective_scan_op(xc, dt, Bc, Cc, A, h0,
+                                           chunk=mc.chunk)
+        y = y + lp[f"{prefix}.D"].astype(jnp.float32) * xc.astype(jnp.float32)
+    with jax.named_scope("pointwise"):
+        y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
+        y = qa(y, qp, f"{prefix}.mamba_out.aq")
     out = dense_proj(y, lp, qp, f"{prefix}.out_proj")
     return out, (h_last, new_conv)
 

@@ -60,7 +60,8 @@ def layer_plan(cfg: ModelConfig) -> tuple[list[SubLayer], int]:
     plan = []
     for j in range(period):
         if cfg.family == "hybrid":
-            mixer = "attn" if j % cfg.attn_every == 0 else "mamba"
+            mixer = "attn" if j % cfg.attn_every == cfg.attn_offset \
+                else "mamba"
         else:
             mixer = "attn"
         if cfg.moe is not None and j % cfg.moe.every == cfg.moe.every - 1:
@@ -302,9 +303,13 @@ class LM:
         return params["embed"][tokens]
 
     def _head(self, params, h):
+        """Logits of `h`, under the `proj` scope with the projections. A
+        tied head contracts `h` with the (V, D) embedding as it is stored,
+        so no transposed copy of it is made."""
         cfg = self.cfg
         if cfg.tie_embeddings and not cfg.num_codebooks:
-            return h @ params["embed"].T
+            with jax.named_scope("proj"):
+                return jnp.einsum("...d,vd->...v", h, params["embed"])
         return Lyr.dense_proj(h, params, None, "head")
 
     def _block_params(self, params: dict) -> dict:
@@ -357,7 +362,8 @@ class LM:
             x = jnp.concatenate([vision_embeds.astype(x.dtype), x], axis=1)
         x = self._constrain(x)
         S = x.shape[1]
-        rope = Lyr.rope_tables(S, cfg.d_head, cfg.rope_theta)
+        rope = (Lyr.rope_tables(S, cfg.d_head, cfg.rope_theta)
+                if cfg.rope else None)
         body = self._body(qp_body, rope)
         if cfg.remat:
             # full remat of the block body: only the per-layer residual
@@ -527,7 +533,10 @@ class LM:
         one stacked buffer at (layer, slot, row) and flash decode reads
         that layer of it in place, so nothing slices, restacks or copies
         the arena. Paged pools and the recurrent-state leaves (O(1) per
-        slot) stay per-layer scan inputs and outputs.
+        slot) stay per-layer scan inputs and outputs; where the layer
+        blocks are unrolled (n_blocks <= 2) their new values are restacked
+        under the `state_write` name scope. (In the scan XLA writes the
+        outputs itself, under the scan's own scope.)
         Returns (logits, new_caches)."""
         cfg = self.cfg
         params, qp_body = self._prequantize(params, qparams)
@@ -535,13 +544,15 @@ class LM:
         B = x.shape[0]
         # rope at each sequence's absolute position
         pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
-        with jax.named_scope("pointwise"):
-            posf = pos.astype(jnp.float32)
-            freqs = cfg.rope_theta ** (-jnp.arange(
-                0, cfg.d_head, 2, dtype=jnp.float32) / cfg.d_head)
-            ang = posf[:, None] * freqs[None, :]
-            rope = (jnp.cos(ang)[:, None],
-                    jnp.sin(ang)[:, None])                  # (B, 1, dh/2)
+        rope = None
+        if cfg.rope:
+            with jax.named_scope("pointwise"):
+                posf = pos.astype(jnp.float32)
+                freqs = cfg.rope_theta ** (-jnp.arange(
+                    0, cfg.d_head, 2, dtype=jnp.float32) / cfg.d_head)
+                ang = posf[:, None] * freqs[None, :]
+                rope = (jnp.cos(ang)[:, None],
+                        jnp.sin(ang)[:, None])              # (B, 1, dh/2)
 
         inplace = set() if pages is not None else set(self.kv_heads())
         arena = {k: v for k, v in caches.items() if k in inplace}
@@ -613,8 +624,9 @@ class LM:
                                  "c": {k: v[i] for k, v in per_layer.items()},
                                  "l": i})
                 new_list.append(nc)
-            new_caches = {k: jnp.stack([nc[k] for nc in new_list])
-                          for k in new_list[0]}
+            with jax.named_scope("state_write"):
+                new_caches = {k: jnp.stack([nc[k] for nc in new_list])
+                              for k in new_list[0]}
         else:
             (x, arena), new_caches = jax.lax.scan(
                 body, (x, arena),
@@ -662,11 +674,13 @@ class LM:
         B, T = x.shape[0], x.shape[1]
         # rope at each slot's absolute positions pos[b] + [0, T)
         pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
-        posf = (pos[:, None] + jnp.arange(T)[None, :]).astype(jnp.float32)
-        freqs = cfg.rope_theta ** (-jnp.arange(0, cfg.d_head, 2,
-                                               dtype=jnp.float32) / cfg.d_head)
-        ang = posf[..., None] * freqs[None, None, :]
-        rope = (jnp.cos(ang), jnp.sin(ang))                 # (B, T, dh/2)
+        rope = None
+        if cfg.rope:
+            posf = (pos[:, None] + jnp.arange(T)[None, :]).astype(jnp.float32)
+            freqs = cfg.rope_theta ** (-jnp.arange(
+                0, cfg.d_head, 2, dtype=jnp.float32) / cfg.d_head)
+            ang = posf[..., None] * freqs[None, None, :]
+            rope = (jnp.cos(ang), jnp.sin(ang))             # (B, T, dh/2)
 
         def body(x, inp):
             x = self._constrain(x)
@@ -737,7 +751,8 @@ class LM:
         if cfg.vision_patches and vision_embeds is not None:
             x = jnp.concatenate([vision_embeds.astype(x.dtype), x], axis=1)
         S = x.shape[1]
-        rope = Lyr.rope_tables(S, cfg.d_head, cfg.rope_theta)
+        rope = (Lyr.rope_tables(S, cfg.d_head, cfg.rope_theta)
+                if cfg.rope else None)
 
         def body(x, inp):
             x = self._constrain(x)
@@ -791,8 +806,9 @@ class LM:
                 x, nc = body(x, {"p": {k: v[i] for k, v in bp.items()},
                                  "c": {k: v[i] for k, v in caches.items()}})
                 new_list.append(nc)
-            new_caches = {k: jnp.stack([nc[k] for nc in new_list])
-                          for k in new_list[0]}
+            with jax.named_scope("state_write"):
+                new_caches = {k: jnp.stack([nc[k] for nc in new_list])
+                              for k in new_list[0]}
         else:
             x, new_caches = jax.lax.scan(body, x, {"p": bp, "c": caches})
         if last_logit_only:
@@ -872,10 +888,13 @@ class LM:
                                        target_param=f"{pre}.attn.{w}")
             return vid
         if sub.mixer == "mamba":
+            # inner channels: every per-channel tensor slices with them;
+            # the dt / B / C norms (over dt_rank, d_state) are never cut
             Di = cfg.mamba.expand * cfg.d_model
             members = [(f"{pre}.mamba.in_proj_x", 2, 1),
                        (f"{pre}.mamba.in_proj_z", 2, 1),
                        (f"{pre}.mamba.conv_w", 2, 1),
+                       (f"{pre}.mamba.conv_b", 1, 1),
                        (f"{pre}.mamba.x_proj", 1, 1),
                        (f"{pre}.mamba.dt_proj", 2, 1),
                        (f"{pre}.mamba.dt_bias", 1, 1),

@@ -138,3 +138,22 @@ def test_blockwise_attention_matches_dense():
     yb = attention_blockwise(q, k, v, block=64)
     np.testing.assert_allclose(np.asarray(yb), np.asarray(yd), rtol=2e-4,
                                atol=2e-4)
+
+
+@pytest.mark.parametrize("arch,period,attn_at", [
+    ("jamba-1.5-large-398b", 8, 4),      # attn_layer_period 8, offset 4
+    ("jamba2-3b", 14, 7),                # attn_layer_period 14, offset 7
+])
+def test_hybrid_attention_at_the_published_offset(arch, period, attn_at):
+    """A Jamba period attends at its published offset, with no positional
+    encoding, and the parameter count follows the same layout: within
+    0.1% of the program's own parameters (it leaves out the norms)."""
+    from repro.models.transformer import layer_plan
+    cfg = get_arch(arch)
+    plan, n_blocks = layer_plan(cfg)
+    assert len(plan) == period and n_blocks == cfg.n_layers // period
+    assert [s.j for s in plan if s.mixer == "attn"] == [attn_at]
+    assert not cfg.rope
+    shapes = jax.eval_shape(lambda: LM(cfg).init(jax.random.PRNGKey(0))[0])
+    n = sum(int(np.prod(v.shape)) for v in shapes.values())
+    assert abs(cfg.param_count() - n) < 1e-3 * n

@@ -29,13 +29,16 @@ from repro.models import layers as Lyr
 ATOL = 1e-4
 
 # (B, S, KVh, g, dh, chunk): ragged lengths, GQA ratios 1/2/3/8,
-# sub-lane and multi-chunk cache lengths, non-128 head dims
+# sub-lane and multi-chunk cache lengths, non-128 head dims, and
+# Jamba2-3B's multi-query attention (one KV head for 20 query heads,
+# which the kernel pads to 24 rows)
 CASES = [
     (1, 7, 1, 1, 4, None),
     (2, 33, 2, 3, 8, 16),
     (3, 64, 4, 2, 16, 16),
     (2, 130, 1, 8, 5, 32),
     (4, 24, 2, 1, 128, None),
+    (3, 50, 1, 20, 128, 16),
 ]
 
 

@@ -359,3 +359,26 @@ def test_arena_is_held_once():
     shapes = {tuple(eng.caches[k].shape) for k in kv}
     live = [a for a in jax.live_arrays() if tuple(a.shape) in shapes]
     assert {id(a) for a in live} == {id(eng.caches[k]) for k in kv}
+
+
+def test_warmup_holds_one_arena_between_lengths():
+    """Each window length warm-up compiles and runs drops the arena it
+    returns before the next: when a length runs, the engine's arena is
+    the only one live (the one it computes is the second). Holding the
+    last call's arena made three, which a 3.6 GB Jamba2-3B arena beside
+    6 GB of weights does not leave room for on one 16 GB chip."""
+    import gc
+    lm, params, qparams = _serving_lm()
+    eng = Engine(lm, params, qparams, max_slots=5, max_seq=23)
+    kv = sorted(lm.kv_heads())
+    shapes = {tuple(eng.caches[k].shape) for k in kv}
+    gc.collect()        # arrays of earlier tests in this process
+    window, live = eng._decode_window, []
+
+    def counted(*args):
+        live.append(sum(tuple(a.shape) in shapes for a in jax.live_arrays()))
+        return window(*args)
+
+    eng._decode_window = counted
+    eng.warmup()
+    assert live == [len(kv)] * len(eng.warmed_window_ks())

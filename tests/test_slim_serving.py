@@ -154,7 +154,8 @@ def test_prune_then_compress_stacks():
         assert codes.shape == sliced[name].shape, name
 
 
-@pytest.mark.parametrize("arch", ["rwkv6-3b", "jamba-1.5-large-398b"])
+@pytest.mark.parametrize("arch", ["rwkv6-3b", "jamba-1.5-large-398b",
+                                  "jamba2-3b"])
 def test_pruned_decode_stateful_families(arch):
     """SSM/RWKV/hybrid(+MoE) subnets decode at sliced state widths: the
     recurrent caches (mamba h/conv, rwkv wkv) shrink with the plan and
@@ -175,6 +176,36 @@ def test_pruned_decode_stateful_families(arch):
         lg, caches = step(p_slim, q_slim, caches, tok, jnp.int32(i))
         tok = jnp.argmax(lg[:, -1], -1).astype(jnp.int32)[:, None]
         assert np.all(np.isfinite(np.asarray(lg)))
+
+
+def test_jamba_pruned_packed_artifact_serves():
+    """A GETA deployment artifact of Jamba (Mamba inner channels and MLP
+    units pruned, projections 4-bit packed) serves through the engine:
+    the conv bias is sliced with its channels, the dt / B / C norms keep
+    their widths, and every request comes back at its length."""
+    lm, params = _f32_lm("jamba2-3b")
+    key = jax.random.PRNGKey(3)
+    params = {k: (jax.random.normal(key, v.shape) * 0.1
+                  if k.endswith("conv_b") else v) for k, v in params.items()}
+    slim = LM(lm.cfg)
+    p, q, meta = prepare_serving(slim, dict(params), bits_init=4.0,
+                                 packed=True, prune_sparsity=0.4)
+    assert meta["sparsity"] > 0.2 and meta["packed_sites"]
+    pre = "blocks.0.mamba."
+    Di = slim.shapes[0].mamba_inner
+    assert Di < lm.shapes[0].mamba_inner
+    assert p[pre + "conv_b"].shape == (slim.n_blocks, Di)
+    assert p[pre + "conv_w"].shape[-1] == Di
+    assert p[pre + "dt_norm"].shape == params[pre + "dt_norm"].shape
+    assert p[pre + "b_norm"].shape == params[pre + "b_norm"].shape
+    assert any(k.startswith(pre + "in_proj_x.packed") for k in p)
+    from repro.launch.engine import Engine
+    eng = Engine(slim, p, q, max_slots=2, max_seq=24)
+    for prompt in synthetic_prompts(lm.cfg, [7, 11, 5]):
+        eng.submit(prompt, 6)
+    out = eng.run()
+    assert sorted(out) == [0, 1, 2]
+    assert all(len(t) == 6 for t in out.values())
 
 
 # ------------------------------------------------------- engine end to end

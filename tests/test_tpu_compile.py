@@ -130,6 +130,32 @@ def test_contiguous_flash_decode_compiles(one_chip):
              q, kv, kv, pos)
 
 
+def test_mqa_flash_decode_reads_the_stacked_arena(one_chip):
+    """Jamba2-3B's decode attention: one KV head for 20 query heads (24
+    rows once padded), reading layer l of the (2, 192, 9216, 128) arena
+    its two attention layers stack, at its served widths."""
+    q = _sds(one_chip, (192, 1, 20, 128), jnp.bfloat16)
+    kv = _sds(one_chip, (2, 192, 9216, 128), jnp.bfloat16)
+    pos = _sds(one_chip, (192,), jnp.int32)
+    layer = _sds(one_chip, (), jnp.int32)
+    _compile(lambda q, k, v, p, l: da.decode_attn_pallas(q, k, v, p,
+                                                         layer=l),
+             q, kv, kv, pos, layer)
+
+
+@pytest.mark.parametrize("S", [366, 1020])
+def test_selective_scan_compiles(one_chip, S):
+    """Jamba2-3B's Mamba prefill scan (d_inner 5120, d_state 16) as one
+    Pallas launch, at prompt lengths that fill no whole token block."""
+    from repro.kernels import selective_scan as ss
+    seq = _sds(one_chip, (1, S, 5120), jnp.bfloat16)
+    dt = _sds(one_chip, (1, S, 5120), jnp.float32)
+    bc = _sds(one_chip, (1, S, 16), jnp.bfloat16)
+    _compile(ss.selective_scan_pallas, seq, dt, bc, bc,
+             _sds(one_chip, (5120, 16), jnp.float32),
+             _sds(one_chip, (1, 5120, 16), jnp.float32))
+
+
 @pytest.mark.parametrize("kv_bits", [None, 8, 4], ids=["bf16", "kv8", "kv4"])
 def test_paged_flash_decode_compiles(one_chip, kv_bits):
     lp = S // PAGE
