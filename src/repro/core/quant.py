@@ -303,6 +303,18 @@ def unpack_codes(packed: jax.Array, bits: int, size: int, *,
     return jnp.moveaxis(out, 0, axis)
 
 
+def code_plane(words: jax.Array, bits: int, field: int) -> jax.Array:
+    """Field `field` of every `pack_codes` word, sign-extended: the codes
+    at K rows `r * (32 // bits) + field` of a K-packed stream, in the
+    words' own shape. Two shifts: the field's top bit to bit 31, then an
+    arithmetic shift back down (no mask, no interleave), so a kernel
+    decodes a word tile one whole plane at a time.
+    `unpack_codes(w)[r * cpw + i] == code_plane(w, bits, i)[r]`."""
+    bits, field = int(bits), int(field)
+    assert 0 <= field < _codes_per_word(bits), (bits, field)
+    return (words << (32 - bits * (field + 1))) >> (32 - bits)
+
+
 # Storage widths the paged KV arena can hold codes at (DESIGN.md §4.11).
 # Weight containers pack along the GEMM K axis into int32 words
 # (`pack_codes`); KV pages instead pack along d_head into int8 bytes —

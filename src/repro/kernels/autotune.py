@@ -129,7 +129,8 @@ def candidate_blocks(M: int, N: int, K: int
 
 
 def vmem_filter(candidates, M: int, N: int, K: int, rhs_ops=(), *,
-                w_itemsize: int = 4, budget: Optional[int] = None):
+                w_itemsize: int = 4, x_itemsize: int = 4,
+                budget: Optional[int] = None):
     """Split candidate (bm, bn, bk) tiles by the static VMEM model.
 
     Each candidate resolves through `gemm_core.plan_blocks` — the exact
@@ -145,11 +146,12 @@ def vmem_filter(candidates, M: int, N: int, K: int, rhs_ops=(), *,
     budget = budget or introspect.VMEM_BUDGET_BYTES
     fits, rejected = [], {}
     for blocks in candidates:
-        plan = gemm_core.plan_blocks(M, N, K, k_pack, tuple(blocks))
+        plan = gemm_core.plan_blocks(M, N, K, k_pack, tuple(blocks),
+                                     x_itemsize=x_itemsize)
         nbytes = introspect.gemm_vmem_bytes(introspect.GemmLaunch(
             M=M, N=N, K=K, k_pack=k_pack, n_col=n_col, n_scalar=n_scalar,
             ops=ops_key(rhs_ops), backend="static", blocks=plan,
-            w_itemsize=w_itemsize))
+            w_itemsize=w_itemsize, x_itemsize=x_itemsize))
         if nbytes > budget:
             rejected[tuple(blocks)] = nbytes
         else:
@@ -181,8 +183,10 @@ def autotune_gemm(x, w, rhs_ops=(), *, backend: Optional[str] = None,
     N = w.shape[1]
     K_logical = K if k_pack == 1 else K    # x carries logical K already
     cands = list(candidates or candidate_blocks(M, N, K_logical))
+    x_itemsize = gemm_core.x_itemsize(x, k_pack)
     cands, rejected = vmem_filter(cands, M, N, K_logical, rhs_ops,
                                   w_itemsize=w.dtype.itemsize,
+                                  x_itemsize=x_itemsize,
                                   budget=vmem_budget)
     if not cands:
         raise ValueError(

@@ -18,10 +18,11 @@ Recording is off by default and costs one `is None` check per traced op.
 
 The VMEM byte models below are deliberately simple upper-estimate
 arithmetic over the block specs (2x double-buffering on grid-streamed
-blocks, accumulator + scratch resident, decoded packed tile materialized
-in-VMEM) against the ~16 MiB/core budget from the Pallas TPU guide. They
-are used by the analysis `vmem` pass *and* by `autotune.autotune_gemm` to
-refuse timing candidate tiles that could not fit on real hardware.
+blocks, accumulator + scratch resident, one decoded field plane of a
+packed word tile at a time) against the ~16 MiB/core budget from the
+Pallas TPU guide. They are used by the analysis `vmem` pass *and* by
+`autotune.autotune_gemm` to refuse timing candidate tiles that could not
+fit on real hardware.
 """
 from __future__ import annotations
 
@@ -52,6 +53,7 @@ class GemmLaunch:
     backend: str
     blocks: tuple[int, int, int, int]  # (bm, bn, bk, bkw) final tile
     w_itemsize: int = 4
+    x_itemsize: int = 4               # x tile (and packed plane) element
 
     kind = "gemm"
 
@@ -117,17 +119,22 @@ def gemm_vmem_bytes(launch: GemmLaunch) -> int:
     """Estimated VMEM bytes for one gemm tile-program.
 
     2x double-buffering on the streamed input blocks (x, w, COL/SCALAR
-    operands), the f32 output accumulator (kept 2x: the (i, j) revisit
-    pattern still overlaps the next block's prologue), plus — when the
-    RHS is a packed word stream — the decoded f32 (bk, bn) tile the
-    unpack epilogue materializes before the dot."""
+    operands) and 2x an f32 (bm, bn) output tile (the unpacked body's
+    accumulator, whose (i, j) revisit overlaps the next block's
+    prologue; the packed body's output block and its f32 K-step
+    scratch). The packed body (`k_pack > 1`) streams x as cpw field
+    slices, bm x bk elements of x's width, and the (bkw, bn) int32 word
+    tile, and decodes one field plane at a time: an int32 plane and its
+    copy in the MXU operand dtype (x's: bf16 planes for bf16 x). No
+    (bk, bn) decoded tile is ever formed."""
     bm, bn, bk, bkw = launch.blocks
-    x_tile = bm * bk * _F32
+    x_tile = bm * bk * (launch.x_itemsize if launch.k_pack > 1 else _F32)
     w_tile = bkw * bn * launch.w_itemsize
     operands = launch.n_col * bn * _F32 + launch.n_scalar * _F32
     out_tile = bm * bn * _F32
-    decoded = bk * bn * _F32 if launch.k_pack > 1 else 0
-    return 2 * (x_tile + w_tile + operands) + 2 * out_tile + decoded
+    plane = (bkw * bn * (_F32 + launch.x_itemsize)
+             if launch.k_pack > 1 else 0)
+    return 2 * (x_tile + w_tile + operands) + 2 * out_tile + plane
 
 
 def attn_vmem_bytes(launch: AttnLaunch) -> int:

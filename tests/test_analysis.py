@@ -224,15 +224,21 @@ def test_vmem_model_flags_oversized_tile():
 
 
 def test_vmem_packed_tile_counts_decoded_blowup():
-    # bits=3 packs 8 codes/word; plan_blocks inflates bk to lcm(24, bk)
-    plan = gemm_core.plan_blocks(256, 256, 768, k_pack=8,
-                                 blocks=(64, 128, 128))
-    bm, bn, bk, bkw = plan
-    assert bk % 8 == 0 and bkw == bk // 8
-    packed = _gemm_launch(plan, k_pack=8)
-    unpacked = _gemm_launch(plan, k_pack=1)
-    assert introspect.gemm_vmem_bytes(packed) > \
-        introspect.gemm_vmem_bytes(unpacked)
+    # bits=4 packs 8 codes/word. The packed body decodes one (bkw, bn)
+    # field plane at a time: the model counts that plane (an int32 plane
+    # and its copy in x's dtype) beside the streamed blocks, and no
+    # decoded (bk, bn) f32 tile.
+    for x_itemsize in (2, 4):
+        plan = gemm_core.plan_blocks(256, 256, 768, k_pack=8,
+                                     blocks=(64, 128, 128),
+                                     x_itemsize=x_itemsize)
+        bm, bn, bk, bkw = plan
+        assert bk % 8 == 0 and bkw == bk // 8
+        packed = _gemm_launch(plan, k_pack=8, x_itemsize=x_itemsize)
+        streamed = 2 * (bm * bk * x_itemsize + bkw * bn * 4) + 2 * bm * bn * 4
+        plane = bkw * bn * (4 + x_itemsize)
+        assert introspect.gemm_vmem_bytes(packed) == streamed + plane
+        assert plane < bk * bn * 4
 
 
 def test_autotune_rejects_oversized_candidates():

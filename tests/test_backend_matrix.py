@@ -3,7 +3,8 @@ across the kernel backends on odd/ragged (non-MXU-aligned) shapes.
 
 One parameterized test covers the full product
 
-    {col_mask, dequant(int8), dequant(int16), fake_quant, fused joint}
+    {col_mask, dequant(int8), dequant(int16), fake_quant, fused joint,
+     unpack_dequant b2/b3/b4/b8}
       x {pallas-interpret vs xla-ref}
       x ragged (M, K, N) sweeps,
 
@@ -22,10 +23,12 @@ from repro.kernels import gemm_core
 
 # deliberately ragged: primes, 1-row/1-col edges, > one block in each dim,
 # decode-shaped small-M rows (M = active slots; exercises the aligned
-# small-M bm clamp in gemm_core._clamp_blocks)
+# small-M bm clamp in gemm_core._clamp_blocks, and the packed plan's
+# decode branch up to the artifact's 28 slots) and a prefill-shaped one
 RAGGED_SHAPES = [(1, 1, 1), (1, 7, 5), (3, 193, 17), (29, 31, 37),
                  (57, 384, 129), (130, 257, 131),
-                 (4, 256, 128), (8, 96, 160)]
+                 (4, 256, 128), (8, 96, 160), (28, 300, 260),
+                 (300, 1030, 131)]
 
 ATOL = 1e-4
 
@@ -47,7 +50,7 @@ def _w_and_ops(key, kind, k, n):
         return codes, (gemm_core.dequant(scale),)
     if kind.startswith("unpack_dequant"):
         # sub-byte packed codes: int32 word stream along K (bits=3 covers
-        # the 10-codes-per-word stream whose block is the non-default 120)
+        # the 10-codes-per-word stream, whose K blocks align to 640 codes)
         from repro.core.quant import pack_codes
         bits = int(kind[-1])
         hi = 2 ** (bits - 1) - 1
@@ -64,7 +67,8 @@ def _w_and_ops(key, kind, k, n):
 
 
 EPILOGUES = ["col_mask", "dequant_int8", "dequant_int16", "fake_quant",
-             "fused_joint", "unpack_dequant_b4", "unpack_dequant_b3"]
+             "fused_joint", "unpack_dequant_b4", "unpack_dequant_b3",
+             "unpack_dequant_b2", "unpack_dequant_b8"]
 
 
 @pytest.mark.parametrize("mkn", RAGGED_SHAPES,

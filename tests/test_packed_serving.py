@@ -10,6 +10,7 @@ fixes: `quantize_int` boundary clamping, the `compression_report`
 sparsity-0.0 line, and `mean_storage_bits`.
 """
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,7 @@ from repro.core import quant as Q
 from repro.core.subnet import (compress_lm, compression_report,
                                prepare_serving, residual_qparams,
                                servable_params, tree_bytes)
-from repro.kernels import gemm_core, ops
+from repro.kernels import dispatch, gemm_core, ops
 from repro.kernels.ref import packed_quant_matmul_ref, quant_matmul_ref
 from repro.models.transformer import LM
 
@@ -72,50 +73,110 @@ def test_packed_storage_bits_rounding():
 
 
 # --------------------------------------------------------- GEMM epilogue
-@pytest.mark.parametrize("mkn", [(1, 1, 1), (3, 193, 17), (29, 31, 37),
-                                 (130, 257, 131)],
-                         ids=lambda s: f"{s[0]}x{s[1]}x{s[2]}")
-@pytest.mark.parametrize("bits", [2, 3, 4, 8])
-def test_unpack_dequant_backend_parity(bits, mkn):
-    """Packed GEMM == unpacked dequant oracle on both backends, over
-    ragged shapes (incl. bits=3, whose 10-codes-per-word stream forces
-    the non-default bk=120 block)."""
-    m, k, n = mkn
+# ragged K (a partial last word) and N, at decode (M = 1, 28: the
+# artifact's 28 slots) and prefill (M = 130, 300) row counts; each shape
+# with f32 activations, and the new ones with bf16 too
+PACKED_SHAPES = [(1, 1, 1), (3, 193, 17), (29, 31, 37), (130, 257, 131),
+                 (28, 300, 260), (300, 1030, 131)]
+PACKED_CASES = ([(mkn, jnp.float32) for mkn in PACKED_SHAPES]
+                + [(mkn, jnp.bfloat16) for mkn in ((1, 1, 1), (3, 193, 17),
+                                                   (28, 300, 260),
+                                                   (300, 1030, 131))])
+
+
+def _case_id(case):
+    (m, k, n), x_dtype = case
+    return f"{m}x{k}x{n}" + ("-bf16" if x_dtype == jnp.bfloat16 else "")
+
+
+def _packed_case(bits, m, k, n, x_dtype, seed):
     hi = 2 ** (bits - 1) - 1
-    rng = np.random.RandomState(bits * 1009 + k * 11 + n)
+    rng = np.random.RandomState(seed)
     codes = rng.randint(-hi, hi + 1, size=(k, n)).astype(np.int8)
     scale = ((rng.rand(n) + 0.5) * (2.0 / max(hi, 1))).astype(np.float32)
-    x = jnp.asarray(rng.randn(m, k).astype(np.float32))
+    x = jnp.asarray(rng.randn(m, k).astype(np.float32)).astype(x_dtype)
+    return x, codes, scale, rng
+
+
+def test_code_plane_is_unpack_codes_field():
+    """The kernel's two-shift field plane reads the `pack_codes` format
+    exactly as `unpack_codes` does, sign and all, for every width."""
+    for bits in range(2, 9):
+        hi, cpw = 2 ** (bits - 1) - 1, 32 // bits
+        rng = np.random.RandomState(bits)
+        c = rng.randint(-hi, hi + 1, size=(7 * cpw, 5)).astype(np.int32)
+        c[0], c[1] = hi, -hi
+        w = Q.pack_codes(jnp.asarray(c), bits, axis=0)
+        for i in range(cpw):
+            np.testing.assert_array_equal(
+                np.asarray(Q.code_plane(w, bits, i)), c[i::cpw])
+
+
+def _packed_gemm(x, packed, bits, scale, backend, rhs_ops=None, **kw):
+    """The packed GEMM, its result in f32 (a bf16 output would round
+    either side's last bit)."""
+    if x.dtype == jnp.float32 and rhs_ops is None and not kw:
+        return ops.packed_quant_matmul_op(x, packed, bits, scale,
+                                          backend=backend)
+    rhs_ops = rhs_ops or (gemm_core.unpack_dequant(bits, scale),)
+    return gemm_core.gemm(x, packed, rhs_ops, backend=backend,
+                          out_dtype=jnp.float32, **kw)
+
+
+@pytest.mark.parametrize("case", PACKED_CASES, ids=_case_id)
+@pytest.mark.parametrize("bits", [2, 3, 4, 8])
+def test_unpack_dequant_backend_parity(bits, case):
+    """Packed GEMM == unpacked dequant oracle on both backends, over
+    ragged shapes (incl. bits=3, whose 10-codes-per-word stream aligns K
+    blocks to 640 codes), with f32 and bf16 activations (the packed
+    body's MXU operands follow x: bf16 planes for bf16 x)."""
+    (m, k, n), x_dtype = case
+    x, codes, scale, _ = _packed_case(bits, m, k, n, x_dtype,
+                                      bits * 1009 + k * 11 + n)
     packed = Q.pack_codes(jnp.asarray(codes), bits, axis=0)
-    want = quant_matmul_ref(x, jnp.asarray(codes), jnp.asarray(scale))
-    ref = packed_quant_matmul_ref(x, packed, bits, jnp.asarray(scale))
+    x32 = x.astype(jnp.float32)
+    want = quant_matmul_ref(x32, jnp.asarray(codes), jnp.asarray(scale))
+    ref = packed_quant_matmul_ref(x32, packed, bits, jnp.asarray(scale))
     np.testing.assert_allclose(np.asarray(ref), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
     for backend in BACKENDS:
-        got = ops.packed_quant_matmul_op(x, packed, bits,
-                                         jnp.asarray(scale), backend=backend)
+        got = _packed_gemm(x, packed, bits, jnp.asarray(scale), backend)
         assert got.shape == (m, n)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-4, atol=1e-4)
 
 
 def test_unpack_dequant_composes_with_col_mask():
-    """Epilogue order: unpack-dequant decodes the raw word tile first,
-    later COL ops see the dense f32 tile (DESIGN.md §4.8)."""
-    rng = np.random.RandomState(0)
-    codes = rng.randint(-7, 8, size=(31, 37)).astype(np.int8)
-    scale = np.full((37,), 0.1, np.float32)
-    mask = (rng.rand(37) > 0.4).astype(np.float32)
-    x = jnp.asarray(rng.randn(5, 31).astype(np.float32))
-    packed = Q.pack_codes(jnp.asarray(codes), 4, axis=0)
-    rhs_ops = (gemm_core.unpack_dequant(4, jnp.asarray(scale)),
-               gemm_core.col_mask(jnp.asarray(mask)))
-    want = quant_matmul_ref(x, jnp.asarray(codes),
-                            jnp.asarray(scale * mask))
-    for backend in BACKENDS:
-        got = gemm_core.gemm(x, packed, rhs_ops, backend=backend)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-4, atol=1e-4)
+    """Epilogue order: unpack-dequant decodes the raw words first; the
+    scale and later COL ops (`col_mask`) multiply the result columns, at
+    the last K step where K spans several blocks (DESIGN.md §4.8). Every
+    width, f32 and bf16 activations, K in one block and in several."""
+    cases = [((5, 31, 37), None), ((28, 2100, 260), (32, 128, 1024))]
+    for bits in (2, 3, 4, 8):
+        cpw = 32 // bits
+        for (m, k, n), blocks in cases:
+            for x_dtype in (jnp.float32, jnp.bfloat16):
+                x, codes, scale, rng = _packed_case(bits, m, k, n, x_dtype,
+                                                    bits)
+                mask = (rng.rand(n) > 0.4).astype(np.float32)
+                packed = Q.pack_codes(jnp.asarray(codes), bits, axis=0)
+                rhs_ops = (gemm_core.unpack_dequant(bits,
+                                                    jnp.asarray(scale)),
+                           gemm_core.col_mask(jnp.asarray(mask)))
+                if blocks is not None:     # K really takes several blocks
+                    _, _, bk, bkw = gemm_core.plan_blocks(m, n, k, cpw,
+                                                          blocks)
+                    assert bk % math.lcm(cpw * 8, 128) == 0
+                    assert -(-k // cpw) > bkw
+                want = quant_matmul_ref(x.astype(jnp.float32),
+                                        jnp.asarray(codes),
+                                        jnp.asarray(scale * mask))
+                for backend in BACKENDS:
+                    got = _packed_gemm(x, packed, bits, None, backend,
+                                       rhs_ops=rhs_ops, blocks=blocks)
+                    np.testing.assert_allclose(np.asarray(got),
+                                               np.asarray(want),
+                                               rtol=1e-4, atol=1e-4)
 
 
 # ------------------------------------------------------------ LM serving
@@ -141,13 +202,19 @@ def _decode(lm, params, qparams, steps=4, batch=2):
     return jnp.concatenate(outs, axis=1)
 
 
-@pytest.mark.parametrize("arch", ["internlm2-1.8b", "rwkv6-3b"])
+@pytest.mark.parametrize(
+    "arch,backend",
+    [("internlm2-1.8b", None), ("rwkv6-3b", None),
+     ("internlm2-1.8b", "pallas-interpret"), ("rwkv6-3b", "pallas-interpret")],
+    ids=["internlm2-1.8b", "rwkv6-3b", "internlm2-1.8b-pallas-interpret",
+         "rwkv6-3b-pallas-interpret"])
 @pytest.mark.parametrize("bits_init", [8.0, 4.0], ids=["b8", "b4"])
-def test_packed_decode_matches_unpacked(arch, bits_init):
+def test_packed_decode_matches_unpacked(arch, bits_init, backend):
     """Packed and unpacked compressed decodes share codes and scales
     bit-for-bit, so logits agree (≤1e-4) and greedy tokens are identical
     — attn/MLP projections on the transformer, the rwkv time/channel-mix
-    family on the SSM arch."""
+    family on the SSM arch — with the packed decode's kernels on the
+    platform's backend and in the Pallas interpreter."""
     lm, params, qparams = _f32_lm(arch, bits_init=bits_init)
     plain = compress_lm(lm, params, qparams)
     packed = compress_lm(lm, params, qparams, packed=True)
@@ -158,7 +225,8 @@ def test_packed_decode_matches_unpacked(arch, bits_init):
     rq = residual_qparams(packed, qparams)
     want = _decode(lm, servable_params(plain),
                    residual_qparams(plain, qparams))
-    got = _decode(lm, servable_params(packed), rq)
+    with dispatch.use_backend(backend):
+        got = _decode(lm, servable_params(packed), rq)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
     assert np.array_equal(np.argmax(np.asarray(got), -1),

@@ -100,8 +100,57 @@ def test_gemm_epilogue_compiles(one_chip, epilogue):
 
 
 def test_bits3_stream_launches_the_640_row_tile():
-    """bits=3 packs 10 codes per word: bk = lcm(10 * 8, 128) = 640."""
-    assert gemm_core.plan_blocks(M, N, K, k_pack=10) == (8, 128, 640, 64)
+    """bits=3 packs 10 codes per word, so every K block is a multiple of
+    lcm(10 * 8, 128) = 640 codes (64 words): the decode plan's whole-K
+    block (205 words padded to 256); an explicit 128-code tile over a
+    640-code K, which launches the 640-code tile itself; and the same
+    tile over K = 2048, where K splits into lane-tiled 128-word blocks."""
+    plans = [gemm_core.plan_blocks(M, N, K, k_pack=10),
+             gemm_core.plan_blocks(M, N, 640, k_pack=10,
+                                   blocks=(8, 128, 128)),
+             gemm_core.plan_blocks(M, N, K, k_pack=10,
+                                   blocks=(8, 128, 128))]
+    assert plans == [(8, 1024, 2560, 256), (8, 128, 640, 64),
+                     (8, 128, 1280, 128)]
+    assert all(bk % 640 == 0 and bk == 10 * bkw for _, _, bk, bkw in plans)
+
+
+# The GETA artifact's packed b4 GEMMs (internlm2-1.8b at 50% structured
+# sparsity: 8 / 4 KV heads of 128, d_ff 4096, vocab 92672), at its 28
+# decode slots and at a ~1k-token prefill.
+ARTIFACT_GEMMS = {"wq": (2048, 1024), "wk": (2048, 512), "wv": (2048, 512),
+                  "wo": (1024, 2048), "gate": (2048, 4096),
+                  "up": (2048, 4096), "down": (4096, 2048),
+                  "head": (2048, 92672)}
+
+
+@pytest.mark.parametrize("rows", [28, 1020], ids=["decode", "prefill"])
+@pytest.mark.parametrize("site", list(ARTIFACT_GEMMS))
+def test_artifact_packed_gemm_takes_the_wide_plan(one_chip, site, rows):
+    """Each packed projection of the artifact compiles for the chip with
+    the plan by M: at decode a grid step streams at least 256 KiB of
+    words (an 8 KiB step is bound by the fixed cost of a grid step), and
+    every plan's modelled VMEM fits the budget."""
+    k, n = ARTIFACT_GEMMS[site]
+    x = _sds(one_chip, (rows, k), jnp.bfloat16)
+    w = _sds(one_chip, (k // 8, n), jnp.int32)
+
+    def fn(x, w, c):
+        return gemm_core.gemm(x, w, (gemm_core.unpack_dequant(4, c),),
+                              backend="pallas-tpu")
+
+    _, launch = _compile_recorded(fn, x, w, _sds(one_chip, (n,),
+                                                 jnp.float32))
+    bm, bn, bk, bkw = launch.blocks
+    assert launch.blocks == gemm_core.plan_blocks(rows, n, k, 8,
+                                                  x_itemsize=2)
+    assert launch.x_itemsize == 2            # bf16 planes for bf16 x
+    assert n % bn == 0 and (k // 8) % bkw == 0   # no padded weight copy
+    if rows <= gemm_core.DECODE_ROWS:
+        assert bkw * bn * 4 >= 256 * 1024
+        assert bk == k                       # K whole: x loaded once
+    assert introspect.launch_vmem_bytes(launch) \
+        <= introspect.VMEM_BUDGET_BYTES
 
 
 def test_fused_fake_quant_mask_forward_and_grad_compile(one_chip):
